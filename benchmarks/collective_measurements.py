@@ -12,23 +12,20 @@ Measurements (exact — all ops are scan-exterior):
      shard_map ring ppermute, f32 and bf16 wire.
 Outputs JSON next to the other dry-run results.
 """
+import json
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-import json  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro.configs import get_config  # noqa: E402
-from repro.core.graphs import bidirectional_ring_w  # noqa: E402
-from repro.core.posterior import GaussianPosterior, consensus_all_agents  # noqa: E402
-from repro.launch.consensus_opt import consensus_ppermute_ring  # noqa: E402
-from repro.launch.dryrun import parse_collectives  # noqa: E402
-from repro.launch.expert_parallel import moe_ffn_expert_parallel  # noqa: E402
-from repro.models.moe import moe_ffn, moe_init  # noqa: E402
+from repro.configs import get_config
+from repro.core.graphs import bidirectional_ring_w
+from repro.core.posterior import GaussianPosterior, consensus_all_agents
+from repro.launch.consensus_opt import consensus_ppermute_ring
+from repro.launch.dryrun import parse_collectives
+from repro.launch.expert_parallel import moe_ffn_expert_parallel
+from repro.models.moe import moe_ffn, moe_init
 
 OUT = os.path.join(os.path.dirname(__file__), "results")
 
@@ -102,4 +99,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # placeholder devices, set before the backend starts (not at import)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

@@ -78,6 +78,16 @@ def build_session(spec: ExperimentSpec) -> "Session":
             engine = LaunchEngine(spec, model, n_agents)
         else:
             engine = SimulatedEngine(spec, model, n_agents)
+    if (spec.inference.wire_dtype == "f16"
+            and jax.default_backend() == "tpu"
+            and getattr(engine, "pallas_consensus", True)):
+        raise ValueError(
+            "wire_dtype='f16' cannot run on a TPU here: this session's "
+            "consensus is a Pallas kernel, and Mosaic cannot compile its f16 "
+            "round trip (tpu.pack_subelements); use wire_dtype='bf16' or "
+            "'f32' (the XLA executions, consensus_impl='segments' or "
+            "'ppermute', do run f16)"
+        )
 
     key = jax.random.key(spec.run.seed)
     key, k_init = jax.random.split(key)

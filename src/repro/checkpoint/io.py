@@ -1,8 +1,6 @@
 """msgpack-based pytree checkpointing (orbax/flax are not available offline).
 
-Arrays are serialized as (dtype, shape, raw bytes) with zstd compression
-(zlib fallback when the ``zstandard`` wheel is absent — the reader sniffs
-the frame magic, so either build restores both formats it can decode);
+Arrays are serialized as (dtype, shape, raw bytes) with zstd compression;
 the pytree structure is serialized as a nested msgpack document.  Restore
 optionally re-shards onto a ``jax.sharding.NamedSharding`` tree via
 ``jax.device_put`` (production path), or returns numpy arrays (host path).
@@ -20,41 +18,18 @@ from __future__ import annotations
 
 import os
 import shutil
-import zlib
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
-
-try:  # optional: not in every offline image
-    import zstandard
-except ImportError:  # pragma: no cover - depends on the container
-    zstandard = None
+import zstandard
 
 PyTree = Any
 
 _ARR = "__arr__"
 _SCALAR = "__scalar__"
-_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
-
-
-def _compress(raw: bytes, level: int) -> bytes:
-    if zstandard is not None:
-        return zstandard.ZstdCompressor(level=level).compress(raw)
-    return zlib.compress(raw, level)
-
-
-def _decompress(comp: bytes) -> bytes:
-    if comp[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise RuntimeError(
-                "checkpoint is zstd-compressed but the zstandard module is "
-                "not installed in this environment"
-            )
-        return zstandard.ZstdDecompressor().decompress(comp)
-    return zlib.decompress(comp)
 
 
 def _pack_leaf(leaf):
@@ -105,7 +80,7 @@ def save_pytree(path: str, tree: PyTree, compress_level: int = 3) -> None:
 
 def _write_doc(path: str, doc: dict, compress_level: int = 3) -> None:
     raw = msgpack.packb(doc, use_bin_type=True)
-    comp = _compress(raw, compress_level)
+    comp = zstandard.ZstdCompressor(level=compress_level).compress(raw)
     tmp = path + ".tmp"
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     with open(tmp, "wb") as f:
@@ -115,7 +90,7 @@ def _write_doc(path: str, doc: dict, compress_level: int = 3) -> None:
 
 def _read_doc(path: str) -> dict:
     with open(path, "rb") as f:
-        raw = _decompress(f.read())
+        raw = zstandard.ZstdDecompressor().decompress(f.read())
     return msgpack.unpackb(raw, raw=False)
 
 

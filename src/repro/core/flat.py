@@ -42,6 +42,7 @@ import numpy as np
 from repro.core import graphs as _graphs
 from repro.core.numerics import (
     COMPUTE_DTYPE,
+    EXCHANGE_PRECISION,
     canonical_wire_dtype,
     softplus,
     softplus_inv,
@@ -328,8 +329,10 @@ def _eq6_block(W, mean, rho, wire_dtype=jnp.float32):
     prec = 1.0 / jnp.square(softplus(rho))
     prec_x = wire_roundtrip(prec, wire_dtype)
     pm_x = wire_roundtrip(prec * mean, wire_dtype)
-    new_prec = jnp.matmul(W, prec_x, preferred_element_type=COMPUTE_DTYPE)
-    new_pm = jnp.matmul(W, pm_x, preferred_element_type=COMPUTE_DTYPE)
+    new_prec = jnp.matmul(W, prec_x, precision=EXCHANGE_PRECISION,
+                          preferred_element_type=COMPUTE_DTYPE)
+    new_pm = jnp.matmul(W, pm_x, precision=EXCHANGE_PRECISION,
+                        preferred_element_type=COMPUTE_DTYPE)
     return new_pm / new_prec, softplus_inv(jax.lax.rsqrt(new_prec))
 
 
@@ -406,7 +409,7 @@ def consensus_flat(
     exchanged (prec, prec*mu) through the wire dtype on every mode —
     f32/None is bitwise the uncompressed path (ROADMAP "Wire precision").
     """
-    from repro.kernels.consensus import DEFAULT_BLOCK, consensus_fused_network
+    from repro.kernels.consensus import consensus_fused_network
 
     if mode is None:
         mode = "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -419,7 +422,7 @@ def consensus_flat(
     elif mode in ("pallas", "interpret"):
         mean, rho = consensus_fused_network(
             W, posts.mean, posts.rho,
-            block=(DEFAULT_BLOCK if block is None else block),
+            block=block,
             interpret=(True if mode == "interpret" else None),
             wire_dtype=canonical_wire_dtype(wire_dtype),
         )
@@ -483,7 +486,7 @@ def consensus_flat_masked(
     on the ppermute mode the rounded payload IS the ppermuted wire traffic
     (halved ICI bytes at bf16); f32/None is bitwise uncompressed.
     """
-    from repro.kernels.consensus import DEFAULT_BLOCK, consensus_fused_masked
+    from repro.kernels.consensus import consensus_fused_masked
 
     if mode == "ppermute":
         from repro.launch.consensus_opt import consensus_ppermute_window
@@ -510,7 +513,7 @@ def consensus_flat_masked(
     elif mode in ("pallas", "interpret"):
         mean, rho = consensus_fused_masked(
             W, active, posts.mean, posts.rho,
-            block=(DEFAULT_BLOCK if block is None else block),
+            block=block,
             interpret=(True if mode == "interpret" else None),
             wire_dtype=canonical_wire_dtype(wire_dtype),
         )
@@ -682,10 +685,7 @@ def consensus_flat_masked_sparse(
     path rebuilds the tiny dense W-tilde (reference semantics); the
     active-edge HBM saving exists on the Pallas path.  ``wire_dtype``
     rounds the gathered (prec, prec*mu) at the exchange boundary."""
-    from repro.kernels.consensus import (
-        DEFAULT_BLOCK,
-        consensus_fused_masked_sparse,
-    )
+    from repro.kernels.consensus import consensus_fused_masked_sparse
 
     if mode is None:
         mode = "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -698,7 +698,7 @@ def consensus_flat_masked_sparse(
     elif mode in ("pallas", "interpret"):
         mean, rho = consensus_fused_masked_sparse(
             neighbors, weights, active, posts.mean, posts.rho,
-            block=(DEFAULT_BLOCK if block is None else block),
+            block=block,
             interpret=(True if mode == "interpret" else None),
             wire_dtype=canonical_wire_dtype(wire_dtype),
         )
@@ -791,12 +791,12 @@ def payload_validity(
         )
         return jnp.all(ok, axis=-1)
     if mode in ("pallas", "interpret"):
-        from repro.kernels.consensus import DEFAULT_BLOCK, payload_validity_fused
+        from repro.kernels.consensus import payload_validity_fused
 
         return payload_validity_fused(
             mean, rho,
             bound=bound,
-            block=(DEFAULT_BLOCK if block is None else block),
+            block=block,
             interpret=(True if mode == "interpret" else None),
             wire_dtype=wire_dtype,
         )
@@ -875,7 +875,9 @@ def consensus_flat_masked_quarantined(
     """
     mean_src = posts.mean if mean_src is None else mean_src
     rho_src = posts.rho if rho_src is None else rho_src
-    vmode = mode if mode in ("pallas", "interpret") else "xla"
+    # the validity probe follows the consensus mode: auto (None) runs the
+    # fused kernel on TPU; the sharded ppermute buffers take the XLA probe
+    vmode = "xla" if mode in ("xla", "ppermute") else mode
     valid_src = payload_validity(
         mean_src, rho_src, wire_dtype=wire_dtype, bound=bound, mode=vmode
     )
@@ -939,12 +941,11 @@ def consensus_flat_masked_sparse_quarantined(
     value-identity, as in the dense wrapper."""
     mean_src = posts.mean if mean_src is None else mean_src
     rho_src = posts.rho if rho_src is None else rho_src
-    vmode = mode if mode in ("pallas", "interpret") else "xla"
     valid_src = payload_validity(
-        mean_src, rho_src, wire_dtype=wire_dtype, bound=bound, mode=vmode
+        mean_src, rho_src, wire_dtype=wire_dtype, bound=bound, mode=mode
     )
     valid_self = payload_validity(
-        posts.mean, posts.rho, wire_dtype=wire_dtype, bound=bound, mode=vmode
+        posts.mean, posts.rho, wire_dtype=wire_dtype, bound=bound, mode=mode
     )
     mean_x, rho_x = _sanitized_sources(
         posts, mean_src, rho_src, valid_src, valid_self
@@ -1160,7 +1161,7 @@ def consensus_flat_sparse(
     kernel lane block); the "xla" path rebuilds the tiny dense W (reference
     semantics — the deg(i) traffic saving exists only on the Pallas
     path)."""
-    from repro.kernels.consensus import DEFAULT_BLOCK, consensus_fused_sparse
+    from repro.kernels.consensus import consensus_fused_sparse
 
     if mode is None:
         mode = "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -1173,7 +1174,7 @@ def consensus_flat_sparse(
     elif mode in ("pallas", "interpret"):
         mean, rho = consensus_fused_sparse(
             neighbors, weights, posts.mean, posts.rho,
-            block=(DEFAULT_BLOCK if block is None else block),
+            block=block,
             interpret=(True if mode == "interpret" else None),
             wire_dtype=canonical_wire_dtype(wire_dtype),
         )

@@ -9,10 +9,19 @@ fix of ISSUE 1).
 
 * tiny y (sigma -> 0): softplus_inv(y) = log(expm1(y)) ~= log(y); the naive
   ``y + log1p(-exp(-y))`` form computes log1p(-exp(-eps)) which underflows
-  ``-exp(-y)`` to -1 and returns -inf one ulp too early.  We use
-  ``log(-expm1(-y)) + y`` which keeps full precision down to y ~ 1e-38.
+  ``-exp(-y)`` to -1 and returns -inf one ulp too early.  Below y = 0.25
+  we use ``log(y * (expm1(y) / y))`` with the Taylor series of
+  ``expm1(y) / y``, which keeps full precision down to y ~ 1e-38.
 * huge y (sigma >> 1): exp(-y) underflows to 0 and the result is exactly y,
   which is the correct asymptote (softplus(x) -> x for large x).
+
+The jnp form avoids ``jnp.expm1`` (``softplus_inv_py`` keeps
+``math.expm1``): Mosaic (the TPU Pallas compiler) has no
+lowering for it, and the kernels and the XLA reference must share one
+formulation to agree bitwise.  It also avoids Kahan's ``(u - 1) * x /
+log(u)`` with ``u = exp(x)``: XLA's algebraic simplifier rewrites
+``log(exp(x))`` to ``x``, which turns that back into the inaccurate
+``exp(x) - 1``.
 """
 from __future__ import annotations
 
@@ -23,6 +32,13 @@ import jax.numpy as jnp
 
 # canonical compute dtype for flat posterior buffers and kernel wrappers
 COMPUTE_DTYPE = jnp.float32
+
+# Precision of every eq. (6) contraction (W @ prec, W @ prec*mu).  A TPU
+# runs an f32 matmul at DEFAULT precision as one bf16 pass, which would
+# round W and the exchanged statistics to 8 mantissa bits whatever the wire
+# dtype says; HIGHEST keeps the contraction fp32.  The CPU backend computes
+# f32 dots in full precision either way, so CPU results are unchanged.
+EXCHANGE_PRECISION = jax.lax.Precision.HIGHEST
 
 # -- wire-dtype compression (ROADMAP "Wire precision") ----------------------
 #
@@ -125,13 +141,30 @@ def softplus(x: jax.Array) -> jax.Array:
     return jax.nn.softplus(x)
 
 
+# below this y, softplus_inv takes the series branch (see softplus_inv)
+_SERIES_MAX = 0.25
+
+
 def softplus_inv(y: jax.Array) -> jax.Array:
     """Inverse of softplus for y > 0: x s.t. log1p(exp(x)) == y.
 
-    Stable form ``y + log(-expm1(-y))`` — see module docstring for why
-    ``expm1`` (and not ``log1p(-exp(.))``) is required at tiny y.
+    Two branches, both exp/log/polynomial only (see module docstring):
+
+    * y < 0.25: ``log(expm1(y)) = log(y * (expm1(y) / y))`` with the
+      Taylor series ``expm1(y) / y = 1 + y/2 + y^2/6 + ... + y^6/5040``
+      (truncation error < 2e-9 relative), so tiny y keeps full precision.
+      One ``log`` of the product, not ``log(y) + log(series)``: XLA
+      rewrites ``log(rsqrt(p))`` to ``-0.5 * log(p)`` in some fusions and
+      not others, which would break the bitwise kernel/reference match;
+    * y >= 0.25: ``y + log(1 - exp(-y))``; ``1 - exp(-y)`` is at least
+      0.22 here, so the subtraction loses < 3e-7 relative, and huge y
+      returns exactly y.
     """
-    return y + jnp.log(-jnp.expm1(-y))
+    series = 1.0 + y * (1 / 2 + y * (1 / 6 + y * (1 / 24 + y * (
+        1 / 120 + y * (1 / 720 + y * (1 / 5040))))))
+    small = jnp.log(y * series)
+    large = y + jnp.log(1.0 - jnp.exp(-y))
+    return jnp.where(y < _SERIES_MAX, small, large)
 
 
 def softplus_inv_py(y: float) -> float:

@@ -244,6 +244,9 @@ class GossipEngine:
         if impl == "auto":
             impl = "segments" if sparse_clock else "masked"
         self.consensus_impl = impl
+        # the dense masked window runs the Pallas kernels on TPU; segments,
+        # ppermute and the delayed event-gather are XLA executions
+        self.pallas_consensus = impl == "masked" and not self.hist_slots
         if impl == "segments":
             if not sparse_clock:
                 raise ValueError(

@@ -53,7 +53,12 @@ Flat-buffer layout contract (shared with ``core.flat.FlatPosterior``):
     including f16's narrow exponent range) and slice the pad back off
     before returning;
   * keep BLOCK a multiple of 128 (TPU lane width); the last dim rides the
-    lane dim, agents/neighbors ride sublanes.
+    lane dim, agents/neighbors ride sublanes.  The dense kernels' default
+    BLOCK shrinks with N (``lane_block``) so the [N, BLOCK] tiles and the
+    resident W fit the scoped VMEM; the row-gathering sparse kernels tile
+    the lane-dense view [N, P/128, 128] (``_sparse_call``);
+  * ``wire_dtype="f16"`` is refused by the compiled kernels: Mosaic cannot
+    lower its round trip on TPU v5e (interpret mode still runs it).
 
 Wire-dtype compression (ROADMAP "Wire precision"): every kernel takes a
 static ``wire_dtype`` (default fp32).  The exchanged sufficient statistics
@@ -83,6 +88,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.numerics import (
+    EXCHANGE_PRECISION,
     canonical_wire_dtype,
     softplus_inv,
     wire_roundtrip,
@@ -90,6 +96,39 @@ from repro.core.numerics import (
 from repro.kernels.dispatch import auto_interpret as _auto_interpret
 
 DEFAULT_BLOCK = 2048
+LANES = 128  # TPU lane width: the sparse kernels view [N, P] as [N, P/128, 128]
+
+# Default scoped-VMEM limit Mosaic gives one kernel on a TPU v5e, and the
+# number of fp32 [N, block] tiles one grid step of the dense kernels keeps
+# live in it: (mean, rho) in and out, double-buffered, plus the softplus /
+# matmul temporaries.  A described-v5e compile at N = 256 measured 22.84 MiB
+# of scoped VMEM at block 2048, i.e. ~11 tiles beside the resident W.
+_VMEM_LIMIT = 16 * 2**20
+_LIVE_TILES = 12
+
+
+def lane_block(n: int, resident_bytes: int = 0) -> int:
+    """The widest lane block (a multiple of 128, at most DEFAULT_BLOCK) whose
+    ``_LIVE_TILES`` fp32 [n, block] tiles plus the double-buffered resident
+    operands (``resident_bytes``, e.g. the [N, N] W) fit three quarters of
+    the scoped VMEM limit.  Floors at one lane group: an N whose resident W
+    alone overflows VMEM is refused by the compiler, not here."""
+    room = _VMEM_LIMIT * 3 // 4 - 2 * resident_bytes
+    fit = room // (_LIVE_TILES * 4 * n) // LANES * LANES
+    return max(LANES, min(DEFAULT_BLOCK, fit))
+
+
+def _wire_for(wire_dtype, interpret: bool):
+    """Canonical wire dtype, refusing f16 for a compiled kernel: Mosaic cannot
+    legalize the f16 round trip (``tpu.pack_subelements``) on TPU v5e."""
+    wire_dtype = canonical_wire_dtype(wire_dtype)
+    if not interpret and wire_dtype == jnp.float16:
+        raise ValueError(
+            "wire_dtype='f16' does not compile in the Pallas TPU consensus "
+            "kernels (Mosaic cannot legalize the f16 round trip, "
+            "tpu.pack_subelements); use 'bf16' or 'f32'"
+        )
+    return wire_dtype
 
 
 def _pad_lanes(mean, rho, block):
@@ -133,7 +172,7 @@ def consensus_fused(
     mean_stack: jax.Array,  # [N, P]
     rho_stack: jax.Array,  # [N, P]
     *,
-    block: int = DEFAULT_BLOCK,
+    block: int | None = None,
     interpret: bool | None = None,
     wire_dtype=None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -146,8 +185,9 @@ def consensus_fused(
     path.
     """
     interpret = _auto_interpret(interpret)
-    wire_dtype = canonical_wire_dtype(wire_dtype)
+    wire_dtype = _wire_for(wire_dtype, interpret)
     n, p = mean_stack.shape
+    block = lane_block(n) if block is None else block
     mean_stack, rho_stack, pp = _pad_lanes(mean_stack, rho_stack, block)
     grid = (pp // block,)
     mean_out, rho_out = pl.pallas_call(
@@ -185,8 +225,10 @@ def _consensus_network_kernel(w_ref, mean_ref, rho_ref, mean_out_ref,
     # new_prec[i] = sum_j W[i,j] prec[j]: one MXU matmul covers every agent,
     # so each [N, BLOCK] column tile is read from HBM exactly once; the
     # contraction accumulates fp32 whatever the wire dtype.
-    new_prec = jnp.dot(w, prec_x, preferred_element_type=jnp.float32)
-    new_pm = jnp.dot(w, pm_x, preferred_element_type=jnp.float32)
+    new_prec = jnp.dot(w, prec_x, precision=EXCHANGE_PRECISION,
+                       preferred_element_type=jnp.float32)
+    new_pm = jnp.dot(w, pm_x, precision=EXCHANGE_PRECISION,
+                     preferred_element_type=jnp.float32)
     mean_out_ref[...] = new_pm / new_prec
     rho_out_ref[...] = softplus_inv(jax.lax.rsqrt(new_prec))
 
@@ -197,7 +239,7 @@ def consensus_fused_network(
     mean: jax.Array,  # [N, P] flat network posterior means
     rho: jax.Array,  # [N, P]
     *,
-    block: int = DEFAULT_BLOCK,
+    block: int | None = None,
     interpret: bool | None = None,
     wire_dtype=None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -210,8 +252,9 @@ def consensus_fused_network(
     f32/None is bitwise the uncompressed kernel.
     """
     interpret = _auto_interpret(interpret)
-    wire_dtype = canonical_wire_dtype(wire_dtype)
+    wire_dtype = _wire_for(wire_dtype, interpret)
     n, p = mean.shape
+    block = lane_block(n, 4 * n * n) if block is None else block
     mean, rho, pp = _pad_lanes(mean, rho, block)
     grid = (pp // block,)
     mean_out, rho_out = pl.pallas_call(
@@ -249,8 +292,10 @@ def _consensus_masked_kernel(
     # fused kernel at every wire dtype; inactive rows never touch the wire
     prec_x = wire_roundtrip(prec, wire_dtype)
     pm_x = wire_roundtrip(prec * mean, wire_dtype)
-    new_prec = jnp.dot(w, prec_x, preferred_element_type=jnp.float32)
-    new_pm = jnp.dot(w, pm_x, preferred_element_type=jnp.float32)
+    new_prec = jnp.dot(w, prec_x, precision=EXCHANGE_PRECISION,
+                       preferred_element_type=jnp.float32)
+    new_pm = jnp.dot(w, pm_x, precision=EXCHANGE_PRECISION,
+                     preferred_element_type=jnp.float32)
     mean_out_ref[...] = jnp.where(act > 0, new_pm / new_prec, mean)
     rho_out_ref[...] = jnp.where(
         act > 0, softplus_inv(jax.lax.rsqrt(new_prec)), rho
@@ -264,7 +309,7 @@ def consensus_fused_masked(
     mean: jax.Array,  # [N, P]
     rho: jax.Array,  # [N, P]
     *,
-    block: int = DEFAULT_BLOCK,
+    block: int | None = None,
     interpret: bool | None = None,
     wire_dtype=None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -280,8 +325,9 @@ def consensus_fused_masked(
     other kernels.
     """
     interpret = _auto_interpret(interpret)
-    wire_dtype = canonical_wire_dtype(wire_dtype)
+    wire_dtype = _wire_for(wire_dtype, interpret)
     n, p = mean.shape
+    block = lane_block(n, 4 * n * n) if block is None else block
     mean, rho, pp = _pad_lanes(mean, rho, block)
     act = active.astype(jnp.float32)[:, None]
     grid = (pp // block,)
@@ -310,12 +356,12 @@ def consensus_fused_masked(
 def _consensus_sparse_kernel(
     nbr_ref,  # scalar-prefetch [N, D] int32 neighbor ids (self-padded)
     wts_ref,  # scalar-prefetch [N, D] fp32 neighbor weights (0-padded)
-    mean_ref,  # [1, BLOCK] — row nbr[i, d], column tile j
-    rho_ref,  # [1, BLOCK]
-    mean_out_ref,  # [1, BLOCK] — row i, column tile j
-    rho_out_ref,  # [1, BLOCK]
-    acc_prec,  # VMEM scratch [1, BLOCK]
-    acc_pm,  # VMEM scratch [1, BLOCK]
+    mean_ref,  # [BLOCK/128, 128] — row nbr[i, d], column tile j
+    rho_ref,  # [BLOCK/128, 128]
+    mean_out_ref,  # [BLOCK/128, 128] — row i, column tile j
+    rho_out_ref,  # [BLOCK/128, 128]
+    acc_prec,  # VMEM scratch [BLOCK/128, 128]
+    acc_pm,  # VMEM scratch [BLOCK/128, 128]
     *,
     wire_dtype,
 ):
@@ -358,7 +404,7 @@ def consensus_fused_sparse(
     mean: jax.Array,  # [N, P]
     rho: jax.Array,  # [N, P]
     *,
-    block: int = DEFAULT_BLOCK,
+    block: int | None = None,
     interpret: bool | None = None,
     wire_dtype=None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -375,37 +421,45 @@ def consensus_fused_sparse(
     uncompressed kernel.
     """
     interpret = _auto_interpret(interpret)
-    wire_dtype = canonical_wire_dtype(wire_dtype)
-    n, p = mean.shape
-    d = neighbors.shape[1]
-    mean, rho, pp = _pad_lanes(mean, rho, block)
-    grid = (n, pp // block, d)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i, j, k, nbr, wts: (nbr[i, k], j)),
-            pl.BlockSpec((1, block), lambda i, j, k, nbr, wts: (nbr[i, k], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i, j, k, nbr, wts: (i, j)),
-            pl.BlockSpec((1, block), lambda i, j, k, nbr, wts: (i, j)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, block), jnp.float32),
-            pltpu.VMEM((1, block), jnp.float32),
-        ],
-    )
-    mean_out, rho_out = pl.pallas_call(
+    wire_dtype = _wire_for(wire_dtype, interpret)
+    return _sparse_call(
         functools.partial(_consensus_sparse_kernel, wire_dtype=wire_dtype),
-        grid_spec=grid_spec,
+        (neighbors.astype(jnp.int32), weights.astype(jnp.float32)),
+        mean, rho, block, interpret,
+    )
+
+
+def _sparse_call(kernel, prefetch, mean, rho, block, interpret):
+    """Run a row-gathering sparse kernel over the lane-dense view
+    ``[N, P/128, 128]``: grid ``(N, P // BLOCK, D)``, the scalar-prefetched
+    ``prefetch`` tables (neighbor ids first) steer each step's input tile to
+    row ``nbr[i, d]``, and two fp32 VMEM accumulators carry the sums over d.
+    A (1, BLOCK) block of the 2-D buffer breaks the TPU (8, 128) tiling rule;
+    a (BLOCK/128, 128) tile of one squeezed row does not."""
+    n, p = mean.shape
+    d = prefetch[0].shape[1]
+    block = DEFAULT_BLOCK if block is None else block
+    mean, rho, pp = _pad_lanes(mean, rho, block)
+    tile = (pl.squeezed, block // LANES, LANES)
+    src = pl.BlockSpec(tile, lambda i, j, k, nbr, *_: (nbr[i, k], j, 0))
+    dst = pl.BlockSpec(tile, lambda i, j, k, *_: (i, j, 0))
+    lane_dense = (n, pp // LANES, LANES)
+    mean_out, rho_out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(n, pp // block, d),
+            in_specs=[src, src],
+            out_specs=[dst, dst],
+            scratch_shapes=[pltpu.VMEM(tile[1:], jnp.float32)] * 2,
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((n, pp), mean.dtype),
-            jax.ShapeDtypeStruct((n, pp), rho.dtype),
+            jax.ShapeDtypeStruct(lane_dense, mean.dtype),
+            jax.ShapeDtypeStruct(lane_dense, rho.dtype),
         ],
         interpret=interpret,
-    )(neighbors.astype(jnp.int32), weights.astype(jnp.float32), mean, rho)
-    return mean_out[:, :p], rho_out[:, :p]
+    )(*prefetch, mean.reshape(lane_dense), rho.reshape(lane_dense))
+    return mean_out.reshape(n, pp)[:, :p], rho_out.reshape(n, pp)[:, :p]
 
 
 def _payload_validity_kernel(mean_ref, rho_ref, ok_ref, *, wire_dtype, bound):
@@ -438,7 +492,7 @@ def payload_validity_fused(
     rho: jax.Array,  # [N, P]
     *,
     bound: float,
-    block: int = DEFAULT_BLOCK,
+    block: int | None = None,
     interpret: bool | None = None,
     wire_dtype=None,
 ) -> jax.Array:
@@ -454,8 +508,9 @@ def payload_validity_fused(
     bit-equal to the ``core.flat.payload_validity`` XLA reference.
     """
     interpret = _auto_interpret(interpret)
-    wire_dtype = canonical_wire_dtype(wire_dtype)
+    wire_dtype = _wire_for(wire_dtype, interpret)
     n, _ = mean.shape
+    block = lane_block(n) if block is None else block
     mean, rho, pp = _pad_lanes(mean, rho, block)
     grid = (pp // block,)
     ok = pl.pallas_call(
@@ -479,12 +534,12 @@ def _consensus_masked_sparse_kernel(
     nbr_ref,  # scalar-prefetch [N, D] int32 neighbor ids (self-padded)
     wts_ref,  # scalar-prefetch [N, D] fp32 weights (0-padded)
     act_ref,  # scalar-prefetch [N] int32 activity mask
-    mean_ref,  # [1, BLOCK] — row nbr[i, d], column tile j
-    rho_ref,  # [1, BLOCK]
-    mean_out_ref,  # [1, BLOCK] — row i, column tile j
-    rho_out_ref,  # [1, BLOCK]
-    acc_prec,  # VMEM scratch [1, BLOCK]
-    acc_pm,  # VMEM scratch [1, BLOCK]
+    mean_ref,  # [BLOCK/128, 128] — row nbr[i, d], column tile j
+    rho_ref,  # [BLOCK/128, 128]
+    mean_out_ref,  # [BLOCK/128, 128] — row i, column tile j
+    rho_out_ref,  # [BLOCK/128, 128]
+    acc_prec,  # VMEM scratch [BLOCK/128, 128]
+    acc_pm,  # VMEM scratch [BLOCK/128, 128]
     *,
     wire_dtype,
 ):
@@ -533,7 +588,7 @@ def consensus_fused_masked_sparse(
     mean: jax.Array,  # [N, P]
     rho: jax.Array,  # [N, P]
     *,
-    block: int = DEFAULT_BLOCK,
+    block: int | None = None,
     interpret: bool | None = None,
     wire_dtype=None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -548,42 +603,15 @@ def consensus_fused_masked_sparse(
     ``launch.costmodel.gossip_window_roofline``.
     """
     interpret = _auto_interpret(interpret)
-    wire_dtype = canonical_wire_dtype(wire_dtype)
-    n, p = mean.shape
-    d = neighbors.shape[1]
-    mean, rho, pp = _pad_lanes(mean, rho, block)
-    grid = (n, pp // block, d)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i, j, k, nbr, wts, act: (nbr[i, k], j)),
-            pl.BlockSpec((1, block), lambda i, j, k, nbr, wts, act: (nbr[i, k], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i, j, k, nbr, wts, act: (i, j)),
-            pl.BlockSpec((1, block), lambda i, j, k, nbr, wts, act: (i, j)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, block), jnp.float32),
-            pltpu.VMEM((1, block), jnp.float32),
-        ],
-    )
-    mean_out, rho_out = pl.pallas_call(
+    wire_dtype = _wire_for(wire_dtype, interpret)
+    return _sparse_call(
         functools.partial(
             _consensus_masked_sparse_kernel, wire_dtype=wire_dtype
         ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n, pp), mean.dtype),
-            jax.ShapeDtypeStruct((n, pp), rho.dtype),
-        ],
-        interpret=interpret,
-    )(
-        neighbors.astype(jnp.int32),
-        weights.astype(jnp.float32),
-        active.astype(jnp.int32),
-        mean,
-        rho,
+        (
+            neighbors.astype(jnp.int32),
+            weights.astype(jnp.float32),
+            active.astype(jnp.int32),
+        ),
+        mean, rho, block, interpret,
     )
-    return mean_out[:, :p], rho_out[:, :p]

@@ -39,13 +39,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.flat import XLA_BLOCK, _MAX_UNROLL, FlatPosterior
-from repro.core.numerics import canonical_wire_dtype, wire_cast_pair
+from repro.core.numerics import (
+    EXCHANGE_PRECISION,
+    canonical_wire_dtype,
+    wire_cast_pair,
+)
 from repro.core.posterior import GaussianPosterior, softplus, softplus_inv
-
-try:  # jax >= 0.5 exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def consensus_einsum(posts: GaussianPosterior, W: jax.Array,
@@ -160,7 +159,7 @@ def consensus_ppermute_ring_flat(
         return new_pm / new_prec, softplus_inv(jnp.sqrt(1.0 / new_prec))
 
     spec = P(axis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec)
     )
     mean, rho = fn(posts.mean, posts.rho)
@@ -218,7 +217,7 @@ def consensus_ppermute_pod(
     outs = []
     for m, r, s in zip(flat_mean, flat_rho, flat_shard):
         spec = s.spec if hasattr(s, "spec") else s
-        fn = _shard_map(
+        fn = jax.shard_map(
             shard_fn, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec)
         )
         outs.append(fn(m, r))
@@ -305,10 +304,12 @@ def _window_consensus_fn(mesh, axis, offsets, n, per, p, block, wire_dtype):
 
         def blk(s, e):
             new_prec = jnp.matmul(
-                w_rows, buf_prec[:, s:e], preferred_element_type=jnp.float32
+                w_rows, buf_prec[:, s:e], precision=EXCHANGE_PRECISION,
+                preferred_element_type=jnp.float32,
             )
             new_pm = jnp.matmul(
-                w_rows, buf_pm[:, s:e], preferred_element_type=jnp.float32
+                w_rows, buf_pm[:, s:e], precision=EXCHANGE_PRECISION,
+                preferred_element_type=jnp.float32,
             )
             m_o = new_pm / new_prec
             r_o = softplus_inv(jax.lax.rsqrt(new_prec))
@@ -334,7 +335,7 @@ def _window_consensus_fn(mesh, axis, offsets, n, per, p, block, wire_dtype):
         return mean_out, rho_out
 
     spec_np = P(axis, None)
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(spec_np, P(axis), spec_np, spec_np),
@@ -464,7 +465,7 @@ def consensus_ppermute_ring(
     outs = []
     for m, r in zip(flat_mean, flat_rho):
         spec = leaf_spec(m)
-        fn = _shard_map(
+        fn = jax.shard_map(
             shard_fn, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec)
         )
         outs.append(fn(m, r))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent without
 hardware.  For every (architecture x input shape x mesh) this lowers and
 compiles the production step function against ShapeDtypeStruct stand-ins
@@ -23,6 +20,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -462,4 +460,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # 512 placeholder CPU devices for the production meshes.  Set here, not
+    # at import: importing this module must not change another program's
+    # XLA flags (importing jax does not start the backend, so this is early
+    # enough).
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

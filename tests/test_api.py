@@ -217,18 +217,15 @@ def test_session_checkpoint_roundtrip_and_resume(tmp_path):
     )
 
 
-def test_session_checkpoint_zlib_fallback(tmp_path, monkeypatch):
-    """Regression for the zstandard-less container: the session document
-    compresses via zlib and the reader sniffs the frame either way."""
-    import repro.checkpoint.io as io
-
-    monkeypatch.setattr(io, "zstandard", None)
+def test_session_checkpoint_is_zstd_framed(tmp_path):
+    """The session document is one zstd frame (magic 28 b5 2f fd) and
+    loads back to the same posterior and spec."""
     s = build_session(_tiny_spec(n_rounds=2))
     s.run()
-    path = os.path.join(tmp_path, "sess_zlib.ckpt")
+    path = os.path.join(tmp_path, "sess_zstd.ckpt")
     s.save(path)
     with open(path, "rb") as f:
-        assert f.read(4) != io._ZSTD_MAGIC  # actually took the zlib path
+        assert f.read(4) == b"\x28\xb5\x2f\xfd"
     s2 = Session.load(path)
     np.testing.assert_array_equal(
         np.asarray(s2.posterior().mean), np.asarray(s.posterior().mean)

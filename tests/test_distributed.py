@@ -58,8 +58,6 @@ def test_consensus_einsum_sharded_matches_unsharded():
 @pytest.mark.slow
 @pytest.mark.multidevice
 def test_consensus_ppermute_matches_einsum():
-    # seed xfail removed: the failure was jax.shard_map missing on jax 0.4.x;
-    # consensus_opt now falls back to jax.experimental.shard_map
     _run("""
     from repro.core.posterior import GaussianPosterior, consensus_all_agents
     from repro.launch.consensus_opt import consensus_ppermute_pod

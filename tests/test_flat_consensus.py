@@ -167,6 +167,18 @@ def test_softplus_inv_extreme_sigma_regression():
     assert np.all(np.isfinite(np.asarray(ro)))
 
 
+def test_softplus_inv_matches_float64_over_sigma_range():
+    """The exp/log-only softplus^-1 (Mosaic has no expm1) against float64
+    NumPy over y in [1e-6, 1e4], jitted as the kernels and references use
+    it.  Mixed tolerance: the result crosses 0 at y = ln 2, where only an
+    absolute bound means anything."""
+    y = np.geomspace(1e-6, 1e4, 200_001).astype(np.float32)
+    got = np.asarray(jax.jit(softplus_inv)(jnp.asarray(y)), np.float64)
+    y64 = y.astype(np.float64)
+    ref = y64 + np.log(-np.expm1(-y64))
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
+
+
 def test_flat_vi_round_and_dispatch():
     """End-to-end flat runtime: init_network(flat=True) + param_layout round
     steps under vmap, consensus_all_agents auto-dispatches on FlatPosterior."""

@@ -1,0 +1,108 @@
+"""Compile the consensus kernels for a described TPU v5e, without a chip.
+
+Interpret mode (every other Pallas test) cannot see what Mosaic refuses:
+primitives it has no lowering for, blocks that break the (8, 128) tiling
+rule, kernels that overrun the scoped VMEM.  These tests compile each
+kernel with ``interpret=False`` for one chip of a described ``v5e:2x2``
+topology at the paper MLP's width (P = 199,210 parameters per agent) and
+check that the program really holds the kernel (``tpu_custom_call``).
+
+The topology is described inside a fixture, never at import: only the
+process that runs these tests loads the TPU compiler library.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.flat import QUARANTINE_BOUND
+from repro.kernels import consensus as kc
+
+P_MLP = 199_210  # 784-200-200-10 MLP
+DEGREE = 5  # torus in-degree + self
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a described-chip compile is written to the persistent cache but can
+    # never be read back without the chip; keep it out of any cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_call(name, n, wire):
+    """(fn, argument shapes) of one kernel, compiled (interpret=False)."""
+    kw = dict(interpret=False, wire_dtype=wire)
+    nn, vec, buf = (n, n), (n,), (n, P_MLP)
+    tables = ((n, DEGREE), jnp.int32), ((n, DEGREE), jnp.float32)
+    if name == "network":
+        return (lambda W, m, r: kc.consensus_fused_network(W, m, r, **kw),
+                [(nn, jnp.float32), (buf, jnp.float32), (buf, jnp.float32)])
+    if name == "masked":
+        return (lambda W, a, m, r: kc.consensus_fused_masked(W, a, m, r, **kw),
+                [(nn, jnp.float32), (vec, jnp.bool_), (buf, jnp.float32),
+                 (buf, jnp.float32)])
+    if name == "validity":
+        return (lambda m, r: kc.payload_validity_fused(
+                    m, r, bound=QUARANTINE_BOUND, **kw),
+                [(buf, jnp.float32), (buf, jnp.float32)])
+    if name == "sparse":
+        return (lambda nb, w, m, r: kc.consensus_fused_sparse(nb, w, m, r, **kw),
+                [*tables, (buf, jnp.float32), (buf, jnp.float32)])
+    assert name == "masked_sparse"
+    return (lambda nb, w, a, m, r: kc.consensus_fused_masked_sparse(
+                nb, w, a, m, r, **kw),
+            [*tables, (vec, jnp.bool_), (buf, jnp.float32),
+             (buf, jnp.float32)])
+
+
+KERNELS = ["network", "masked", "validity", "sparse", "masked_sparse"]
+
+
+@pytest.mark.parametrize("wire", ["f32", "bf16"])
+@pytest.mark.parametrize("n", [16, 256])
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e_at_paper_width(one_chip, name, n, wire):
+    fn, shapes = _kernel_call(name, n, wire)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_compiled_kernel_refuses_f16_wire(name):
+    """Mosaic cannot legalize the f16 round trip on v5e
+    (``tpu.pack_subelements``): a compiled kernel refuses f16 up front
+    instead of failing inside the compiler (or quietly running elsewhere)."""
+    fn, shapes = _kernel_call(name, 8, "f16")
+    args = [jnp.zeros(s, dt) for s, dt in shapes]
+    with pytest.raises(ValueError, match="f16"):
+        fn(*args)
+
+
+@pytest.mark.parametrize("n", [16, 256, 1024])
+def test_lane_block_fits_scoped_vmem(n):
+    """The dense kernels' default lane block keeps ~12 fp32 [n, block]
+    tiles plus the double-buffered [n, n] W inside 3/4 of the 16 MiB
+    scoped VMEM, and stays a positive multiple of 128."""
+    block = kc.lane_block(n, 4 * n * n)
+    assert block % 128 == 0 and 128 <= block <= kc.DEFAULT_BLOCK
+    if n <= 256:
+        assert 12 * 4 * n * block + 2 * 4 * n * n <= 12 * 2**20

@@ -483,6 +483,23 @@ def test_wire_spec_validation():
     InferenceSpec(wire_dtype="bf16").validate()
 
 
+def test_build_session_refuses_f16_where_tpu_runs_a_pallas_kernel(
+    monkeypatch,
+):
+    """On a TPU backend an f16 wire is refused at build time wherever the
+    consensus is a Pallas kernel (Mosaic cannot compile its f16 round
+    trip); the XLA delayed event-gather still builds.  The backend is
+    steered here: nothing is compiled or run."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="f16.*Pallas"):
+        build_session(_gossip_session_spec(wire="f16"))
+    delayed = {"kind": "delayed",
+               "inner": {"kind": "poisson", "rate": 0.8, "seed": 1},
+               "latency": {"kind": "constant", "delay": 1}}
+    s = build_session(_gossip_session_spec(wire="f16", clock=delayed))
+    assert not s.engine.pallas_consensus
+
+
 def test_gossip_engine_wire_f32_bitwise_and_bf16_runs():
     """Engine plumbing: wire_dtype="f32" session is bit-identical to the
     default; a bf16 session runs finite, reports its wire dtype in the
