@@ -216,12 +216,15 @@ class Session:
             self.state, batches, w_arg, k_round
         )
         self.round_idx = r + 1
-        losses = np.asarray(losses)
-        n_trained = int(np.isfinite(losses).sum())
-        if getattr(self.engine, "loss_nan_is_sentinel", False):
-            loss = float(np.nanmean(losses)) if n_trained else None
-        else:
-            loss = float(losses.mean())
+        # the host's wait for the device: reading the losses back blocks
+        # until the round's program has run
+        with _span(self._obs, "session.sync", round=r):
+            losses = np.asarray(losses)
+            n_trained = int(np.isfinite(losses).sum())
+            if getattr(self.engine, "loss_nan_is_sentinel", False):
+                loss = float(np.nanmean(losses)) if n_trained else None
+            else:
+                loss = float(losses.mean())
         rec = {"round": self.round_idx, "loss": loss, "n_trained": n_trained}
         crashed = getattr(self.engine, "last_crashed", None)
         if crashed is not None:

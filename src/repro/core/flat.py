@@ -778,19 +778,21 @@ def payload_validity(
     wire_dtype = canonical_wire_dtype(wire_dtype)
     if mode is None:
         mode = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if mode == "xla":
-        prec = 1.0 / jnp.square(softplus(rho))
-        prec_x = wire_roundtrip(prec, wire_dtype)
-        pm_x = wire_roundtrip(prec * mean, wire_dtype)
-        ok = (
-            jnp.isfinite(prec_x)
-            & (prec_x > 0.0)
-            & (prec_x <= bound)
-            & jnp.isfinite(pm_x)
-            & (jnp.abs(pm_x) <= bound)
-        )
-        return jnp.all(ok, axis=-1)
-    if mode in ("pallas", "interpret"):
+    if mode not in ("xla", "pallas", "interpret"):
+        raise ValueError(f"unknown payload_validity mode {mode!r}")
+    with jax.named_scope("fault_guard"):
+        if mode == "xla":
+            prec = 1.0 / jnp.square(softplus(rho))
+            prec_x = wire_roundtrip(prec, wire_dtype)
+            pm_x = wire_roundtrip(prec * mean, wire_dtype)
+            ok = (
+                jnp.isfinite(prec_x)
+                & (prec_x > 0.0)
+                & (prec_x <= bound)
+                & jnp.isfinite(pm_x)
+                & (jnp.abs(pm_x) <= bound)
+            )
+            return jnp.all(ok, axis=-1)
         from repro.kernels.consensus import payload_validity_fused
 
         return payload_validity_fused(
@@ -800,7 +802,6 @@ def payload_validity(
             interpret=(True if mode == "interpret" else None),
             wire_dtype=wire_dtype,
         )
-    raise ValueError(f"unknown payload_validity mode {mode!r}")
 
 
 def quarantine_w(W: jax.Array, valid: jax.Array) -> jax.Array:

@@ -122,10 +122,10 @@ def network_local_steps(
     bit-identity in the all-edges-active case hangs on sharing this exact
     key/step derivation, so extend it here rather than copying it.
 
-    Returns (posterior', opt_state', per-agent mean losses [N]).
+    Returns (posterior', opt_state', per-agent mean losses [N]).  Its
+    device work carries the ``local_phase`` named scope (the profiler's
+    per-layer reading).
     """
-    n_agents = step.shape[0]
-    keys = jax.random.split(key, n_agents)
 
     def local(post_i, prior_i, opt_i, batches_i, key_i, step_i):
         return local_vi_steps(
@@ -142,7 +142,10 @@ def network_local_steps(
             kl_scale=kl_scale,
         )
 
-    return jax.vmap(local)(posterior, prior, opt_state, batches, keys, step)
+    with jax.named_scope("local_phase"):
+        keys = jax.random.split(key, step.shape[0])
+        return jax.vmap(local)(posterior, prior, opt_state, batches, keys,
+                               step)
 
 
 def make_round_fn(
@@ -185,16 +188,17 @@ def make_round_fn(
             lr, state.step, n_samples=n_mc_samples, kl_scale=kl_scale,
         )
         u = jax.tree.leaves(batches)[0].shape[1]
-        if consensus == "gaussian":
-            post = consensus_all_agents(post, W, wire_dtype=wire_dtype)
-        elif consensus == "mean_only":
-            # dataclasses.replace keeps the posterior's own type (and, for a
-            # FlatPosterior, its static layout)
-            post = dataclasses.replace(
-                post,
-                mean=consensus_mean_only(post.mean, W),
-                rho=consensus_mean_only(post.rho, W),
-            )
+        with jax.named_scope("consensus"):
+            if consensus == "gaussian":
+                post = consensus_all_agents(post, W, wire_dtype=wire_dtype)
+            elif consensus == "mean_only":
+                # dataclasses.replace keeps the posterior's own type (and,
+                # for a FlatPosterior, its static layout)
+                post = dataclasses.replace(
+                    post,
+                    mean=consensus_mean_only(post.mean, W),
+                    rho=consensus_mean_only(post.rho, W),
+                )
         # consensus == "none": isolated learning (paper Fig 1b baseline)
         new_state = NetworkState(
             posterior=post,

@@ -355,23 +355,50 @@ class GossipEngine:
                 # zero-fault guarded window stays value-identical to the
                 # unguarded one (the bitwise ladder in tests/test_faults.py).
                 train = (active & up) if policy == "active" else up
-                post = _agent_select(train, post, state.posterior)
-                opt_state = _agent_select(train, opt_state, state.opt_state)
-                step = jnp.where(train, state.step + u, state.step)
-                losses = jnp.where(train, losses, jnp.nan)
-                active = active & up
             elif policy == "active":
                 # wake-on-event: sleeping agents' local state passes through,
                 # and their (discarded) phantom losses must not pollute the
                 # loss telemetry — NaN marks "did not train this window"
                 # (Session.round aggregates NaN-safely and reports n_trained)
-                post = _agent_select(active, post, state.posterior)
-                opt_state = _agent_select(active, opt_state, state.opt_state)
-                step = jnp.where(active, state.step + u, state.step)
-                losses = jnp.where(active, losses, jnp.nan)
+                train = active
             else:
-                step = state.step + u
+                return post, opt_state, state.step + u, active, losses
+            with jax.named_scope("agent_select"):
+                post = _agent_select(train, post, state.posterior)
+                opt_state = _agent_select(train, opt_state, state.opt_state)
+                step = jnp.where(train, state.step + u, state.step)
+                losses = jnp.where(train, losses, jnp.nan)
+            if up is not None:
+                active = active & up
             return post, opt_state, step, active, losses
+
+        def mean_only(post, W, active):
+            """The FedAvg baseline's merge: W @ mean, W @ rho on the
+            merging agents."""
+            act = active[:, None]
+            return dataclasses.replace(
+                post,
+                mean=jnp.where(act, W @ post.mean, post.mean),
+                rho=jnp.where(act, W @ post.rho, post.rho),
+            )
+
+        def corrupt_fill(post, corrupt, fill_mean, fill_rho):
+            """The wire payloads the corrupted agents transmit this window
+            (their resident state stays intact)."""
+            with jax.named_scope("fault_guard"):
+                c = corrupt[:, None]
+                return (jnp.where(c, fill_mean[:, None], post.mean),
+                        jnp.where(c, fill_rho[:, None], post.rho))
+
+        def merged_rows(post, merged, active):
+            """``merged``'s rows on the merging agents, ``post``'s
+            elsewhere."""
+            act = active[:, None]
+            return dataclasses.replace(
+                post,
+                mean=jnp.where(act, merged.mean, post.mean),
+                rho=jnp.where(act, merged.rho, post.rho),
+            )
 
         def finish(state, post, opt_state, step, active):
             merged = active if consensus_mode != "none" else jnp.zeros_like(active)
@@ -389,17 +416,13 @@ class GossipEngine:
             post, opt_state, step, active, losses = local_phase(
                 state, batches, active, key
             )
-            if consensus_mode == "gaussian" and merge_in_jit:
-                post = consensus_flat_masked(
-                    post, W, active, wire_dtype=wire_dtype
-                )
-            elif consensus_mode == "mean_only":
-                act = active[:, None]
-                post = dataclasses.replace(
-                    post,
-                    mean=jnp.where(act, W @ post.mean, post.mean),
-                    rho=jnp.where(act, W @ post.rho, post.rho),
-                )
+            with jax.named_scope("consensus"):
+                if consensus_mode == "gaussian" and merge_in_jit:
+                    post = consensus_flat_masked(
+                        post, W, active, wire_dtype=wire_dtype
+                    )
+                elif consensus_mode == "mean_only":
+                    post = mean_only(post, W, active)
             return finish(state, post, opt_state, step, active), losses
 
         def window_fn_delayed(
@@ -421,11 +444,12 @@ class GossipEngine:
                 state.hist_rho, post.rho.astype(hist_dtype), slot, 0
             )
             if consensus_mode == "gaussian":
-                post = consensus_flat_delayed(
-                    post, W, active, edges, weights, lags,
-                    hist_mean, hist_rho, state.round,
-                    wire_dtype=wire_dtype,
-                )
+                with jax.named_scope("consensus"):
+                    post = consensus_flat_delayed(
+                        post, W, active, edges, weights, lags,
+                        hist_mean, hist_rho, state.round,
+                        wire_dtype=wire_dtype,
+                    )
             new_state = finish(state, post, opt_state, step, active)
             return dataclasses.replace(
                 new_state, hist_mean=hist_mean, hist_rho=hist_rho
@@ -447,38 +471,31 @@ class GossipEngine:
             )
             n_q = state.n_quarantined
             if consensus_mode == "gaussian" and merge_in_jit:
-                c = corrupt[:, None]
-                mean_src = jnp.where(c, fill_mean[:, None], post.mean)
-                rho_src = jnp.where(c, fill_rho[:, None], post.rho)
-                if quarantine:
-                    post, valid_src = consensus_flat_masked_quarantined(
-                        post, W, active,
-                        mean_src=mean_src, rho_src=rho_src,
-                        wire_dtype=wire_dtype,
-                    )
-                    n_q = n_q + (~valid_src).astype(jnp.int32)
-                else:
-                    # strict: the wire buffer is trusted verbatim, so the
-                    # injected garbage reaches every receiving agent (the
-                    # undefended baseline); only the exchange is poisoned —
-                    # non-merging agents keep their true resident state
-                    merged = consensus_flat_masked(
-                        dataclasses.replace(post, mean=mean_src, rho=rho_src),
-                        W, active, wire_dtype=wire_dtype,
-                    )
-                    act = active[:, None]
-                    post = dataclasses.replace(
-                        post,
-                        mean=jnp.where(act, merged.mean, post.mean),
-                        rho=jnp.where(act, merged.rho, post.rho),
-                    )
+                mean_src, rho_src = corrupt_fill(post, corrupt, fill_mean,
+                                                 fill_rho)
+                with jax.named_scope("consensus"):
+                    if quarantine:
+                        post, valid_src = consensus_flat_masked_quarantined(
+                            post, W, active,
+                            mean_src=mean_src, rho_src=rho_src,
+                            wire_dtype=wire_dtype,
+                        )
+                        n_q = n_q + (~valid_src).astype(jnp.int32)
+                    else:
+                        # strict: the wire buffer is trusted verbatim, so
+                        # the injected garbage reaches every receiving agent
+                        # (the undefended baseline); only the exchange is
+                        # poisoned — non-merging agents keep their true
+                        # resident state
+                        merged = consensus_flat_masked(
+                            dataclasses.replace(post, mean=mean_src,
+                                                rho=rho_src),
+                            W, active, wire_dtype=wire_dtype,
+                        )
+                        post = merged_rows(post, merged, active)
             elif consensus_mode == "mean_only":
-                act = active[:, None]
-                post = dataclasses.replace(
-                    post,
-                    mean=jnp.where(act, W @ post.mean, post.mean),
-                    rho=jnp.where(act, W @ post.rho, post.rho),
-                )
+                with jax.named_scope("consensus"):
+                    post = mean_only(post, W, active)
             new_state = finish(state, post, opt_state, step, active)
             return dataclasses.replace(new_state, n_quarantined=n_q), losses
 
@@ -501,21 +518,22 @@ class GossipEngine:
                 state.hist_rho, post.rho.astype(hist_dtype), slot, 0
             )
             n_q = state.n_quarantined
-            if consensus_mode == "gaussian":
-                if quarantine:
+            if consensus_mode == "gaussian" and quarantine:
+                with jax.named_scope("consensus"):
                     post, valid_e = consensus_flat_delayed_quarantined(
                         post, W, active, edges, weights, lags,
                         hist_mean, hist_rho, state.round,
                         corrupt=corrupt, fill_mean=fill_mean,
                         fill_rho=fill_rho, wire_dtype=wire_dtype,
                     )
-                    # count only REAL dropped events — [E_max] padding rows
-                    # carry zero weight and must not inflate the telemetry
-                    bad = ((~valid_e) & (weights > 0.0)).astype(jnp.int32)
-                    n_q = n_q.at[edges[:, 0]].add(bad)
-                else:
-                    # strict: poison the gathered copies (by src id, every
-                    # ring slot) — the state's ring keeps the true values
+                # count only REAL dropped events — [E_max] padding rows
+                # carry zero weight and must not inflate the telemetry
+                bad = ((~valid_e) & (weights > 0.0)).astype(jnp.int32)
+                n_q = n_q.at[edges[:, 0]].add(bad)
+            elif consensus_mode == "gaussian":
+                # strict: poison the gathered copies (by src id, every
+                # ring slot) — the state's ring keeps the true values
+                with jax.named_scope("fault_guard"):
                     c = corrupt[None, :, None]
                     hm = jnp.where(
                         c, fill_mean.astype(hist_mean.dtype)[None, :, None],
@@ -525,6 +543,7 @@ class GossipEngine:
                         c, fill_rho.astype(hist_rho.dtype)[None, :, None],
                         hist_rho,
                     )
+                with jax.named_scope("consensus"):
                     post = consensus_flat_delayed(
                         post, W, active, edges, weights, lags,
                         hm, hr, state.round, wire_dtype=wire_dtype,
@@ -554,11 +573,12 @@ class GossipEngine:
                 state, batches, active, key
             )
             if consensus_mode == "gaussian":
-                d_all, s_all, w_all = _self_loops(dst, src, w_e, w_self)
-                post = consensus_flat_segments(
-                    post, d_all, s_all, w_all,
-                    active=active, wire_dtype=wire_dtype,
-                )
+                with jax.named_scope("consensus"):
+                    d_all, s_all, w_all = _self_loops(dst, src, w_e, w_self)
+                    post = consensus_flat_segments(
+                        post, d_all, s_all, w_all,
+                        active=active, wire_dtype=wire_dtype,
+                    )
             return finish(state, post, opt_state, step, active), losses
 
         def window_fn_segments_guarded(
@@ -577,34 +597,30 @@ class GossipEngine:
             )
             n_q = state.n_quarantined
             if consensus_mode == "gaussian":
-                c = corrupt[:, None]
-                mean_src = jnp.where(c, fill_mean[:, None], post.mean)
-                rho_src = jnp.where(c, fill_rho[:, None], post.rho)
-                if quarantine:
+                mean_src, rho_src = corrupt_fill(post, corrupt, fill_mean,
+                                                 fill_rho)
+            if consensus_mode == "gaussian" and quarantine:
+                with jax.named_scope("consensus"):
                     post, valid_e = consensus_flat_segments_quarantined(
                         post, dst, src, w_e, w_self, active=active,
                         mean_src=mean_src, rho_src=rho_src,
                         wire_dtype=wire_dtype,
                     )
-                    # count only REAL dropped edges — [E_max] padding slots
-                    # carry zero weight and must not inflate the telemetry
-                    bad = ((~valid_e) & (w_e > 0.0)).astype(jnp.int32)
-                    n_q = n_q.at[dst].add(bad)
-                else:
-                    # strict: the wire is trusted verbatim — the corrupted
-                    # sources' garbage reaches every receiving agent
+                # count only REAL dropped edges — [E_max] padding slots
+                # carry zero weight and must not inflate the telemetry
+                bad = ((~valid_e) & (w_e > 0.0)).astype(jnp.int32)
+                n_q = n_q.at[dst].add(bad)
+            elif consensus_mode == "gaussian":
+                # strict: the wire is trusted verbatim — the corrupted
+                # sources' garbage reaches every receiving agent
+                with jax.named_scope("consensus"):
                     d_all, s_all, w_all = _self_loops(dst, src, w_e, w_self)
                     merged = consensus_flat_segments(
                         dataclasses.replace(post, mean=mean_src, rho=rho_src),
                         d_all, s_all, w_all,
                         active=active, wire_dtype=wire_dtype,
                     )
-                    act = active[:, None]
-                    post = dataclasses.replace(
-                        post,
-                        mean=jnp.where(act, merged.mean, post.mean),
-                        rho=jnp.where(act, merged.rho, post.rho),
-                    )
+                    post = merged_rows(post, merged, active)
             new_state = finish(state, post, opt_state, step, active)
             return dataclasses.replace(new_state, n_quarantined=n_q), losses
 
@@ -761,8 +777,8 @@ class GossipEngine:
                    if (self.hist_slots or ppermute) else None)
             active = (np.asarray(spec_win.active) if spec_win is not None
                       else self._host_active(r, W, win))
-        W = jnp.asarray(W)
-        act = jnp.asarray(active)
+            W = jnp.asarray(W)
+            act = jnp.asarray(active)
         if self.hist_slots:
             # ONE fused jitted call: local phase + event-gather consensus
             # (dispatch-side wall clock; Session.round owns the synced span)

@@ -211,6 +211,7 @@ def make_local_step(
     appears only inside the model apply (``layout.unflatten``).
     """
 
+    @jax.named_scope("local_phase")
     def step_fn(state: BayesTrainState, prior: GaussianPosterior, batch, key):
         a = _n_agents(state.posterior)
         lr = lr_schedule(state.step)
@@ -254,8 +255,10 @@ def make_local_step(
         (_, losses), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.posterior
         )
-        updates, opt_state = opt.update(grads, state.opt_state, state.step, lr)
-        new_post = apply_updates(state.posterior, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, state.opt_state,
+                                            state.step, lr)
+            new_post = apply_updates(state.posterior, updates)
         loss = losses if nll_fn is not None else jnp.mean(losses)
         return (
             BayesTrainState(posterior=new_post, opt_state=opt_state, step=state.step + 1),
@@ -273,6 +276,7 @@ def make_consensus_step(cfg, W: jax.Array, wire_dtype=None):
     the exchanged (prec, prec*mu) — f32/None is bitwise uncompressed."""
     del cfg  # consensus is model-independent
 
+    @jax.named_scope("consensus")
     def step_fn(posterior: GaussianPosterior) -> GaussianPosterior:
         return consensus_all_agents(posterior, W, wire_dtype=wire_dtype)
 

@@ -6,11 +6,11 @@ Three pillars, one contract:
 * ``obs.metrics`` — the unified ``MetricsRegistry`` (counters / gauges /
   histograms with labels, JSONL event sink, Prometheus-style exporter);
 * ``obs.trace`` — nested wall-clock spans with compile-vs-warm
-  attribution (plus the ``CompileWarmTimer`` / ``median_us`` bench
-  helpers the benchmarks build on);
-* ``obs.convergence`` + ``obs.roofline`` — theory-vs-measured: live
-  network disagreement / KL against ``core.theory``'s predicted decay,
-  measured window time against the ``launch.costmodel`` rooflines.
+  attribution, each also a profiler annotation when enabled (plus the
+  ``CompileWarmTimer`` / ``median_us`` bench helpers the benchmarks build
+  on);
+* ``obs.convergence`` — theory-vs-measured: live network disagreement /
+  KL against ``core.theory``'s predicted decay.
 
 The contract: observability is READ-ONLY and OFF by default.  With
 ``ObsSpec`` unset a run is bitwise identical to an uninstrumented build
@@ -32,11 +32,6 @@ from repro.obs.metrics import (
     JsonlSink,
     MetricsRegistry,
 )
-from repro.obs.roofline import (
-    attainment,
-    consensus_attainment,
-    window_attainment,
-)
 from repro.obs.trace import (
     CompileWarmTimer,
     Tracer,
@@ -52,9 +47,6 @@ __all__ = [
     "Histogram",
     "JsonlSink",
     "MetricsRegistry",
-    "attainment",
-    "consensus_attainment",
-    "window_attainment",
     "CompileWarmTimer",
     "Tracer",
     "compile_warm_split",

@@ -23,8 +23,14 @@ This module promotes that discipline into a reusable API:
 Spans measure HOST wall-clock at the dispatch boundary.  Calls that return
 before the device finishes (jax async dispatch) are only fully counted
 when something downstream synchronizes — ``Session.round`` does
-(``np.asarray(losses)``), so the ``session.round`` span is end-to-end
-accurate; inner engine spans are dispatch-side and documented as such.
+(``np.asarray(losses)``, under its ``session.sync`` span), so the
+``session.round`` span is end-to-end accurate; inner engine spans are
+dispatch-side and documented as such.
+
+An enabled tracer's span also opens a ``jax.profiler.TraceAnnotation``
+under the span's bare name, so a profiler trace shows the program's spans
+on the same clock as the device's operations (the attributes stay in the
+span's own record: an annotation carries them in its name).
 """
 from __future__ import annotations
 
@@ -33,6 +39,8 @@ import dataclasses
 import json
 import time
 from typing import Any, Callable
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -73,9 +81,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    """An open span: closes itself into the tracer buffer on ``__exit__``."""
+    """An open span: closes itself into the tracer buffer on ``__exit__``,
+    inside a profiler annotation of the same (bare) name."""
 
-    __slots__ = ("tracer", "name", "attrs", "t0")
+    __slots__ = ("tracer", "name", "attrs", "t0", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self.tracer = tracer
@@ -83,6 +92,8 @@ class _LiveSpan:
         self.attrs = attrs
 
     def __enter__(self):
+        self.annotation = TraceAnnotation(self.name)
+        self.annotation.__enter__()
         self.tracer._depth += 1
         self.t0 = time.perf_counter()
         return self
@@ -98,6 +109,7 @@ class _LiveSpan:
             depth=tr._depth,
             attrs=self.attrs,
         ))
+        self.annotation.__exit__(*exc)
         return False
 
 

@@ -50,11 +50,14 @@ def free_energy(
     ``kl_scale`` implements minibatch KL reweighting (1/num_batches in [10])
     so that one epoch of minibatch steps applies the KL once in expectation.
     """
-    kl = kl_gaussian(post, prior)
+    with jax.named_scope("kl"):
+        kl = kl_gaussian(post, prior)
 
     def one(k):
-        theta = post.sample(k)
-        return nll_fn(theta, batch)
+        with jax.named_scope("sample"):
+            theta = post.sample(k)
+        with jax.named_scope("nll"):
+            return nll_fn(theta, batch)
 
     keys = jax.random.split(key, n_samples)
     enll = jnp.mean(jax.vmap(one)(keys))
@@ -103,8 +106,9 @@ def local_vi_steps(
         loss, grads = free_energy_and_grad(
             post, prior, nll_fn, batch, k, n_samples, kl_scale
         )
-        updates, opt_state = opt.update(grads, opt_state, step, lr)
-        post = apply_updates(post, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, step, lr)
+            post = apply_updates(post, updates)
         return (post, opt_state, step + 1), loss
 
     (post, opt_state, _), losses = jax.lax.scan(

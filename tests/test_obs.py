@@ -9,12 +9,16 @@ Pins the layer's two-sided contract plus the unit behavior of each pillar:
 * **Namespaced telemetry** — ``evaluate()`` puts engine telemetry under
   ``out["engine"]``; a telemetry key can never clobber a metric key
   (regression for the pre-obs ``out.update(...)`` merge).
-* Registry / exporter / tracer / convergence-tracker / roofline units,
-  and the ``ObsSpec`` doc + checkpoint round trip.
+* Registry / exporter / tracer / convergence-tracker units, and the
+  ``ObsSpec`` doc + checkpoint round trip.
+* **One clock** — an enabled tracer's spans are profiler annotations too,
+  and every round program carries the layer scopes a device trace is read
+  by (``local_phase``, ``optimizer``, ``consensus``, ``fault_guard``).
 """
 import dataclasses
 import json
 import math
+import re
 
 import jax
 import numpy as np
@@ -28,11 +32,7 @@ from repro.obs.metrics import (
     escape_label_value,
     sanitize_name,
 )
-from repro.obs.roofline import (
-    attainment,
-    consensus_attainment,
-    window_attainment,
-)
+from repro.obs import trace as trace_mod
 from repro.obs.trace import CompileWarmTimer, Tracer
 
 # ---------------------------------------------------------------------------
@@ -198,6 +198,47 @@ def test_tracer_flush_is_incremental(tmp_path):
     assert tr.flush() == 1
 
 
+class _StubAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs enter/exit."""
+
+    log: list = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.kw))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.kw))
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    monkeypatch.setattr(_StubAnnotation, "log", [])
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", _StubAnnotation)
+    return _StubAnnotation.log
+
+
+def test_enabled_tracer_spans_are_annotations_with_bare_names(annotations):
+    tr = Tracer(enabled=True)
+    with tr.span("outer", round=3):
+        with tr.span("inner", impl="masked"):
+            pass
+    # attributes stay in the span record; the annotation name is constant
+    assert annotations == [("enter", "outer", {}), ("enter", "inner", {}),
+                           ("exit", "inner", {}), ("exit", "outer", {})]
+    assert [s.attrs for s in tr.spans] == [{"impl": "masked"}, {"round": 3}]
+
+
+def test_disabled_tracer_opens_no_annotation(annotations):
+    tr = Tracer(enabled=False)
+    with tr.span("outer", round=3):
+        with tr.span("inner"):
+            pass
+    assert annotations == [] and tr.spans == []
+
+
 def test_compile_warm_timer_accumulates():
     t = CompileWarmTimer()
     with t.compile():
@@ -258,30 +299,6 @@ def test_tracker_series_columns():
     cols = tracker.series()
     assert cols["round"] == [0]
     assert cols["disagreement"] == [0.0]
-
-
-# ---------------------------------------------------------------------------
-# roofline attainment
-
-
-def test_attainment_ratio_and_degenerate():
-    assert attainment(100.0, 50e-6) == pytest.approx(0.5)
-    assert attainment(0.0, 1.0) == 0.0
-    assert attainment(1.0, 0.0) == 0.0
-
-
-def test_consensus_and_window_attainment():
-    a = consensus_attainment(1e4, n_agents=8, n_params=1 << 16)
-    # modeled best-case never beats a measured CPU time
-    assert 0.0 < a["attainment"] < 1.0
-    assert a["modeled_us"] == pytest.approx(a["attainment"] * 1e4)
-    w = window_attainment(1e4, n_agents=8, n_params=1 << 16,
-                          n_participating=4)
-    assert 0.0 < w["attainment"] < 1.0
-    assert w["participating_fraction"] == pytest.approx(0.5)
-    with pytest.raises(ValueError, match="unknown"):
-        window_attainment(1e4, n_agents=8, n_params=1 << 16,
-                          n_participating=4, strategy="nope")
 
 
 # ---------------------------------------------------------------------------
@@ -408,6 +425,99 @@ def test_obs_gossip_engine_counters_and_spans():
     assert reg.gauge("gossip.jit_traces").value() == s.engine.n_traces == 1
     names = {sp.name for sp in s.obs.tracer.spans}
     assert "gossip.window" in names
+
+
+def test_round_spans_nest_inside_session_round():
+    """The host's round: schedule, batches and the wait for the losses
+    (``session.sync``) are spans inside ``session.round``."""
+    from repro.api import ObsSpec, build_session
+
+    s = build_session(_tiny_spec(obs=ObsSpec(enabled=True)))
+    s.round()  # the benchmark's path: the spec's schedule, built per round
+    s.round()
+    spans = s.obs.tracer.spans
+    rounds = [sp for sp in spans if sp.name == "session.round"]
+    assert len(rounds) == 2
+    for name in ("session.w_build", "session.batches", "session.sync"):
+        inner = [sp for sp in spans if sp.name == name]
+        assert len(inner) == 2, name
+        for sp, rd in zip(inner, rounds):
+            assert sp.depth == rd.depth + 1
+            assert rd.t0_us <= sp.t0_us
+            assert sp.t0_us + sp.dur_us <= rd.t0_us + rd.dur_us
+            assert sp.attrs["round"] == rd.attrs["round"]
+
+
+_FAULTS = {"crash_rate": 0.3, "recover_rate": 0.5, "corrupt_rate": 0.3,
+           "seed": 0}
+_CLOCK = {"kind": "poisson", "rate": 0.8, "seed": 0}
+
+
+def _program_spec(kind):
+    from repro.api import (
+        DataSpec, ExperimentSpec, InferenceSpec, RunSpec, TopologySpec,
+    )
+
+    inference = {}
+    if kind == "sync":
+        topo = TopologySpec(kind="bidirectional_ring", params={"n": 4})
+    elif kind == "masked":
+        topo = TopologySpec.gossip("bidirectional_ring", {"n": 4}, _CLOCK)
+    elif kind == "segments":
+        topo = TopologySpec.sparse("ring", clock=_CLOCK, n=4)
+    else:
+        topo = TopologySpec.gossip("bidirectional_ring", {"n": 4},
+                                   {**_CLOCK, "faults": _FAULTS})
+        inference = {"fault_policy": "quarantine"}
+    return ExperimentSpec(
+        topology=topo,
+        data=DataSpec(
+            dataset_params=dict(n_classes=3, dim=8, n_train_per_class=20),
+            partition="iid", partition_params=dict(n_agents=4),
+            batch_size=4, local_updates=2,
+        ),
+        inference=InferenceSpec(hidden=4, depth=1, lr=1e-2, **inference),
+        run=RunSpec(n_rounds=1, seed=0),
+    )
+
+
+def _round_program_op_names(spec) -> list:
+    """The op_name metadata of the compiled program one round runs."""
+    from repro.api import build_session
+
+    s = build_session(spec)
+    attr = "_round" if hasattr(s.engine, "_round") else "_window"
+    program, calls = getattr(s.engine, attr), []
+
+    def record(*args):
+        calls.append(args)
+        return program(*args)
+
+    setattr(s.engine, attr, record)
+    s.round()
+    text = program.lower(*calls[0]).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+def _has_scope(op_name: str, scope: str) -> bool:
+    # a scope is a whole path component, possibly wrapped: vmap(nll)
+    return re.search(rf"(^|[/(]){scope}($|[/)])", op_name) is not None
+
+
+@pytest.mark.parametrize("kind", ["sync", "masked", "segments",
+                                  "quarantine"])
+def test_round_program_carries_layer_scopes(kind):
+    names = _round_program_op_names(_program_spec(kind))
+    expected = ["local_phase", "optimizer", "sample", "nll", "kl",
+                "consensus"]
+    if kind == "quarantine":
+        expected += ["fault_guard", "agent_select"]
+    for scope in expected:
+        assert any(_has_scope(n, scope) for n in names), scope
+    # the optimizer and the MC sample are parts of the local phase
+    for scope in ("optimizer", "sample"):
+        assert all(_has_scope(n, "local_phase") for n in names
+                   if _has_scope(n, scope)), scope
 
 
 def test_evaluate_namespaces_engine_telemetry():
