@@ -597,6 +597,65 @@ def consensus_flat_delayed(
 _SEGMENT_GATHER_ELEMS = 1 << 24
 
 
+def segments_mode(n_agents: int, slots: int | None, wire_dtype=None) -> str:
+    """The execution ``consensus_flat_segments`` runs for ``mode=None``.
+
+    "pallas" — the destination-major row gather (``gather_tables`` into
+    ``kernels.consensus.consensus_fused_masked_sparse``) — on TPU, when the
+    caller bounds every row's entries by ``slots``, the [N, slots] tables
+    fit the kernel's scalar memory (N x slots <= ``SPARSE_TABLE_ENTRIES``)
+    and the wire is not f16 (which the compiled kernels refuse).  "xla" —
+    the blocked segment sum — everywhere else: off TPU, without a row
+    bound, and at N = 10^4+ populations."""
+    from repro.kernels.consensus import SPARSE_TABLE_ENTRIES
+    from repro.kernels.dispatch import on_tpu
+
+    if (slots is not None and on_tpu()
+            and n_agents * slots <= SPARSE_TABLE_ENTRIES
+            and canonical_wire_dtype(wire_dtype) != jnp.float16):
+        return "pallas"
+    return "xla"
+
+
+def gather_tables(
+    dst: jax.Array,
+    src: jax.Array,
+    weights: jax.Array,
+    n_agents: int,
+    slots: int,
+    active: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """Destination-major ``[N, slots]`` (neighbour id, weight) tables of an
+    edge list, for the row-gather kernel.
+
+    Row i lists the sources of i's nonzero-weight edges in edge order —
+    the callers append the self-loops after the fired edges, so a row
+    reads: fired sources, then its own id carrying the self weight — then
+    its own id at weight 0 in every remaining slot, so consecutive padding
+    steps name one row and the kernel fetches it once.  Zero-weight pad
+    edges are dropped; a row whose ``active`` is false is all own id at
+    weight 0 (the kernel passes it through untouched).  ``slots`` must
+    bound every row's entry count: a longer row would lose its last
+    entries.  Built in-graph from a stable sort of the E keys and gathers —
+    no scatter, nothing O(N^2)."""
+    n_edges = dst.shape[0]
+    w = weights.astype(COMPUTE_DTYPE)
+    keep = w != 0.0
+    if active is not None:
+        keep = keep & (active > 0)[dst]
+    key = jnp.where(keep, dst.astype(jnp.int32), n_agents)
+    order = jnp.argsort(key, stable=True)
+    key_sorted = key[order]
+    rows = jnp.arange(n_agents + 1, dtype=jnp.int32)
+    bounds = jnp.searchsorted(key_sorted, rows)  # row i: [bounds[i], bounds[i+1])
+    start, count = bounds[:-1], jnp.diff(bounds)
+    slot = jnp.arange(slots, dtype=jnp.int32)[None, :]
+    real = slot < count[:, None]
+    at = order[jnp.minimum(start[:, None] + slot, n_edges - 1)]
+    nbr = jnp.where(real, src.astype(jnp.int32)[at], rows[:-1, None])
+    return nbr, jnp.where(real, w[at], 0.0)
+
+
 def consensus_flat_segments(
     posts: FlatPosterior,
     dst: jax.Array,
@@ -606,23 +665,37 @@ def consensus_flat_segments(
     active: jax.Array | None = None,
     block: int | None = None,
     wire_dtype=None,
+    slots: int | None = None,
+    mode: str | None = None,
 ) -> FlatPosterior:
-    """Edge-native eq. (6): segment-sum consensus over flat [E] edge arrays.
+    """Edge-native eq. (6): consensus over flat [E] edge arrays.
 
     The sparse-first counterpart of ``consensus_flat_reference`` — the graph
     arrives as ``(dst, src, weights)`` edge lists (self-loops INCLUDED, e.g.
-    ``SparseGraph.edge_arrays()``), never as a dense ``[N, N]`` W.  Per lane
-    block: gather each edge's source sufficient statistics, scatter-add
-    (``segment_sum``) into the destination rows
+    ``SparseGraph.edge_arrays()``), never as a dense ``[N, N]`` W:
 
         prec_out[i] = sum_{e: dst_e = i} w_e * prec_x[src_e]
         pm_out[i]   = sum_{e: dst_e = i} w_e * (prec * mu)_x[src_e]
 
     with the (prec, prec*mu) buffers rounded through ``wire_dtype`` at the
     exchange boundary exactly as in ``_eq6_block`` (structural no-op at
-    f32) and fp32 accumulation throughout.  Peak memory is O(E * block):
-    the default ``block`` shrinks with E so the gather intermediate stays
-    under ``_SEGMENT_GATHER_ELEMS`` elements — no path here is O(N^2).
+    f32) and fp32 accumulation throughout.
+
+    mode (``None``: ``segments_mode(N, slots, wire_dtype)``):
+      "xla"       per lane block, gather each edge's source statistics and
+                  scatter-add (``segment_sum``) them into the destination
+                  rows.  Peak memory O(E * block): the default ``block``
+                  shrinks with E so the gather intermediate stays under
+                  ``_SEGMENT_GATHER_ELEMS`` elements.
+      "pallas"    the destination-major row gather: ``gather_tables``
+                  (``slots`` entries a row, required) and one
+                  ``consensus_fused_masked_sparse`` call, in which each
+                  agent reads its sources' rows and its own, accumulates in
+                  fp32 VMEM and writes its row once; ``block`` is the
+                  kernel's lane block.
+      "interpret" the same kernel in the Pallas interpreter.
+    No mode is O(N^2).  The row gather accumulates a row's terms in edge
+    order, as the scatter does.
 
     Agrees with the dense reference elementwise to fp32 reduction-order
     tolerance on every wire dtype (the scatter accumulates in edge order,
@@ -635,6 +708,26 @@ def consensus_flat_segments(
     """
     wire_dtype = canonical_wire_dtype(wire_dtype)
     n, p = posts.mean.shape
+    if mode is None:
+        mode = segments_mode(n, slots, wire_dtype)
+    if mode in ("pallas", "interpret"):
+        from repro.kernels.consensus import consensus_fused_masked_sparse
+
+        if slots is None:
+            raise ValueError(
+                "the row-gather segments execution needs slots, a bound on "
+                "every row's entries (max in-degree + 1 with self-loops)"
+            )
+        nbr, wts = gather_tables(dst, src, weights, n, slots, active)
+        act = jnp.ones((n,), jnp.int32) if active is None else active
+        mean, rho = consensus_fused_masked_sparse(
+            nbr, wts, act, posts.mean, posts.rho, block=block,
+            interpret=(True if mode == "interpret" else None),
+            wire_dtype=wire_dtype,
+        )
+        return FlatPosterior(mean=mean, rho=rho, layout=posts.layout)
+    if mode != "xla":
+        raise ValueError(f"unknown consensus_flat_segments mode {mode!r}")
     n_edges = int(dst.shape[0])
     w_e = weights[:, None].astype(COMPUTE_DTYPE)
     act = None if active is None else (active > 0)[:, None]
@@ -1083,6 +1176,8 @@ def consensus_flat_segments_quarantined(
     block: int | None = None,
     wire_dtype=None,
     bound: float = QUARANTINE_BOUND,
+    slots: int | None = None,
+    mode: str | None = None,
 ) -> tuple[FlatPosterior, jax.Array]:
     """Quarantine-guarded ``consensus_flat_segments`` for edge-native event
     windows (``gossip.clocks.SparseWindow``): validate every FIRED edge's
@@ -1105,7 +1200,8 @@ def consensus_flat_segments_quarantined(
     transmission is dropped from every receiving row while the sender's
     own self term falls back to its TRUE resident statistics
     (``_sanitized_sources``); an agent whose RESIDENT state is invalid
-    passes through unchanged.
+    passes through unchanged.  ``slots``/``mode`` pick the execution as in
+    ``consensus_flat_segments``.
     """
     wire_dtype = canonical_wire_dtype(wire_dtype)
     mean_src = posts.mean if mean_src is None else mean_src
@@ -1135,6 +1231,7 @@ def consensus_flat_segments_quarantined(
         jnp.concatenate([src, ar]),
         jnp.concatenate([w_e_g, w_self_g]),
         active=act_g, block=block, wire_dtype=wire_dtype,
+        slots=slots, mode=mode,
     )
     v_self = valid_self[:, None]
     return (

@@ -795,6 +795,10 @@ class SparseClock:
             self._ns_dst, weights=self._ns_w64, minlength=n
         )
         self._deg_offdiag = np.bincount(self._ns_dst, minlength=n)
+        #: the most non-self in-edges any one window can fire into a row
+        #: (the base graph's max in-degree): with the self term, the static
+        #: row length of the engine's gather tables
+        self.max_in_degree = int(self._deg_offdiag.max(initial=0))
         #: non-self directed edge count — the fired-index space of _fired
         self.n_edges = int(self._ns_dst.shape[0])
         self.e_max = max(self.n_edges, 1)

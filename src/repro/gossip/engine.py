@@ -35,10 +35,13 @@ sharded gossip, bitwise):
   the per-agent conserve-rule self-weight vector + the explicit host-exact
   active mask; no ``[N, N]`` anywhere) executed through
   ``core.flat.consensus_flat_segments`` with the self terms folded into
-  the segment-sum as N extra self-loop slots.  The only execution that
-  runs above ``SPARSE_DENSE_GUARD`` — Watts-Strogatz / Barabási-Albert
-  populations at N = 10^4+ gossip with O(E) host work and O(E·P) device
-  work per window;
+  the edge list as N extra self-loop slots — on TPU the destination-major
+  row-gather kernel (tables of ``clock.max_in_degree + 1`` slots a row),
+  elsewhere the XLA segment sum (``core.flat.segments_mode``; the
+  registry's ``gossip.consensus_path`` says which ran).  The only
+  execution that runs above ``SPARSE_DENSE_GUARD`` — Watts-Strogatz /
+  Barabási-Albert populations at N = 10^4+ gossip with O(E) host work and
+  O(E·P) device work per window;
 * delivery latency (a ``DelayedClock`` in the spec) — events merge the SRC
   POSTERIOR AS OF FIRE TIME from a bounded ``[K, N, P]`` posterior history
   ring buffer carried in ``GossipState`` (K = max_delay + 1; slot
@@ -104,6 +107,7 @@ from repro.core.flat import (
     consensus_flat_segments,
     consensus_flat_segments_quarantined,
     make_flat_nll,
+    segments_mode,
 )
 from repro.core.numerics import canonical_wire_dtype, wire_dtype_name
 from repro.core.simulated import init_network, network_local_steps
@@ -244,9 +248,7 @@ class GossipEngine:
         if impl == "auto":
             impl = "segments" if sparse_clock else "masked"
         self.consensus_impl = impl
-        # the dense masked window runs the Pallas kernels on TPU; segments,
-        # ppermute and the delayed event-gather are XLA executions
-        self.pallas_consensus = impl == "masked" and not self.hist_slots
+        self.gather_slots = self.segments_mode = None
         if impl == "segments":
             if not sparse_clock:
                 raise ValueError(
@@ -261,6 +263,13 @@ class GossipEngine:
                     "consensus; mean_only (the FedAvg baseline) runs on "
                     "the dense masked path"
                 )
+            # a window row holds at most the base graph's in-degree of
+            # fired edges plus the self term: the gather tables' static
+            # width, so one trace serves every window
+            self.gather_slots = self.clock.max_in_degree + 1
+            self.segments_mode = segments_mode(
+                n_agents, self.gather_slots, self.wire_dtype
+            )
         elif sparse_clock:
             # dense view of a sparse clock: legal below the guard (the
             # segments-vs-masked equivalence ladder trains on exactly this),
@@ -280,6 +289,13 @@ class GossipEngine:
                     f"SPARSE_DENSE_GUARD={SPARSE_DENSE_GUARD} "
                     "(use consensus_impl='segments')"
                 )
+        # the dense masked window runs the Pallas kernels on TPU, and so do
+        # the segments windows where they run the row gather; ppermute and
+        # the delayed event-gather are XLA executions
+        self.pallas_consensus = (
+            (impl == "masked" and not self.hist_slots)
+            or self.segments_mode == "pallas"
+        )
         self._mesh = None
         if self.consensus_impl == "ppermute":
             if self.max_delay > 0:
@@ -313,6 +329,7 @@ class GossipEngine:
         policy, consensus_mode = self.local_policy, self.consensus_mode
         hist_slots = self.hist_slots
         wire_dtype, hist_dtype = self.wire_dtype, self.hist_dtype
+        seg_exec = dict(slots=self.gather_slots, mode=self.segments_mode)
         merge_in_jit = self.consensus_impl != "ppermute"
         quarantine = self.quarantine
         # structural gate: with no fault model and the strict policy the
@@ -577,7 +594,7 @@ class GossipEngine:
                     d_all, s_all, w_all = _self_loops(dst, src, w_e, w_self)
                     post = consensus_flat_segments(
                         post, d_all, s_all, w_all,
-                        active=active, wire_dtype=wire_dtype,
+                        active=active, wire_dtype=wire_dtype, **seg_exec,
                     )
             return finish(state, post, opt_state, step, active), losses
 
@@ -604,7 +621,7 @@ class GossipEngine:
                     post, valid_e = consensus_flat_segments_quarantined(
                         post, dst, src, w_e, w_self, active=active,
                         mean_src=mean_src, rho_src=rho_src,
-                        wire_dtype=wire_dtype,
+                        wire_dtype=wire_dtype, **seg_exec,
                     )
                 # count only REAL dropped edges — [E_max] padding slots
                 # carry zero weight and must not inflate the telemetry
@@ -618,7 +635,7 @@ class GossipEngine:
                     merged = consensus_flat_segments(
                         dataclasses.replace(post, mean=mean_src, rho=rho_src),
                         d_all, s_all, w_all,
-                        active=active, wire_dtype=wire_dtype,
+                        active=active, wire_dtype=wire_dtype, **seg_exec,
                     )
                     post = merged_rows(post, merged, active)
             new_state = finish(state, post, opt_state, step, active)
@@ -756,7 +773,7 @@ class GossipEngine:
             )
         with _span(obs, "gossip.window", impl="segments", round=r):
             out = self._window(state, batches, *args, key, *extra)
-        self._obs_after_window(obs)
+        self._obs_after_window(obs, W)
         return out
 
     def run_round(self, state, batches, W, key):
@@ -807,8 +824,9 @@ class GossipEngine:
         self._obs_after_window(obs)
         return out
 
-    def _obs_after_window(self, obs) -> None:
-        """Registry bookkeeping after one window (host-side, pure observer)."""
+    def _obs_after_window(self, obs, win=None) -> None:
+        """Registry bookkeeping after one window (host-side, pure observer).
+        ``win`` is the edge-native window a segments execution just ran."""
         if obs is None:
             return
         obs.registry.counter(
@@ -817,6 +835,21 @@ class GossipEngine:
         obs.registry.gauge(
             "gossip.jit_traces", "distinct window traces (retrace telemetry)"
         ).set(self.n_traces)
+        if win is None or self.consensus_mode != "gaussian":
+            return
+        gather = self.segments_mode != "xla"
+        obs.registry.counter(
+            "gossip.consensus_path",
+            "edge-native windows by consensus execution",
+        ).inc(path="row_gather" if gather else "segment_sum")
+        if gather:
+            # real table entries (fired edges and the merging rows' self
+            # terms) over the N x D slots the kernel's grid walks
+            real = win.n_events + int(np.count_nonzero(win.active))
+            obs.registry.gauge(
+                "gossip.gather_slot_fill",
+                "real entries of the row-gather tables over N x D",
+            ).set(real / (self.n_agents * self.gather_slots))
 
     def _ppermute_consensus(self, state, losses, W, win, extra):
         """The host-level sharded consensus dispatch (the one window

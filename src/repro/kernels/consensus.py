@@ -31,17 +31,26 @@ Three kernels, all computing
   agent's own row), giving HBM traffic proportional to the window's
   active-edge fraction (``launch.costmodel.gossip_window_roofline``).
 
-The padded neighbor tables both sparse kernels scalar-prefetch come from
-THE one CSR construction — ``core.graphs.SparseGraph.neighbor_tables()``
+The padded neighbor tables of a static topology come from THE one CSR
+construction — ``core.graphs.SparseGraph.neighbor_tables()``
 (``core.flat.neighbor_tables`` is its dense-W bridge) — so the kernel view
-of a topology can never disagree with the graph layer's.  The [N, N]-free
-counterpart for N = 10^4+ populations is ``core.flat
-.consensus_flat_segments``: a segment-sum over ``SparseGraph.edge_arrays()``
-[E] edge lists with the identical exchange-boundary wire contract.  It
-stays an XLA scatter path by design — TPU Pallas has no efficient
-data-dependent scatter primitive, and at deg(i) << N the gather/segment-sum
-is memory-bound XLA already handles well — while these Pallas kernels own
-the dense/VMEM-resident regime (N <= a few thousand).
+of a topology can never disagree with the graph layer's.
+
+Edge-native gossip windows (``core.flat.consensus_flat_segments``, [E]
+dst/src/weight lists, no [N, N] anywhere) run one of two executions:
+
+* on TPU, ``consensus_fused_masked_sparse`` as a destination-major row
+  GATHER: ``core.flat.gather_tables`` sorts the window's edges by
+  destination in-graph into [N, D] tables (D = the base graph's max
+  in-degree + 1, static), and each agent reads its fired sources' rows and
+  its own, accumulates in fp32 VMEM and writes its row once.  Nothing is
+  scattered.  XLA on TPU runs a data-dependent scatter-add one update at a
+  time: the ws512 window's blocked segment sum (43 lane blocks x 3,584
+  edge rows) took 104.6 ms of a 222 ms window on a v5e, 25x its HBM floor;
+  the gather reads each needed row once a window.
+* elsewhere — off TPU (CPU, the tests), for tables too large for the
+  scalar memory (N x D > ``SPARSE_TABLE_ENTRIES``, e.g. the N = 10^4
+  sweeps), or an f16 wire — the XLA blocked segment sum.
 
 Flat-buffer layout contract (shared with ``core.flat.FlatPosterior``):
   * axis 0 is the agent axis (N rows), axis 1 the flattened parameter axis
@@ -56,7 +65,8 @@ Flat-buffer layout contract (shared with ``core.flat.FlatPosterior``):
     lane dim, agents/neighbors ride sublanes.  The dense kernels' default
     BLOCK shrinks with N (``lane_block``) so the [N, BLOCK] tiles and the
     resident W fit the scoped VMEM; the row-gathering sparse kernels tile
-    the lane-dense view [N, P/128, 128] (``_sparse_call``);
+    the lane-dense view [N, P/128, 128] (``_sparse_call``), a whole row a
+    tile where it fits (``sparse_block``);
   * ``wire_dtype="f16"`` is refused by the compiled kernels: Mosaic cannot
     lower its round trip on TPU v5e (interpret mode still runs it).
 
@@ -105,6 +115,15 @@ LANES = 128  # TPU lane width: the sparse kernels view [N, P] as [N, P/128, 128]
 # of scoped VMEM at block 2048, i.e. ~11 tiles beside the resident W.
 _VMEM_LIMIT = 16 * 2**20
 _LIVE_TILES = 12
+# The row-gathering sparse kernels' fp32 tiles per grid step: the gathered
+# (mean, rho) and the written (mean, rho), double-buffered, plus the two
+# accumulators.
+_SPARSE_LIVE_TILES = 10
+# Largest N x D whose scalar-prefetched tables ([N * D] int32 ids and fp32
+# weights, [N] int32 mask) fit half the 1 MiB SMEM of a TPU v5e; a
+# described-v5e compile refuses 2-D [4096, 10] tables (padded to 128 words
+# a row, 2 MiB each).
+SPARSE_TABLE_ENTRIES = 1 << 16
 
 
 def lane_block(n: int, resident_bytes: int = 0) -> int:
@@ -354,8 +373,8 @@ def consensus_fused_masked(
 
 
 def _consensus_sparse_kernel(
-    nbr_ref,  # scalar-prefetch [N, D] int32 neighbor ids (self-padded)
-    wts_ref,  # scalar-prefetch [N, D] fp32 neighbor weights (0-padded)
+    nbr_ref,  # scalar-prefetch [N * D] int32 neighbor ids (self-padded)
+    wts_ref,  # scalar-prefetch [N * D] fp32 neighbor weights (0-padded)
     mean_ref,  # [BLOCK/128, 128] — row nbr[i, d], column tile j
     rho_ref,  # [BLOCK/128, 128]
     mean_out_ref,  # [BLOCK/128, 128] — row i, column tile j
@@ -367,7 +386,7 @@ def _consensus_sparse_kernel(
 ):
     i = pl.program_id(0)
     d = pl.program_id(2)
-    w = wts_ref[i, d]
+    w = wts_ref[i * pl.num_programs(2) + d]
 
     @pl.when(d == 0)
     def _init():
@@ -429,19 +448,38 @@ def consensus_fused_sparse(
     )
 
 
+def sparse_block(p: int) -> int:
+    """Lane block of the row-gathering sparse kernels for rows of ``p``
+    lanes: the whole row, padded to a multiple of 128, when the
+    ``_SPARSE_LIVE_TILES`` fp32 tiles of one grid step fit three quarters of
+    the scoped VMEM (a 199,210-lane row does: 10 x 0.8 MB), else the fewest
+    equal tiles that do (each a multiple of 8 x 128, the f32 tiling).  One
+    tile per row keeps the grid at N x D steps: at a 2048-lane block the
+    paper-width grid has ~100x more steps, each moving 8 KB."""
+    rows = -(-p // LANES)
+    fit = _VMEM_LIMIT * 3 // 4 // (_SPARSE_LIVE_TILES * 4 * LANES) // 8 * 8
+    if rows <= fit:
+        return rows * LANES
+    per_tile = -(-rows // -(-rows // fit))
+    return -(-per_tile // 8) * 8 * LANES
+
+
 def _sparse_call(kernel, prefetch, mean, rho, block, interpret):
     """Run a row-gathering sparse kernel over the lane-dense view
     ``[N, P/128, 128]``: grid ``(N, P // BLOCK, D)``, the scalar-prefetched
     ``prefetch`` tables (neighbor ids first) steer each step's input tile to
     row ``nbr[i, d]``, and two fp32 VMEM accumulators carry the sums over d.
     A (1, BLOCK) block of the 2-D buffer breaks the TPU (8, 128) tiling rule;
-    a (BLOCK/128, 128) tile of one squeezed row does not."""
+    a (BLOCK/128, 128) tile of one squeezed row does not.  The [N, D] tables
+    ride flattened: SMEM pads a 2-D table's last dim to 128 words, a 1-D
+    one holds N x D (``SPARSE_TABLE_ENTRIES``)."""
     n, p = mean.shape
     d = prefetch[0].shape[1]
-    block = DEFAULT_BLOCK if block is None else block
+    prefetch = tuple(t.reshape(-1) for t in prefetch)
+    block = sparse_block(p) if block is None else block
     mean, rho, pp = _pad_lanes(mean, rho, block)
     tile = (pl.squeezed, block // LANES, LANES)
-    src = pl.BlockSpec(tile, lambda i, j, k, nbr, *_: (nbr[i, k], j, 0))
+    src = pl.BlockSpec(tile, lambda i, j, k, nbr, *_: (nbr[i * d + k], j, 0))
     dst = pl.BlockSpec(tile, lambda i, j, k, *_: (i, j, 0))
     lane_dense = (n, pp // LANES, LANES)
     mean_out, rho_out = pl.pallas_call(
@@ -531,8 +569,8 @@ def payload_validity_fused(
 
 
 def _consensus_masked_sparse_kernel(
-    nbr_ref,  # scalar-prefetch [N, D] int32 neighbor ids (self-padded)
-    wts_ref,  # scalar-prefetch [N, D] fp32 weights (0-padded)
+    nbr_ref,  # scalar-prefetch [N * D] int32 neighbor ids (self-padded)
+    wts_ref,  # scalar-prefetch [N * D] fp32 weights (0-padded)
     act_ref,  # scalar-prefetch [N] int32 activity mask
     mean_ref,  # [BLOCK/128, 128] — row nbr[i, d], column tile j
     rho_ref,  # [BLOCK/128, 128]
@@ -545,25 +583,30 @@ def _consensus_masked_sparse_kernel(
 ):
     i = pl.program_id(0)
     d = pl.program_id(2)
-    w = wts_ref[i, d]
+    w = wts_ref[i * pl.num_programs(2) + d]
 
     @pl.when(d == 0)
     def _init():
         acc_prec[...] = jnp.zeros_like(acc_prec)
         acc_pm[...] = jnp.zeros_like(acc_pm)
 
-    sigma = jax.nn.softplus(rho_ref[...])
-    if wire_dtype == jnp.float32:
-        # pre-wire op order, verbatim — f32 stays bitwise identical
-        wp = w / (sigma * sigma)
-        acc_prec[...] += wp
-        acc_pm[...] += wp * mean_ref[...]
-    else:
-        prec = 1.0 / (sigma * sigma)
-        prec_x = wire_roundtrip(prec, wire_dtype)
-        pm_x = wire_roundtrip(prec * mean_ref[...], wire_dtype)
-        acc_prec[...] += w * prec_x
-        acc_pm[...] += w * pm_x
+    # a weight-0 slot (table padding, or any slot of an inactive row) adds
+    # nothing: skip its arithmetic, and the pipeline skips its fetch when
+    # it names the same row as the slot before
+    @pl.when(w != 0.0)
+    def _accumulate():
+        sigma = jax.nn.softplus(rho_ref[...])
+        if wire_dtype == jnp.float32:
+            # pre-wire op order, verbatim — f32 stays bitwise identical
+            wp = w / (sigma * sigma)
+            acc_prec[...] += wp
+            acc_pm[...] += wp * mean_ref[...]
+        else:
+            prec = 1.0 / (sigma * sigma)
+            prec_x = wire_roundtrip(prec, wire_dtype)
+            pm_x = wire_roundtrip(prec * mean_ref[...], wire_dtype)
+            acc_prec[...] += w * prec_x
+            acc_pm[...] += w * pm_x
 
     @pl.when(d == pl.num_programs(2) - 1)
     def _finish():

@@ -427,6 +427,39 @@ def test_obs_gossip_engine_counters_and_spans():
     assert "gossip.window" in names
 
 
+@pytest.mark.parametrize("path", ["segment_sum", "row_gather"])
+def test_obs_segments_consensus_path_counter(path, monkeypatch):
+    """``gossip.consensus_path`` counts the execution each edge-native
+    window's consensus ran: the XLA segment sum on the CPU, the row gather
+    where the dispatch picks it (steered here to the Pallas interpreter),
+    whose ``gossip.gather_slot_fill`` is the tables' share of real
+    entries."""
+    import dataclasses
+
+    from repro.api import ObsSpec, RunSpec, build_session
+    from repro.gossip import engine as engine_mod
+
+    if path == "row_gather":
+        monkeypatch.setattr(engine_mod, "segments_mode",
+                            lambda n, slots, wire=None: "interpret")
+    spec = dataclasses.replace(_program_spec("segments"),
+                               run=RunSpec(n_rounds=3, seed=0),
+                               obs=ObsSpec(enabled=True))
+    s = build_session(spec)
+    s.run()
+    reg = s.obs.registry
+    counts = reg.counter("gossip.consensus_path")
+    other = "segment_sum" if path == "row_gather" else "row_gather"
+    assert counts.value(path=path) == reg.counter("gossip.windows").value()
+    assert counts.value(path=path) == 3
+    assert counts.value(path=other) == 0
+    fill = reg.collect().get("gossip.gather_slot_fill")
+    if path == "row_gather":
+        assert 0.0 < fill <= 1.0
+    else:
+        assert fill is None
+
+
 def test_round_spans_nest_inside_session_round():
     """The host's round: schedule, batches and the wait for the losses
     (``session.sync``) are spans inside ``session.round``."""

@@ -28,6 +28,7 @@ from repro.core.flat import (
     FlatPosterior,
     consensus_flat_reference,
     consensus_flat_segments,
+    gather_tables,
     neighbor_tables,
 )
 from repro.core.graphs import (
@@ -271,6 +272,118 @@ def test_segments_blocked_matches_single_call():
     assert bool(jnp.all(whole.rho == blocked.rho))
 
 
+# -- the row-gather execution of the segment consensus -----------------------
+
+
+def _window_edges(n, *, pads, rate=0.6, seed=4, k=4):
+    """A Poisson window's fired edges on WS(n, k), zero-weight pads (all
+    aimed at row 0, as the clock pads) and the self-loops appended, the
+    engine's layout; plus the clock's row bound D and the active mask."""
+    clk = SparsePoissonClock(watts_strogatz_sparse(n, k=k, beta=0.3, seed=2),
+                             rate=rate, seed=seed)
+    win = clk.window(0)
+    e = win.n_events
+    ar = np.arange(n, dtype=np.int32)
+    zeros_i = np.zeros(pads, np.int32)
+    dst = np.concatenate([win.dst[:e], zeros_i, ar])
+    src = np.concatenate([win.src[:e], zeros_i, ar])
+    w = np.concatenate([win.weights[:e], np.zeros(pads, np.float32),
+                        win.self_weight.astype(np.float32)])
+    return dst, src, w, win.active.copy(), clk.max_in_degree + 1
+
+
+def _full_row_edges(n):
+    """Every edge of WS(n, 4) fired: the max-in-degree row is full."""
+    g = watts_strogatz_sparse(n, k=4, beta=0.3, seed=2)
+    dst, src, w = g.edge_arrays()
+    ns = dst != src
+    deg = np.bincount(dst[ns], minlength=n)
+    assert deg.max() > deg.min()  # ragged rows, so one row is the longest
+    ar = np.arange(n, dtype=np.int32)
+    diag = np.zeros(n, np.float32)
+    diag[dst[~ns]] = w[~ns]
+    return (np.concatenate([dst[ns], ar]), np.concatenate([src[ns], ar]),
+            np.concatenate([w[ns], diag]), np.ones(n, bool),
+            int(deg.max()) + 1)
+
+
+def _gather_case(case):
+    n = 20
+    if case == "max_in_degree_row":
+        return n, 96, _full_row_edges(n)
+    dst, src, w, active, slots = _window_edges(n, pads=37)
+    if case == "all_inactive":
+        active[:] = False
+    elif case == "all_active":
+        # rows with no fired in-edge still merge their self term alone
+        active[:] = True
+    p = 200 if case == "p_not_lane_multiple" else 128
+    return n, p, (dst, src, w, active, slots)
+
+
+@pytest.mark.parametrize("wire", ["f32", "bf16"])
+@pytest.mark.parametrize("case", ["mixed_active_with_pads",
+                                  "max_in_degree_row", "all_inactive",
+                                  "all_active", "p_not_lane_multiple"])
+def test_row_gather_matches_segment_sum(case, wire):
+    """The row-gather kernel (Pallas interpreter) against the XLA segment
+    sum: the same terms in the same edge order, fp32 accumulation;
+    zero-weight pads add nothing and inactive rows pass through bitwise."""
+    n, p, (dst, src, w, active, slots) = _gather_case(case)
+    posts = _posts(n, p, seed=8)
+    args = (posts, jnp.asarray(dst), jnp.asarray(src), jnp.asarray(w))
+    kw = dict(active=jnp.asarray(active), wire_dtype=wire)
+    ref = consensus_flat_segments(*args, mode="xla", **kw)
+    got = consensus_flat_segments(*args, slots=slots, mode="interpret", **kw)
+    assert got.mean.shape == (n, p)
+    np.testing.assert_allclose(np.asarray(got.mean), np.asarray(ref.mean),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got.rho), np.asarray(ref.rho),
+                               rtol=1e-5, atol=1e-6)
+    inact = ~active
+    np.testing.assert_array_equal(np.asarray(got.mean)[inact],
+                                  np.asarray(posts.mean)[inact])
+    np.testing.assert_array_equal(np.asarray(got.rho)[inact],
+                                  np.asarray(posts.rho)[inact])
+    if active.any():
+        assert not np.array_equal(np.asarray(got.mean)[active],
+                                  np.asarray(posts.mean)[active])
+
+
+def test_gather_tables_row_order_self_slot_and_dropped_pads():
+    """Row i: its fired sources in edge order, then its own id with the
+    self weight, then its own id at weight 0; zero-weight pads are gone,
+    and an inactive row is all own id at weight 0."""
+    n, slots = 5, 4
+    #        fired edges       pads    self-loops
+    dst = [3, 1, 3, 0, 1,     0, 0,   0, 1, 2, 3, 4]
+    src = [2, 4, 0, 4, 3,     0, 0,   0, 1, 2, 3, 4]
+    w = [.2, .1, .3, .4, .2,   0, 0,   .6, .7, 1, .5, 1]
+    active = np.array([True, True, False, True, False])
+    nbr, wts = gather_tables(jnp.asarray(dst), jnp.asarray(src),
+                             jnp.asarray(w, jnp.float32), n, slots,
+                             jnp.asarray(active))
+    np.testing.assert_array_equal(np.asarray(nbr), [
+        [4, 0, 0, 0],
+        [4, 3, 1, 1],
+        [2, 2, 2, 2],
+        [2, 0, 3, 3],
+        [4, 4, 4, 4],
+    ])
+    np.testing.assert_allclose(np.asarray(wts), np.float32([
+        [.4, .6, 0, 0],
+        [.1, .2, .7, 0],
+        [0, 0, 0, 0],
+        [.2, .3, .5, 0],
+        [0, 0, 0, 0],
+    ]))
+    # without a mask every row keeps its nonzero entries
+    nbr_all, wts_all = gather_tables(jnp.asarray(dst), jnp.asarray(src),
+                                     jnp.asarray(w, jnp.float32), n, slots)
+    assert list(np.asarray(nbr_all)[2]) == [2, 2, 2, 2]
+    assert list(np.asarray(wts_all)[2]) == [1, 0, 0, 0]
+
+
 # -- thinned-Poisson clocks --------------------------------------------------
 
 
@@ -511,14 +624,29 @@ def _clocked_spec(n, impl, wire="f32", n_rounds=2, **clock_extra):
     )
 
 
-@pytest.mark.parametrize("wire", ["f32", "bf16", "f16"])
-def test_segments_engine_matches_masked_engine_per_wire(wire):
+@pytest.mark.parametrize("wire,gather", [
+    pytest.param("f32", False, id="f32"),
+    pytest.param("bf16", False, id="bf16"),
+    pytest.param("f16", False, id="f16"),
+    pytest.param("f32", True, id="f32-row_gather"),
+    pytest.param("bf16", True, id="bf16-row_gather"),
+])
+def test_segments_engine_matches_masked_engine_per_wire(wire, gather,
+                                                        monkeypatch):
     """Below SPARSE_DENSE_GUARD the same SparseWindow executes two ways:
     edge-native segments, or densified (w_eff) through the masked engine.
     Both cast payloads to the wire dtype BEFORE reduction, so they sum the
     same quantized values — only edge-order vs column-order differs, which
-    is fp32 reduction tolerance, not wire tolerance."""
+    is fp32 reduction tolerance, not wire tolerance.  The segments engine
+    runs the XLA segment sum here, or (``gather``) the row-gather kernel
+    its TPU dispatch picks, in the Pallas interpreter."""
+    if gather:
+        from repro.gossip import engine as engine_mod
+
+        monkeypatch.setattr(engine_mod, "segments_mode",
+                            lambda n, slots, wire=None: "interpret")
     s_seg = build_session(_clocked_spec(16, "segments", wire=wire))
+    assert s_seg.engine.segments_mode == ("interpret" if gather else "xla")
     s_msk = build_session(_clocked_spec(16, "masked", wire=wire))
     s_seg.run()
     s_msk.run()

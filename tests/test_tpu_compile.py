@@ -19,11 +19,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.flat import QUARANTINE_BOUND
+from repro.core.flat import QUARANTINE_BOUND, gather_tables
 from repro.kernels import consensus as kc
 
 P_MLP = 199_210  # 784-200-200-10 MLP
 DEGREE = 5  # torus in-degree + self
+# the paper_mlp.ws512 gossip window: N = 512 on WS(k = 6, beta = 0.1), max
+# in-degree 9 + self; 3,072 fired-edge slots + 512 self-loops
+WS_N, WS_SLOTS, WS_EDGES = 512, 10, 3_072 + 512
 
 
 @pytest.fixture(scope="module")
@@ -84,6 +87,60 @@ def test_kernel_compiles_for_v5e_at_paper_width(one_chip, name, n, wire):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _edge_gather(n, slots, edges, p, wire):
+    """The segments window's row-gather consensus, compiled: the in-graph
+    table build and the masked sparse kernel, as ``consensus_flat_segments``
+    runs them on TPU."""
+    def fn(dst, src, w, active, mean, rho):
+        nbr, wts = gather_tables(dst, src, w, n, slots, active)
+        return kc.consensus_fused_masked_sparse(
+            nbr, wts, active, mean, rho, interpret=False, wire_dtype=wire)
+
+    ids, buf = ((edges,), jnp.int32), ((n, p), jnp.float32)
+    return fn, [ids, ids, ((edges,), jnp.float32), ((n,), jnp.bool_), buf,
+                buf]
+
+
+@pytest.mark.parametrize("wire", ["f32", "bf16"])
+def test_edge_list_gather_compiles_for_v5e_at_paper_width(one_chip, wire):
+    """The ws512 window's consensus at N = 512, D = 10, P = 199,210: one
+    Pallas call whose lane tile is the whole row and fits the scoped VMEM
+    (a described-v5e compile refuses a tile that does not)."""
+    block = kc.sparse_block(P_MLP)
+    assert block % kc.LANES == 0 and block >= P_MLP
+    assert kc._SPARSE_LIVE_TILES * 4 * block <= kc._VMEM_LIMIT * 3 // 4
+    fn, shapes = _edge_gather(WS_N, WS_SLOTS, WS_EDGES, P_MLP, wire)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+    assert WS_N * WS_SLOTS <= kc.SPARSE_TABLE_ENTRIES
+
+
+def test_gather_tables_at_the_smem_bound_compile(one_chip):
+    """N x D = SPARSE_TABLE_ENTRIES: the scalar-prefetched tables still fit
+    the v5e's 1 MiB SMEM (a narrow row keeps the HBM side small)."""
+    n, slots = 8_192, kc.SPARSE_TABLE_ENTRIES // 8_192
+    fn, shapes = _edge_gather(n, slots, 4 * n, 1_024, "f32")
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("p", [128, 1_000, P_MLP, 400_000])
+def test_sparse_block_tiles_whole_rows_within_vmem(p):
+    """The sparse kernels' lane block: a multiple of 128 lanes, the whole
+    row while its live tiles fit 3/4 of the scoped VMEM, else equal tiles
+    of 8 x 128 multiples that do."""
+    block = kc.sparse_block(p)
+    assert block % kc.LANES == 0
+    assert kc._SPARSE_LIVE_TILES * 4 * block <= kc._VMEM_LIMIT * 3 // 4
+    if block < p:
+        # equal tiles: the padding is less than one 8 x 128 group a tile
+        tiles = -(-p // block)
+        assert block % (8 * kc.LANES) == 0
+        assert tiles * block - p < tiles * 8 * kc.LANES
+    else:
+        assert block - p < kc.LANES
 
 
 @pytest.mark.parametrize("name", KERNELS)
