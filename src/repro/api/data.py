@@ -14,7 +14,11 @@ from repro.api.spec import DataSpec
 from repro.data import linreg as linreg_mod
 from repro.data import partition as partition_mod
 from repro.data import synthetic
-from repro.data.pipeline import AgentDataset, make_round_batches
+from repro.data.pipeline import (
+    AgentDataset,
+    make_lm_batch_sampler,
+    make_round_batches,
+)
 
 _DATASETS = {
     "synthetic_classification": synthetic.make_synthetic_classification,
@@ -28,7 +32,7 @@ class DataBundle:
     """Concrete data behind a Session: sampler(key, round) -> batches pytree
     with leading [N, u, B] axes, plus the test set for ``evaluate``."""
 
-    kind: str  # "classification" | "linreg"
+    kind: str  # "classification" | "linreg" | "tokens"
     n_agents: int
     sampler: Callable[[jax.Array, int], Any]
     x_test: np.ndarray | None = None
@@ -56,6 +60,8 @@ def _partition(spec: DataSpec, ds) -> list:
 def build_data(spec: DataSpec, n_agents: int) -> DataBundle:
     if spec.dataset == "linreg":
         return _build_linreg(spec, n_agents)
+    if spec.dataset == "zipf_tokens":
+        return _build_tokens(spec, n_agents)
     ds = _DATASETS[spec.dataset](**dict(spec.dataset_params))
     shards = _partition(spec, ds)
     if len(shards) != n_agents:
@@ -114,3 +120,15 @@ def _build_linreg(spec: DataSpec, n_agents: int) -> DataBundle:
         test_phi=phi_t,
         test_y=y_t,
     )
+
+
+def _build_tokens(spec: DataSpec, n_agents: int) -> DataBundle:
+    """Token text drawn on the device every round: [N, u, B, seq_len]
+    tokens and next-token targets, iid Zipf ids per agent and step."""
+    p = dict(spec.dataset_params)
+    sampler = make_lm_batch_sampler(
+        p["vocab_size"], spec.batch_size, p["seq_len"], n_agents=n_agents,
+        local_updates=spec.local_updates, exponent=p.get("exponent", 1.2),
+    )
+    return DataBundle(kind="tokens", n_agents=n_agents, sampler=sampler,
+                      dim=p["seq_len"], n_classes=p["vocab_size"])

@@ -39,7 +39,7 @@ from repro.core.posterior import (
     consensus_full_cov,
     linreg_bayes_update,
 )
-from repro.core.simulated import init_network, make_round_fn
+from repro.core.simulated import init_network, make_round_fn, with_shared
 from repro.optim import Optimizer, adam, sgd
 from repro.optim.schedules import Schedule, constant_schedule, exponential_decay
 
@@ -111,7 +111,12 @@ class SimulatedEngine:
         )
 
     def run_round(self, state, batches, W, key):
-        return self._round(state, batches, jnp.asarray(W), key)
+        """One round; the nll's aux (the model's counters per agent) is
+        kept as ``last_aux`` for ``Session.round``'s telemetry."""
+        state, losses, self.last_aux = self._round(
+            state, with_shared(batches, self.model.shared), jnp.asarray(W),
+            key)
+        return state, losses
 
     def posterior(self, state) -> FlatPosterior:
         return state.posterior

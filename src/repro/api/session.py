@@ -57,13 +57,7 @@ def build_session(spec: ExperimentSpec) -> "Session":
     if spec.inference.method == "conjugate_linreg":
         engine: Engine = ConjugateLinregEngine(spec, data)
     else:
-        model = build_model(
-            spec.inference.model,
-            data.dim,
-            data.n_classes,
-            hidden=spec.inference.hidden,
-            depth=spec.inference.depth,
-        )
+        model = build_model(spec.inference, data, spec.run.seed)
         if spec.topology.kind == "gossip" or (
             spec.topology.kind == "sparse"
             and spec.topology.clock is not None
@@ -248,6 +242,9 @@ class Session:
             reg.histogram("session.loss_dist", "per-round loss").observe(
                 rec["loss"]
             )
+        aux = getattr(self.engine, "last_aux", None) or {}
+        if "expert_tokens" in aux:
+            self._obs_expert_tokens(reg, aux["expert_tokens"])
         if "n_crashed" in rec:
             reg.counter(
                 "session.crashed_agent_windows", "agent-windows down"
@@ -259,6 +256,22 @@ class Session:
             with obs.tracer.span("obs.convergence", round=rec["round"]):
                 stats = conv.update(self.posterior(), rec["round"])
             reg.ingest("convergence", stats)
+
+    def _obs_expert_tokens(self, reg, tokens) -> None:
+        """The round's tokens per routed expert (the router's counts, the
+        round's aux ``expert_tokens``, [N, n_moe, E]), summed over agents:
+        one ``model.expert_tokens{layer}`` observation per expert, and the
+        gauge ``model.expert_load_max``: the largest expert's count over
+        the mean, the worst layer."""
+        counts = np.asarray(tokens).sum(axis=0)  # [n_moe, E]
+        hist = reg.histogram("model.expert_tokens",
+                             "tokens per routed expert per round")
+        for layer, row in enumerate(counts):
+            for c in row:
+                hist.observe(float(c), layer=layer)
+        load = counts.max(axis=1) / np.maximum(counts.mean(axis=1), 1e-30)
+        reg.gauge("model.expert_load_max", "largest expert's tokens over "
+                  "the mean, worst layer, last round").set(float(load.max()))
 
     def run(
         self,
@@ -315,7 +328,7 @@ class Session:
         ``n_mc=0`` is the deterministic point estimate: one softmax at the
         posterior MEAN (the paper's L=1 serving fast path / the non-Bayesian
         confidence baseline) — no sampling, ``key`` ignored."""
-        if self.model is None:
+        if self.model is None or self.model.logits_fn is None:
             raise ValueError("predictive() requires a classification model")
         post = self.agent_posterior(agent)
         if n_mc == 0:
@@ -387,10 +400,12 @@ class Session:
         only published snapshots — call ``snapshot()`` first (and again
         whenever the served posterior should roll forward).  The attached
         server's telemetry shows up in ``evaluate()``."""
-        if self.model is None:
+        if self.model is None or self.model.logits_fn is None:
             raise ValueError(
                 "attach_server() requires a classification model (the "
-                "conjugate linreg engine has no serving path)"
+                "conjugate linreg engine has no serving path; an LM "
+                "posterior would need token-level decoding through the "
+                "frozen trunk, which repro.serve does not implement)"
             )
         from repro.serve import PredictiveServer
 
@@ -556,6 +571,12 @@ class Session:
                 for i in range(self.data.n_agents)
             ]
             return {"mse": mses, "avg_mse": float(np.mean(mses))}
+        if self.data.kind == "tokens":
+            raise NotImplementedError(
+                "Session.evaluate() on an LM posterior: the held-out token "
+                "NLL needs a held-out token stream and an apply of the "
+                "adapter posterior's mean through the frozen trunk, neither "
+                "of which exists yet")
         key = jax.random.key(99) if key is None else key
         yt = np.asarray(self.data.y_test)
         accs = []

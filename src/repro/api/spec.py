@@ -407,8 +407,12 @@ class DataSpec:
 
     dataset: ``synthetic_classification | mnist_like | fmnist_like``
     (classification stand-ins, ``dataset_params`` forwarded to
-    ``data.synthetic``) or ``linreg`` (paper Example 1,
-    ``dataset_params`` forwarded to ``data.linreg.make_linreg_task``).
+    ``data.synthetic``), ``linreg`` (paper Example 1,
+    ``dataset_params`` forwarded to ``data.linreg.make_linreg_task``) or
+    ``zipf_tokens`` (language-model text: every agent draws B sequences of
+    ``seq_len`` token ids per local step, iid Zipf(``exponent``, default
+    1.2) over ``vocab_size`` ids, on the device; ``partition`` stays
+    ``iid``).
 
     partition (classification only): ``iid | by_label | star | grid``
     (``partition_params`` forwarded to ``data.partition``).
@@ -424,8 +428,20 @@ class DataSpec:
     def validate(self) -> None:
         if self.dataset not in (
             "synthetic_classification", "mnist_like", "fmnist_like", "linreg",
+            "zipf_tokens",
         ):
             raise ValueError(f"unknown dataset {self.dataset!r}")
+        if self.dataset == "zipf_tokens":
+            extra = sorted(set(self.dataset_params)
+                           - {"vocab_size", "seq_len", "exponent"})
+            if extra or not {"vocab_size", "seq_len"} <= set(
+                    self.dataset_params):
+                raise ValueError(
+                    "zipf_tokens takes dataset_params vocab_size, seq_len "
+                    f"and optionally exponent; got {sorted(self.dataset_params)}")
+            if self.partition != "iid" or self.partition_params:
+                raise ValueError("zipf_tokens draws iid text per agent; "
+                                 "partition must be 'iid' with no params")
         if self.dataset != "linreg" and self.partition not in (
             "iid", "by_label", "star", "grid",
         ):
@@ -442,6 +458,17 @@ class InferenceSpec:
     from the registry (``api.models``) — the NN experiments.
     method="conjugate_linreg": the exact conjugate full-covariance update of
     Example 1 (eq. 2); model/optimizer fields are ignored.
+
+    The model sets which parameters carry the mean-field posterior:
+    ``model="mlp"`` puts it on every parameter; ``model="lm"`` on low-rank
+    adapters (LoRA) of rank ``lora_rank``, scaled alpha / r = ``lora_alpha
+    / lora_rank``, on the four latent-attention projections of every layer
+    of the registry architecture ``arch``, cut to its first ``n_layers``
+    layers when given.  The LM's trunk is frozen, seeded from the run's
+    seed, held once in bfloat16 and shared by every agent (BLoB, arXiv:
+    2406.11675): a full posterior would keep 24 B per parameter per agent
+    (mean, rho and Adam's two moments), beyond one chip for any registry
+    architecture at its published widths.
 
     ``consensus_impl`` picks the EXECUTION of the (gossip) consensus, not
     its math — every impl is bit-identical by test:
@@ -497,10 +524,28 @@ class InferenceSpec:
     history_dtype: str | None = None  # delayed gossip ring residency (None=f32)
     fault_policy: str = "strict"  # strict | quarantine: exchange validation
     prior_var: float = 0.5  # conjugate_linreg prior N(0, prior_var I)
+    arch: str | None = None  # model="lm": a repro.configs registry name
+    n_layers: int | None = None  # model="lm": depth cut (None = published)
+    lora_rank: int = 16
+    lora_alpha: float = 32.0
 
     def validate(self) -> None:
         if self.method not in ("bbb", "conjugate_linreg"):
             raise ValueError(f"unknown inference method {self.method!r}")
+        if self.model not in ("mlp", "lm"):
+            raise ValueError(f"unknown model {self.model!r}; known: mlp | lm")
+        if self.model == "lm":
+            if self.arch is None:
+                raise ValueError("model='lm' needs an arch (a repro.configs "
+                                 "registry name)")
+            if self.lora_rank <= 0 or self.lora_alpha <= 0:
+                raise ValueError("lora_rank and lora_alpha must be positive")
+            if self.n_layers is not None and self.n_layers <= 0:
+                raise ValueError("n_layers must be a positive layer count")
+        elif self.arch is not None or self.n_layers is not None:
+            raise ValueError(
+                "arch and n_layers describe the LM family "
+                f"(model='lm'); model={self.model!r} would ignore them")
         if self.optimizer not in ("adam", "sgd"):
             raise ValueError(f"unknown optimizer {self.optimizer!r}")
         if self.consensus not in ("gaussian", "mean_only", "none"):
@@ -679,6 +724,13 @@ class ExperimentSpec:
             raise ValueError("dataset='linreg' requires method='conjugate_linreg'")
         if self.inference.method == "conjugate_linreg" and self.run.engine == "launch":
             raise ValueError("the launch engine backs Bayes-by-Backprop inference only")
+        if (self.inference.model == "lm") != (self.data.dataset == "zipf_tokens"):
+            raise ValueError("model='lm' trains on token data "
+                             "(dataset='zipf_tokens'), and only it does")
+        if self.inference.model == "lm" and self.run.engine == "launch":
+            raise ValueError("the launch engine has no shared frozen trunk; "
+                             "model='lm' runs on the simulated or gossip "
+                             "engine")
         # "gossiping" = the GossipEngine drives the run: a dense gossip
         # topology, or a sparse topology with an edge-native clock attached
         gossiping = (self.topology.kind == "gossip"

@@ -15,14 +15,39 @@ Block kinds:
   rglru       RecurrentGemma recurrent block (conv1d + RG-LRU) + MLP
   enc_attn    bidirectional encoder attention + MLP (whisper encoder)
   dec_attn    causal self-attn + cross-attn + MLP (whisper decoder)
+  mla         pre-norm latent attention (MLA, DeepSeek-V2) + SwiGLU MLP
+  mla_moe     pre-norm latent attention + DeepSeekMoE (routed + shared
+              experts, dropless)
+
+``first_k_dense`` leading ``mla`` layers precede the periods (DeepSeek's
+``first_k_dense_replace``); they count in ``n_layers``.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN RoPE scaling (Peng et al., arXiv:2309.00071) as DeepSeek-V2
+    configures it (``rope_scaling`` with ``type: yarn``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def get_mscale(factor: float, mscale: float) -> float:
+        """0.1 * mscale * ln(factor) + 1 (1 when the factor does not scale)."""
+        return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,6 +67,20 @@ class ModelConfig:
     top_k: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # DeepSeekMoE (``mla_moe``, always dropless): routed expert width (0 ->
+    # d_ff), shared experts (one SwiGLU of n_shared_experts * moe_d_ff),
+    # greedy top-k over softmax scores, optionally renormalized, scaled
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    first_k_dense: int = 0  # leading dense (``mla``) layers
+    # --- latent attention (MLA) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: YarnScaling | None = None
     # --- attention options ---
     qk_norm: bool = False
     sliding_window: int = 0  # 0 = full; >0 = window size for local_attn
@@ -72,13 +111,22 @@ class ModelConfig:
         return _round_up(self.vocab_size, 256)
 
     @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def n_periods(self) -> int:
-        return self.n_layers // len(self.pattern)
+        return (self.n_layers - self.first_k_dense) // len(self.pattern)
 
     @property
     def tail(self) -> tuple[str, ...]:
-        """Pattern remainder when n_layers % len(pattern) != 0."""
-        r = self.n_layers % len(self.pattern)
+        """Pattern remainder when the layers after the leading dense ones
+        are not a whole number of periods."""
+        r = (self.n_layers - self.first_k_dense) % len(self.pattern)
         return self.pattern[:r]
 
     @property
@@ -96,14 +144,22 @@ class ModelConfig:
         assert self.d_model % self.n_heads == 0 or self.head_dim, self.name
         assert self.n_heads % self.n_kv_heads == 0, self.name
         if self.n_experts:
-            assert self.top_k > 0 and "moe" in self.pattern, self.name
-        assert self.n_periods * len(self.pattern) + len(self.tail) == self.n_layers
+            assert self.top_k > 0 and (
+                "moe" in self.pattern or "mla_moe" in self.pattern), self.name
+        if self.is_mla:
+            assert self.qk_rope_head_dim % 2 == 0, self.name
+            assert set(self.pattern) <= {"mla", "mla_moe"}, self.name
+        assert (self.first_k_dense + self.n_periods * len(self.pattern)
+                + len(self.tail) == self.n_layers)
 
     def reduced(self, **overrides) -> "ModelConfig":
         """Smoke-test variant: 2 layers (1 period of a truncated pattern or
         2 periods of single-kind), d_model<=256, <=4 experts."""
         kinds = list(dict.fromkeys(self.pattern))  # preserve kind coverage
         pattern = tuple(kinds[:2]) if len(kinds) >= 2 else (kinds[0],) * 2
+        first_k = min(self.first_k_dense, 1)
+        if first_k:  # the leading dense layer plus one period
+            pattern = pattern[:1]
         d_model = min(self.d_model, 256)
         n_heads = min(self.n_heads, 4)
         kv = max(1, min(self.n_kv_heads, n_heads))
@@ -112,7 +168,8 @@ class ModelConfig:
         base = dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            n_layers=len(pattern),
+            n_layers=first_k + len(pattern),
+            first_k_dense=first_k,
             pattern=pattern,
             d_model=d_model,
             n_heads=n_heads,
@@ -122,6 +179,12 @@ class ModelConfig:
             vocab_size=min(self.vocab_size, 512),
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            moe_d_ff=min(self.moe_d_ff, 128),
+            n_shared_experts=min(self.n_shared_experts, 1),
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
             encoder_layers=min(self.encoder_layers, 2),
             encoder_seq=min(self.encoder_seq, 16),
             n_patches=min(self.n_patches, 16) if self.n_patches else 0,

@@ -4,6 +4,7 @@ from __future__ import annotations
 from repro.configs.base import ModelConfig
 from repro.configs import (
     deepseek_7b,
+    deepseek_v2_lite,
     granite_20b,
     mistral_nemo_12b,
     olmoe_1b_7b,
@@ -29,6 +30,7 @@ ARCHS: dict[str, ModelConfig] = {
         pixtral_12b.CONFIG,
         mistral_nemo_12b.CONFIG,
         deepseek_7b.CONFIG,
+        deepseek_v2_lite.CONFIG,
         REPRO_100M,
     ]
 }

@@ -307,8 +307,8 @@ def make_flat_nll(nll_fn: Callable[[PyTree, Any], jax.Array], layout: FlatLayout
     """Wrap a pytree-parameter nll into one taking a flat theta [P] — the
     single model-apply-boundary conversion of the flat runtime."""
 
-    def flat_nll(theta_flat: jax.Array, batch: Any) -> jax.Array:
-        return nll_fn(layout.unflatten(theta_flat), batch)
+    def flat_nll(theta_flat: jax.Array, batch: Any, *shared) -> jax.Array:
+        return nll_fn(layout.unflatten(theta_flat), batch, *shared)
 
     return flat_nll
 

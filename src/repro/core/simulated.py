@@ -49,6 +49,27 @@ class NetworkState:
     round: jax.Array  # scalar communication-round counter
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class SharedBatches:
+    """A round's batches plus a pytree that every agent's nll reads, such as
+    a frozen trunk under per-agent adapters.  ``per_agent`` leaves carry the
+    leading [N, u] axes; ``shared`` is unbatched and reaches the model as
+    the nll's third argument, ``nll(theta, batch, shared)``: one copy in the
+    round program's arguments, never one per agent, never a constant of
+    the program.  ``per_agent`` is the first field, so the first leaf of a
+    ``SharedBatches`` is a per-agent batch leaf."""
+
+    per_agent: Any
+    shared: Any
+
+
+def with_shared(batches, shared):
+    """The round's batches, with a model's frozen part (if it has one)
+    riding along as one unbatched argument of the round program."""
+    return batches if shared is None else SharedBatches(batches, shared)
+
+
 def init_network(
     key: jax.Array,
     n_agents: int,
@@ -122,10 +143,15 @@ def network_local_steps(
     bit-identity in the all-edges-active case hangs on sharing this exact
     key/step derivation, so extend it here rather than copying it.
 
-    Returns (posterior', opt_state', per-agent mean losses [N]).  Its
-    device work carries the ``local_phase`` named scope (the profiler's
-    per-layer reading).
+    ``batches`` may be a ``SharedBatches``: its shared part is closed over
+    by every agent's nll, unbatched.  Returns (posterior', opt_state',
+    per-agent mean losses [N], the nll's aux summed over the steps, leaves
+    [N, ...]).  Its device work carries the ``local_phase`` named scope
+    (the profiler's per-layer reading).
     """
+    if isinstance(batches, SharedBatches):
+        shared, batches, agent_nll = batches.shared, batches.per_agent, nll
+        nll = lambda theta, batch: agent_nll(theta, batch, shared)
 
     def local(post_i, prior_i, opt_i, batches_i, key_i, step_i):
         return local_vi_steps(
@@ -160,7 +186,7 @@ def make_round_fn(
 ):
     """Build the jittable per-round transition.
 
-    round_fn(state, batches, W, key) -> (state', mean_loss_per_agent)
+    round_fn(state, batches, W, key) -> (state', mean_loss_per_agent, aux)
       batches: pytree, leaves [N, u, ...] — u local minibatches per agent
       W: [N, N] row-stochastic (may differ per round: time-varying networks)
 
@@ -170,7 +196,9 @@ def make_round_fn(
     boundary.  ``param_layout`` pre-binds that layout at build time (skips
     the per-trace wrap; required only when the state type is not known yet).
     ``wire_dtype`` compresses the gaussian consensus exchange
-    (``consensus_all_agents``); f32/None is bitwise uncompressed.
+    (``consensus_all_agents``); f32/None is bitwise uncompressed.  ``aux``
+    is the nll's aux per agent, summed over the local steps (``()`` for an
+    nll that returns its value alone; ``network_local_steps``).
     """
     if consensus not in ("gaussian", "mean_only", "none"):
         raise ValueError(f"unknown consensus mode {consensus!r}")
@@ -183,7 +211,7 @@ def make_round_fn(
             nll = make_flat_nll(nll_fn, state.posterior.layout)
         lr = lr_schedule(state.round)
         prior = state.posterior  # q_i^{(n-1)}: consensus result of last round
-        post, opt_state, losses = network_local_steps(
+        post, opt_state, losses, aux = network_local_steps(
             state.posterior, prior, opt, state.opt_state, nll, batches, key,
             lr, state.step, n_samples=n_mc_samples, kl_scale=kl_scale,
         )
@@ -206,7 +234,7 @@ def make_round_fn(
             step=state.step + u,
             round=state.round + 1,
         )
-        return new_state, losses
+        return new_state, losses, aux
 
     return round_fn
 
@@ -250,7 +278,8 @@ def run_rounds(
     for r in range(n_rounds):
         key, k_batch, k_round = jax.random.split(key, 3)
         batches = batch_sampler(k_batch, r)
-        state, losses = fn(state, batches, jnp.asarray(w_for_round(r)), k_round)
+        state, losses, _ = fn(state, batches, jnp.asarray(w_for_round(r)),
+                              k_round)
         if eval_every and ((r + 1) % eval_every == 0 or r == n_rounds - 1):
             rec = {"round": r + 1, "loss": float(jnp.mean(losses))}
             if eval_fn is not None:

@@ -84,32 +84,51 @@ def make_round_batches(
     return sampler
 
 
+def zipf_cdf(vocab_size: int, exponent: float = 1.2) -> np.ndarray:
+    """Cumulative Zipf weights k^-exponent over ids 0..V-1 (id k has rank
+    k + 1), normalized in float64 and stored float32 [V]."""
+    w = 1.0 / (np.arange(1, vocab_size + 1, dtype=np.float64) ** exponent)
+    return (np.cumsum(w) / w.sum()).astype(np.float32)
+
+
+def zipf_ids(key, cdf: jax.Array, shape) -> jax.Array:
+    """Zipf token ids by inverse CDF: one uniform per id and a
+    ``searchsorted`` over the [V] cumulative weights, so nothing of size
+    [..., V] is built per token."""
+    u = jax.random.uniform(key, shape, jnp.float32)
+    ids = jnp.searchsorted(cdf, u, side="right")
+    return jnp.minimum(ids, cdf.shape[0] - 1).astype(jnp.int32)
+
+
 def make_lm_batch_sampler(
     vocab_size: int, batch_size: int, seq_len: int, n_agents: int = 0,
-    distribution: str = "zipf",
+    distribution: str = "zipf", local_updates: int = 0,
+    exponent: float = 1.2,
 ):
     """Synthetic LM token pipeline: sampler(key, round) -> dict with
-    ``tokens`` [(N,) B, S] and ``targets`` (next-token shift).  Used by the
-    production train driver and the ~100M end-to-end example.
+    ``tokens`` [(N,) (u,) B, S] and ``targets`` (next-token shift), the
+    agent and local-step axes present when ``n_agents`` / ``local_updates``
+    are given.  Used by the production train driver, the ~100M end-to-end
+    example and the ``zipf_tokens`` dataset of ``repro.api``.
 
-    ``distribution``: "zipf" (learnable unigram structure, entropy below
-    log V — training visibly reduces NLL) or "uniform"."""
+    ``distribution``: "zipf" (ids iid with P(k) proportional to
+    (k + 1)^-exponent: learnable unigram structure, entropy below log V —
+    training visibly reduces NLL) or "uniform"."""
 
-    shape = ((n_agents, batch_size, seq_len + 1) if n_agents
-             else (batch_size, seq_len + 1))
+    shape = tuple(d for d in (n_agents, local_updates) if d) + (
+        batch_size, seq_len + 1)
     if distribution == "zipf":
-        w = 1.0 / (np.arange(1, vocab_size + 1) ** 1.2)
-        logits = jnp.asarray(np.log(w / w.sum()), jnp.float32)
+        cdf = jnp.asarray(zipf_cdf(vocab_size, exponent))
+        draw = lambda key: zipf_ids(key, cdf, shape)
     elif distribution == "uniform":
-        logits = jnp.zeros((vocab_size,), jnp.float32)
+        draw = lambda key: jax.random.randint(key, shape, 0, vocab_size,
+                                              jnp.int32)
     else:
         raise ValueError(distribution)
 
     @jax.jit
     def sampler_impl(key):
-        toks = jax.random.categorical(
-            key, jnp.broadcast_to(logits, shape + (vocab_size,))
-        ).astype(jnp.int32)
+        toks = draw(key)
         return {"tokens": toks[..., :-1], "targets": toks[..., 1:]}
 
     def sampler(key, round_idx: int):

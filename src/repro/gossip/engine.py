@@ -110,7 +110,7 @@ from repro.core.flat import (
     segments_mode,
 )
 from repro.core.numerics import canonical_wire_dtype, wire_dtype_name
-from repro.core.simulated import init_network, network_local_steps
+from repro.core.simulated import init_network, network_local_steps, with_shared
 from repro.gossip.clocks import SparseClock, SparseWindow
 
 PyTree = Any
@@ -360,7 +360,7 @@ class GossipEngine:
             # the SHARED local phase (simulated.network_local_steps): the
             # all-edges-active window is bit-identical to the synchronous
             # round because both runtimes run this exact derivation
-            post, opt_state, losses = network_local_steps(
+            post, opt_state, losses, aux = network_local_steps(
                 state.posterior, prior, opt, state.opt_state, nll, batches,
                 key, lr, state.step, n_samples=n_mc, kl_scale=kl_scale,
             )
@@ -379,15 +379,17 @@ class GossipEngine:
                 # (Session.round aggregates NaN-safely and reports n_trained)
                 train = active
             else:
-                return post, opt_state, state.step + u, active, losses
+                return post, opt_state, state.step + u, active, losses, aux
             with jax.named_scope("agent_select"):
                 post = _agent_select(train, post, state.posterior)
                 opt_state = _agent_select(train, opt_state, state.opt_state)
                 step = jnp.where(train, state.step + u, state.step)
                 losses = jnp.where(train, losses, jnp.nan)
+                aux = _agent_select(train, aux,
+                                    jax.tree.map(jnp.zeros_like, aux))
             if up is not None:
                 active = active & up
-            return post, opt_state, step, active, losses
+            return post, opt_state, step, active, losses, aux
 
         def mean_only(post, W, active):
             """The FedAvg baseline's merge: W @ mean, W @ rho on the
@@ -430,7 +432,7 @@ class GossipEngine:
             )
 
         def window_fn(state: GossipState, batches, W, active, key):
-            post, opt_state, step, active, losses = local_phase(
+            post, opt_state, step, active, losses, aux = local_phase(
                 state, batches, active, key
             )
             with jax.named_scope("consensus"):
@@ -440,12 +442,12 @@ class GossipEngine:
                     )
                 elif consensus_mode == "mean_only":
                     post = mean_only(post, W, active)
-            return finish(state, post, opt_state, step, active), losses
+            return finish(state, post, opt_state, step, active), losses, aux
 
         def window_fn_delayed(
             state: GossipState, batches, W, active, key, edges, weights, lags
         ):
-            post, opt_state, step, active, losses = local_phase(
+            post, opt_state, step, active, losses, aux = local_phase(
                 state, batches, active, key
             )
             # record this window's post-local, PRE-merge posterior in its
@@ -470,7 +472,7 @@ class GossipEngine:
             new_state = finish(state, post, opt_state, step, active)
             return dataclasses.replace(
                 new_state, hist_mean=hist_mean, hist_rho=hist_rho
-            ), losses
+            ), losses, aux
 
         def window_fn_guarded(
             state: GossipState, batches, W, active, key, up, corrupt,
@@ -483,7 +485,7 @@ class GossipEngine:
             ``quarantine`` swaps in the validated consensus.  All-up /
             no-corruption inputs make every extra op a value-identity, so
             the zero-fault guarded trajectory is bitwise the strict one."""
-            post, opt_state, step, active, losses = local_phase(
+            post, opt_state, step, active, losses, aux = local_phase(
                 state, batches, active, key, up
             )
             n_q = state.n_quarantined
@@ -514,7 +516,8 @@ class GossipEngine:
                 with jax.named_scope("consensus"):
                     post = mean_only(post, W, active)
             new_state = finish(state, post, opt_state, step, active)
-            return dataclasses.replace(new_state, n_quarantined=n_q), losses
+            return (dataclasses.replace(new_state, n_quarantined=n_q),
+                    losses, aux)
 
         def window_fn_delayed_guarded(
             state: GossipState, batches, W, active, key, edges, weights,
@@ -524,7 +527,7 @@ class GossipEngine:
             time by source id (every event gathered FROM a corrupted agent
             this window reads garbage, whatever its fire time); the history
             ring always records the TRUE resident posterior."""
-            post, opt_state, step, active, losses = local_phase(
+            post, opt_state, step, active, losses, aux = local_phase(
                 state, batches, active, key, up
             )
             slot = jnp.mod(state.round, hist_slots)
@@ -569,7 +572,7 @@ class GossipEngine:
             return dataclasses.replace(
                 new_state, hist_mean=hist_mean, hist_rho=hist_rho,
                 n_quarantined=n_q,
-            ), losses
+            ), losses, aux
 
         def _self_loops(dst, src, w_e, w_self):
             """Fold the conserve-rule self terms into the edge list as N
@@ -586,7 +589,7 @@ class GossipEngine:
             [N] self-weights + the host-exact active mask ride as traced
             arguments (static shapes — one trace for the whole run); no
             [N, N] is ever materialized, host or device."""
-            post, opt_state, step, active, losses = local_phase(
+            post, opt_state, step, active, losses, aux = local_phase(
                 state, batches, active, key
             )
             if consensus_mode == "gaussian":
@@ -596,7 +599,7 @@ class GossipEngine:
                         post, d_all, s_all, w_all,
                         active=active, wire_dtype=wire_dtype, **seg_exec,
                     )
-            return finish(state, post, opt_state, step, active), losses
+            return finish(state, post, opt_state, step, active), losses, aux
 
         def window_fn_segments_guarded(
             state: GossipState, batches, dst, src, w_e, w_self, active,
@@ -609,7 +612,7 @@ class GossipEngine:
             dst's self term.  All-up / no-corruption inputs reduce to the
             unguarded call bitwise (the same equivalence-ladder rung the
             dense guarded windows pin)."""
-            post, opt_state, step, active, losses = local_phase(
+            post, opt_state, step, active, losses, aux = local_phase(
                 state, batches, active, key, up
             )
             n_q = state.n_quarantined
@@ -639,7 +642,8 @@ class GossipEngine:
                     )
                     post = merged_rows(post, merged, active)
             new_state = finish(state, post, opt_state, step, active)
-            return dataclasses.replace(new_state, n_quarantined=n_q), losses
+            return (dataclasses.replace(new_state, n_quarantined=n_q),
+                    losses, aux)
 
         if self.consensus_impl == "segments":
             fn = window_fn_segments_guarded if guarded else window_fn_segments
@@ -777,6 +781,13 @@ class GossipEngine:
         return out
 
     def run_round(self, state, batches, W, key):
+        """One window; the nll's aux (the model's counters per agent) is
+        kept as ``last_aux`` for ``Session.round``'s telemetry."""
+        state, losses, self.last_aux = self._run_window(
+            state, with_shared(batches, self.model.shared), W, key)
+        return state, losses
+
+    def _run_window(self, state, batches, W, key):
         obs = self.obs
         r = int(state.round)
         if self.consensus_impl == "segments":
@@ -809,15 +820,13 @@ class GossipEngine:
             return out
         if ppermute:
             with _span(obs, "gossip.local_phase", impl="ppermute", round=r):
-                state, losses = self._window(
+                state, losses, aux = self._window(
                     state, batches, W, act, key, *extra
                 )
             with _span(obs, "gossip.consensus", impl="ppermute", round=r):
-                state, losses = self._ppermute_consensus(
-                    state, losses, W, win, extra
-                )
+                state = self._ppermute_consensus(state, W, win, extra)
             self._obs_after_window(obs)
-            return state, losses
+            return state, losses, aux
         # dense masked path: local phase + consensus fused in one call
         with _span(obs, "gossip.window", impl="masked", round=r):
             out = self._window(state, batches, W, act, key, *extra)
@@ -851,7 +860,7 @@ class GossipEngine:
                 "real entries of the row-gather tables over N x D",
             ).set(real / (self.n_agents * self.gather_slots))
 
-    def _ppermute_consensus(self, state, losses, W, win, extra):
+    def _ppermute_consensus(self, state, W, win, extra):
         """The host-level sharded consensus dispatch (the one window
         execution whose consensus is a separate program from the local
         phase — which is why it gets its own span in ``run_round``)."""
@@ -862,7 +871,7 @@ class GossipEngine:
                 mode="ppermute", mesh=self._mesh, axis="agents",
                 window=win, wire_dtype=self.wire_dtype,
             )
-            return dataclasses.replace(state, posterior=post), losses
+            return dataclasses.replace(state, posterior=post)
         up, corrupt, fm, fr = extra
         c = corrupt[:, None]
         mean_src = jnp.where(c, fm[:, None], post.mean)
@@ -892,7 +901,7 @@ class GossipEngine:
                 rho=jnp.where(act, merged.rho, post.rho),
             )
             state = dataclasses.replace(state, posterior=post)
-        return state, losses
+        return state
 
     def posterior(self, state) -> FlatPosterior:
         return state.posterior

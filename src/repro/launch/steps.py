@@ -229,7 +229,7 @@ def make_local_step(
                 if nll_fn is not None:
                     from repro.vi.bayes_by_backprop import free_energy
 
-                    return free_energy(
+                    value, _ = free_energy(
                         post_a,
                         prior_a,
                         lambda theta, b: nll_fn(unflatten(theta), b),
@@ -238,6 +238,7 @@ def make_local_step(
                         n_samples=n_mc_samples,
                         kl_scale=kl_scale,
                     )
+                    return value
                 theta = post_a.sample(key_a)
                 kl = kl_gaussian(post_a, prior_a)
                 nll, aux = nll_loss(unflatten(theta), cfg, batch_a, remat=remat)

@@ -7,6 +7,17 @@ Training/prefill uses ``chunked_attention`` — the flash-attention algorithm
 Pallas kernel (repro.kernels.flash_attention) implements the same contract
 with explicit VMEM tiling; ``ops.attention`` dispatches between them.
 
+``mla_block`` is DeepSeek-V2's multi-head latent attention (MLA, arXiv:
+2405.04434 §2.1) without query compression, for training and prefill only
+(no latent decode cache).  Departures from the published description:
+
+* RoPE pairs dimension i with i + d/2 of the rope part (rotate-half);
+  DeepSeek's checkpoint interleaves pairs (2i, 2i+1) and permutes them
+  before rotating.  With seeded weights this is a fixed permutation of
+  W_q's and W_kv_a's rope columns, so the function family is the same;
+* an optional low-rank adapter (LoRA) sits on each of the four
+  projections: ``x W + (alpha / r) (x A) B``.
+
 Decode uses a fixed-size KV cache: full-length for decode_32k, a ring buffer
 of ``window`` slots for sliding-window long-context decode (long_500k).
 """
@@ -17,7 +28,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models.modules import rmsnorm, rope, truncated_normal_init
+from repro.models.modules import (
+    rmsnorm,
+    rope,
+    rope_scale,
+    truncated_normal_init,
+    yarn_inv_freq,
+)
 
 NEG_INF = -1e30
 
@@ -60,15 +77,40 @@ def chunked_attention(
     k_valid: jax.Array | None = None,  # [B, Sk] bool (cache slots)
     k_positions: jax.Array | None = None,  # [B, Sk] absolute positions
     chunk_size: int = 512,
+    scale: float | None = None,
+    q_chunk_size: int = 0,
 ) -> jax.Array:
     """Flash-attention algorithm over KV chunks (pure JAX).
 
     ``q_offset``: absolute position of q[0] (prefill continuation / decode).
     ``window`` > 0 masks keys older than ``window`` positions behind a query.
+    ``scale``: the softmax scale (default hd^-1/2).  v may have its own head
+    width (MLA: q/k 192, v 128).  ``q_chunk_size`` > 0 runs the queries in
+    blocks of that many, each block rematerialized in the backward pass,
+    so the backward holds one block's scores at a time.
     """
     b, sq, h, hd = q.shape
-    sk = k.shape[1]
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    if q_chunk_size and q_chunk_size < sq:
+        if sq % q_chunk_size:
+            raise ValueError(f"{sq} queries are not a multiple of the query "
+                             f"chunk {q_chunk_size}")
+        kw = dict(causal=causal, window=window, k_valid=k_valid,
+                  k_positions=k_positions, chunk_size=chunk_size,
+                  scale=scale)
+
+        @jax.checkpoint
+        def block(args):
+            q_i, off = args
+            return chunked_attention(q_i, k, v, q_offset=off, **kw)
+
+        n_q = sq // q_chunk_size
+        out = jax.lax.map(block, (
+            q.reshape(b, n_q, q_chunk_size, h, hd).swapaxes(0, 1),
+            q_offset + q_chunk_size * jnp.arange(n_q)))
+        return out.swapaxes(0, 1).reshape(b, sq, h, v.shape[-1])
+    sk, dv = k.shape[1], v.shape[-1]
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     n_chunks = -(-sk // chunk_size)
     pad = n_chunks * chunk_size - sk
     if pad:
@@ -91,13 +133,13 @@ def chunked_attention(
 
     q_pos = q_offset + jnp.arange(sq)  # [Sq]
     kc = k.reshape(b, n_chunks, chunk_size, h, hd).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(b, n_chunks, chunk_size, h, hd).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(b, n_chunks, chunk_size, h, dv).transpose(1, 0, 2, 3, 4)
     kpos_c = k_positions.reshape(b, n_chunks, chunk_size).transpose(1, 0, 2)
     kval_c = k_valid.reshape(b, n_chunks, chunk_size).transpose(1, 0, 2)
 
     m0 = jnp.full((b, h, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
-    acc0 = jnp.zeros((b, sq, h, hd), jnp.float32)
+    acc0 = jnp.zeros((b, sq, h, dv), jnp.float32)
 
     def body_fixed(carry, xs):
         m, l, acc = carry
@@ -315,3 +357,87 @@ def attention_block(
         )
     y = out.reshape(b, s, cfg.n_heads * hd) @ params["wo"].astype(dt)
     return y, new_cache
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+
+MLA_PROJECTIONS = ("q", "kv_a", "kv_b", "o")
+
+
+def mla_dims(cfg) -> dict:
+    """(d_in, d_out) of each MLA projection."""
+    h = cfg.n_heads
+    return {
+        "q": (cfg.d_model, h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+        "kv_a": (cfg.d_model, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+        "kv_b": (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "o": (h * cfg.v_head_dim, cfg.d_model),
+    }
+
+
+def mla_init(key, cfg):
+    ks = jax.random.split(key, 4)
+    dims = mla_dims(cfg)
+    p = {f"w{name}": truncated_normal_init(k, dims[name], 1.0)
+         for k, name in zip(ks, MLA_PROJECTIONS)}
+    p["kv_norm"] = {"scale": jnp.ones((cfg.kv_lora_rank,), jnp.float32)}
+    return p
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(nope + rope)^-1/2, times YaRN's mscale(factor, mscale_all_dim)^2
+    when the rope is scaled (DeepSeek-V2)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        m = ys.get_mscale(ys.factor, ys.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def _project(params, name: str, x, lora_scale: float):
+    """x @ W, plus (alpha / r) (x @ A) @ B when the layer carries an
+    adapter (``params["lora"][name]``, in float32)."""
+    dt = x.dtype
+    y = x @ params[f"w{name}"].astype(dt)
+    lora = params.get("lora")
+    if lora is not None:
+        ad = lora[name]
+        y = y + (lora_scale * ((x @ ad["a"].astype(dt)) @ ad["b"].astype(dt))
+                 ).astype(dt)
+    return y
+
+
+def mla_block(params, x: jax.Array, cfg, *, positions: jax.Array,
+              lora_scale: float = 1.0, chunk: int = 256) -> jax.Array:
+    """MLA over x [B, S, D] (causal).  Per token: q = x W_q split into
+    heads of (nope, rope); [c_kv, k_pe] = x W_kv_a with c_kv RMS-normed;
+    [k_nope, v] = c_kv W_kv_b; YaRN RoPE on q_pe and the shared k_pe;
+    o = softmax(q k^T * scale) v; y = o W_o."""
+    b, s, _ = x.shape
+    h, nope, rd = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    with jax.named_scope("mla"):
+        q = _project(params, "q", x, lora_scale).reshape(b, s, h, nope + rd)
+        ckv = _project(params, "kv_a", x, lora_scale)
+        c, k_pe = ckv[..., :cfg.kv_lora_rank], ckv[..., cfg.kv_lora_rank:]
+        c = rmsnorm(params["kv_norm"], c, cfg.norm_eps)
+        kv = _project(params, "kv_b", c, lora_scale).reshape(
+            b, s, h, nope + cfg.v_head_dim)
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        ys = cfg.rope_scaling
+        inv_freq = (None if ys is None
+                    else yarn_inv_freq(rd, cfg.rope_theta, ys))
+        rs = 1.0 if ys is None else rope_scale(ys)
+        q_pe = rope(q[..., nope:], positions, cfg.rope_theta, inv_freq, rs)
+        k_pe = rope(k_pe[:, :, None, :], positions, cfg.rope_theta, inv_freq,
+                    rs)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, (b, s, h, rd))], axis=-1)
+        o = chunked_attention(q, k, v, causal=True, chunk_size=s,
+                              scale=mla_softmax_scale(cfg),
+                              q_chunk_size=chunk)
+        return _project(params, "o", o.reshape(b, s, h * cfg.v_head_dim),
+                        lora_scale)

@@ -7,10 +7,12 @@ stacks them over periods for scan.
 """
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 PyTree = Any
 
@@ -54,14 +56,52 @@ def rmsnorm(params, x, eps: float = 1e-6):
     return (out * params["scale"].astype(jnp.float32)).astype(dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding.  x: [..., S, H, hd]; positions: [..., S]."""
+def yarn_inv_freq(dim: int, theta: float, scaling) -> np.ndarray:
+    """YaRN's per-pair inverse frequencies [dim // 2] (arXiv:2309.00071, as
+    DeepSeek-V2 computes them): the plain RoPE frequency below the
+    correction range (``beta_fast`` rotations over the original context),
+    the frequency over ``factor`` above it (``beta_slow``), a linear ramp
+    between."""
+    base = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    extra, inter = 1.0 / base, 1.0 / (scaling.factor * base)
+    orig = scaling.original_max_position_embeddings
+
+    def corr_dim(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def rope_scale(scaling) -> float:
+    """YaRN's cos/sin magnitude: mscale(factor, mscale) over
+    mscale(factor, mscale_all_dim) (1 for DeepSeek-V2, where they agree)."""
+    get = scaling.get_mscale
+    return (get(scaling.factor, scaling.mscale)
+            / get(scaling.factor, scaling.mscale_all_dim))
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq=None, scale: float = 1.0) -> jax.Array:
+    """Rotary position embedding.  x: [..., S, H, hd]; positions: [..., S].
+    ``inv_freq`` [hd // 2] replaces theta's plain frequencies (YaRN);
+    ``scale`` multiplies cos and sin."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if inv_freq is None:
+        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, half]
     cos = jnp.cos(angles)[..., None, :]  # [..., S, 1, half]
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1

@@ -1,4 +1,5 @@
-"""Top-k mixture-of-experts FFN with capacity-based dispatch.
+"""Mixture-of-experts FFNs: top-k with capacity-based dispatch (``moe_ffn``)
+and DeepSeekMoE's dropless routed + shared experts (``deepseek_moe``).
 
 Routing: softmax router -> top-k experts per token -> capacity-limited
 dispatch (tokens over capacity are dropped, standard Switch/GShard
@@ -12,13 +13,26 @@ optimization in EXPERIMENTS.md §Perf).
 Also emits the load-balancing auxiliary loss (Switch-style
 E * sum_e f_e * p_e) — the paper-external but production-required router
 regularizer.
+
+``deepseek_moe`` (DeepSeek-V2 §2.2) scores experts with a softmax over the
+router's logits (float32), takes the greedy top-k, optionally renormalizes
+the k weights, scales them by ``routed_scaling_factor`` and adds the
+shared experts (one SwiGLU of ``n_shared_experts`` times the expert
+width).  Dispatch is dropless: the (token, expert) pairs are sorted by
+expert and each expert's SwiGLU runs as a grouped matmul
+(``jax.lax.ragged_dot``) over all of its tokens.  Under ``vmap`` (the
+agents of the simulated runtime, which share the frozen experts) the
+grouped product sees the tokens of every batch element at once: one
+product per layer, not one per agent (``_flat_over_vmap``).  No auxiliary
+loss: the router is frozen wherever this path trains.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
-from repro.models.modules import truncated_normal_init
+from repro.models.modules import swiglu, swiglu_init, truncated_normal_init
 
 
 def moe_init(key, cfg):
@@ -102,3 +116,145 @@ def moe_ffn(params, x: jax.Array, cfg, dtype=None):
     contrib = jnp.where(keep[:, None], gathered.astype(jnp.float32) * w_of[:, None], 0.0)
     out = out.at[jnp.where(keep, token_of + 1, 0)].add(contrib)
     return out[1:].astype(dtype).reshape(b, s, d), aux
+
+
+# ---------------------------------------------------------------------------
+# DeepSeekMoE: dropless routed experts + shared experts
+# ---------------------------------------------------------------------------
+
+
+def deepseek_moe_init(key, cfg):
+    ks = jax.random.split(key, 5)
+    e, d, f = cfg.n_experts, cfg.d_model, cfg.expert_d_ff
+    p = {
+        "router": truncated_normal_init(ks[0], (d, e), 1.0),
+        "w_gate": truncated_normal_init(ks[1], (e, d, f), 1.0),
+        "w_up": truncated_normal_init(ks[2], (e, d, f), 1.0),
+        "w_down": truncated_normal_init(ks[3], (e, f, d), 1.0),
+    }
+    if cfg.n_shared_experts:
+        p["shared"] = swiglu_init(ks[4], d, cfg.n_shared_experts * f)
+    return p
+
+
+def route_greedy(router_logits: jax.Array, cfg):
+    """[T, E] float32 logits -> (weights [T, k], experts [T, k]): softmax
+    scores, greedy top-k, renormalized only if ``norm_topk_prob``, times
+    ``routed_scaling_factor``."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    weights, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights * cfg.routed_scaling_factor, idx
+
+
+def _grouped_swiglu(x, weights, idx, w_gate, w_up, w_down):
+    """Dropless routed experts over tokens x [T, D]: sort the T*k (token,
+    expert) pairs by expert, gather their rows, run gate/up/down as grouped
+    matmuls over the experts' contiguous row groups, and combine each
+    token's k outputs with its weights.  Returns y [T, D] (x's dtype)."""
+    t, d = x.shape
+    k, e = idx.shape[-1], w_gate.shape[0]
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)  # pair slots grouped by expert
+    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    rows = x[order // k]
+    g = jax.lax.ragged_dot(rows, w_gate, sizes)
+    u = jax.lax.ragged_dot(rows, w_up, sizes)
+    h = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32))
+    y = jax.lax.ragged_dot(h.astype(x.dtype), w_down, sizes)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    y = y[inverse].reshape(t, k, d).astype(jnp.float32)
+    return jnp.einsum("tkd,tk->td", y, weights.astype(jnp.float32)
+                      ).astype(x.dtype)
+
+
+def _flat_over_vmap(fn, n_token_args: int):
+    """``fn`` whose first ``n_token_args`` arguments (and every output) are
+    token-major, under a batching rule that folds a vmapped leading axis
+    into the token axis instead of batching ``fn``: the grouped matmuls of
+    N vmapped agents become one over all their tokens.  The trailing
+    arguments (the expert weights) must be unbatched."""
+
+    @custom_vmap
+    def f(*args):
+        return fn(*args)
+
+    @f.def_vmap
+    def rule(axis_size, in_batched, *args):
+        if any(in_batched[n_token_args:]):
+            raise NotImplementedError(
+                "the grouped experts fold a vmapped token axis; batched "
+                "expert weights are not supported")
+        args = [jnp.broadcast_to(a, (axis_size,) + a.shape) if not b else a
+                for a, b in zip(args[:n_token_args], in_batched)] + list(
+                    args[n_token_args:])
+        merged = [a.reshape((-1,) + a.shape[2:]) for a in args[:n_token_args]]
+        out = f(*merged, *args[n_token_args:])
+        unfold = lambda o: o.reshape((axis_size, -1) + o.shape[1:])
+        return jax.tree.map(unfold, out), jax.tree.map(lambda _: True, out)
+
+    return f
+
+
+_experts_fwd = _flat_over_vmap(_grouped_swiglu, 3)
+
+
+def _grouped_swiglu_vjp(x, weights, idx, w_gate, w_up, w_down, g):
+    _, vjp = jax.vjp(
+        lambda x, w: _grouped_swiglu(x, w, idx, w_gate, w_up, w_down),
+        x, weights)
+    return vjp(g)
+
+
+def _experts_bwd_rule(x, weights, idx, g, w_gate, w_up, w_down):
+    return _grouped_swiglu_vjp(x, weights, idx, w_gate, w_up, w_down, g)
+
+
+_experts_bwd = _flat_over_vmap(_experts_bwd_rule, 4)
+
+
+@jax.custom_vjp
+def routed_experts(x, weights, idx, w_gate, w_up, w_down):
+    """Dropless grouped routed experts (``_grouped_swiglu``), differentiable
+    in the tokens and the routing weights; the expert weights are frozen
+    (no cotangent).  Forward and backward each fold vmapped agents into
+    one grouped product."""
+    return _experts_fwd(x, weights, idx, w_gate, w_up, w_down)
+
+
+def _routed_fwd(x, weights, idx, w_gate, w_up, w_down):
+    y = _experts_fwd(x, weights, idx, w_gate, w_up, w_down)
+    return y, (x, weights, idx, w_gate, w_up, w_down)
+
+
+def _routed_bwd(res, g):
+    x, weights, idx, w_gate, w_up, w_down = res
+    dx, dw = _experts_bwd(x, weights, idx, g, w_gate, w_up, w_down)
+    return dx, dw, None, None, None, None
+
+
+routed_experts.defvjp(_routed_fwd, _routed_bwd)
+
+
+def deepseek_moe(params, x: jax.Array, cfg):
+    """x [B, S, D] -> (y [B, S, D], tokens per routed expert [E] int32)."""
+    b, s, d = x.shape
+    dt = x.dtype
+    xt = x.reshape(b * s, d)
+    with jax.named_scope("moe"):
+        with jax.named_scope("router"):
+            logits = jnp.matmul(xt.astype(jnp.float32),
+                                params["router"].astype(jnp.float32),
+                                precision=jax.lax.Precision.HIGHEST)
+            weights, idx = route_greedy(logits, cfg)
+            counts = jnp.bincount(idx.reshape(-1), length=cfg.n_experts)
+        with jax.named_scope("experts"):
+            y = routed_experts(xt, weights, idx,
+                               *(params[w].astype(dt)
+                                 for w in ("w_gate", "w_up", "w_down")))
+        if "shared" in params:
+            with jax.named_scope("shared_experts"):
+                y = y + swiglu(params["shared"], xt, dt)
+    return y.reshape(b, s, d), counts.astype(jnp.int32)

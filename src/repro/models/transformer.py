@@ -8,7 +8,13 @@ is what makes the 52-layer/42-B dry-runs tractable.  Caches (KV / recurrent
 state) are threaded through the same scan as xs/ys.
 
 Supported block kinds: attn, local_attn, moe, mlstm, slstm, rglru,
-enc_attn, dec_attn (see configs.base docstring).
+enc_attn, dec_attn, mla, mla_moe (see configs.base docstring).
+
+Latent-attention models (DeepSeek-V2: ``cfg.is_mla``) run ``mla_hidden``:
+the ``first_k_dense`` leading ``mla`` layers, then one scan over the
+``mla_moe`` layers, each layer rematerialized under ``remat``; optional
+low-rank adapters ride along each layer's parameters, and the scan
+returns every MoE layer's tokens per routed expert.
 """
 from __future__ import annotations
 
@@ -19,7 +25,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import moe as moe_lib
-from repro.models.attention import attention_block, attn_init, init_kv_cache
+from repro.models.attention import (
+    attention_block,
+    attn_init,
+    init_kv_cache,
+    mla_block,
+    mla_init,
+)
 from repro.models.modules import (
     embed,
     embed_init,
@@ -71,6 +83,15 @@ def block_init(key, kind: str, cfg):
             "norm2": rmsnorm_init(cfg.d_model),
             "moe": moe_lib.moe_init(ks[1], cfg),
         }
+    if kind in ("mla", "mla_moe"):
+        ks = jax.random.split(key, 2)
+        p = {"norm1": rmsnorm_init(cfg.d_model), "attn": mla_init(ks[0], cfg),
+             "norm2": rmsnorm_init(cfg.d_model)}
+        if kind == "mla":
+            p["mlp"] = swiglu_init(ks[1], cfg.d_model, cfg.d_ff)
+        else:
+            p["moe"] = moe_lib.deepseek_moe_init(ks[1], cfg)
+        return p
     if kind == "mlstm":
         return mlstm_init(key, cfg)
     if kind == "slstm":
@@ -98,6 +119,10 @@ def block_cache_init(kind: str, cfg, batch: int, capacity: int, dtype=jnp.bfloat
         return slstm_state_init(cfg, batch)
     if kind == "rglru":
         return rglru_state_init(cfg, batch)
+    if kind in ("mla", "mla_moe"):
+        raise NotImplementedError(
+            "latent attention has no decode cache here (training and "
+            "prefill only)")
     raise ValueError(kind)
 
 
@@ -193,6 +218,8 @@ def init_params(cfg, key) -> PyTree:
                 lambda a: a.reshape((cfg.n_periods, c) + a.shape[1:]), stk
             )
     params["stacks"] = stacks
+    if cfg.first_k_dense:
+        params["lead"] = _stack_inits(ks[6], "mla", cfg, cfg.first_k_dense)
     if cfg.tail:
         tkeys = jax.random.split(ks[3], len(cfg.tail))
         params["tail"] = [
@@ -318,6 +345,15 @@ def forward(
     (prefill returns next-token logits without materializing [S, V]).
     """
     dt = jnp.dtype(cfg.dtype)
+    if cfg.is_mla:
+        if cache is not None or positions is not None:
+            raise NotImplementedError("latent attention runs whole "
+                                      "sequences from position 0 only")
+        x, _ = mla_hidden(params, cfg, tokens, remat=remat)
+        if logits_tail:
+            x = x[:, -logits_tail:]
+        logits = (x @ params["lm_head"]["w"].astype(dt)).astype(jnp.float32)
+        return logits, None, jnp.asarray(0.0, jnp.float32)
     x = embed(params["embed"], tokens, dt)
     if cfg.frontend == "vision_stub" and patches is not None:
         pe = patches.astype(dt) @ params["patch_proj"]["w"].astype(dt)
@@ -373,6 +409,54 @@ def forward(
     else:
         logits = (x @ params["lm_head"]["w"].astype(dt)).astype(jnp.float32)
     return logits, new_cache, aux
+
+
+def _mla_layer(kind: str, cfg, positions, lora_scale: float):
+    """One latent-attention layer: (x, (params, adapters)) -> (x', tokens
+    per routed expert, or None for a dense layer)."""
+
+    def layer(x, p_ad):
+        p, adapters = p_ad
+        attn = p["attn"] if adapters is None else {**p["attn"],
+                                                   "lora": adapters}
+        h = rmsnorm(p["norm1"], x, cfg.norm_eps)
+        x = x + mla_block(attn, h, cfg, positions=positions,
+                          lora_scale=lora_scale)
+        h = rmsnorm(p["norm2"], x, cfg.norm_eps)
+        if kind == "mla_moe":
+            y, counts = moe_lib.deepseek_moe(p["moe"], h, cfg)
+        else:
+            y, counts = swiglu(p["mlp"], h, x.dtype), None
+        return x + y, counts
+
+    return layer
+
+
+def mla_hidden(params, cfg, tokens, *, adapters=None, lora_scale: float = 1.0,
+               remat: bool = False):
+    """Final-normed hidden states [B, S, D] of a latent-attention model and
+    the tokens per routed expert of each MoE layer [n_moe, E].
+
+    ``adapters``: optional low-rank adapters of the MLA projections,
+    ``{"lead": {proj: {"a", "b"}}, "moe": ...}`` with a leading layer axis
+    matching ``params["lead"]`` and the ``mla_moe`` stack."""
+    dt = jnp.dtype(cfg.dtype)
+    x = embed(params["embed"], tokens, dt)
+    positions = jnp.arange(tokens.shape[1])
+    adapters = adapters or {}
+
+    def run(kind, stack, ads, x):
+        layer = _mla_layer(kind, cfg, positions, lora_scale)
+        if remat:
+            layer = jax.checkpoint(layer)
+        return jax.lax.scan(layer, x, (stack, ads))
+
+    if cfg.first_k_dense:
+        x, _ = run("mla", params["lead"], adapters.get("lead"), x)
+    moe_stack = jax.tree.map(lambda a: a[:, 0],
+                             params["stacks"]["mla_moe"])  # [n_periods, ...]
+    x, counts = run("mla_moe", moe_stack, adapters.get("moe"), x)
+    return rmsnorm(params["final_norm"], x, cfg.norm_eps), counts
 
 
 def nll_loss(params, cfg, batch, remat: bool = False) -> tuple[jax.Array, jax.Array]:

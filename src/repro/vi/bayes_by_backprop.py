@@ -32,8 +32,10 @@ from repro.core.posterior import GaussianPosterior, kl_gaussian
 from repro.optim import Optimizer, apply_updates
 
 PyTree = Any
-# nll_fn(params, batch) -> scalar total negative log-likelihood over the batch
-NllFn = Callable[[PyTree, Any], jax.Array]
+# nll_fn(params, batch) -> scalar total negative log-likelihood over the
+# batch, or (that scalar, aux): aux is a pytree of the model's counters
+# (the LM's tokens per expert), reported and never differentiated
+NllFn = Callable[[PyTree, Any], Any]
 
 
 def free_energy(
@@ -44,8 +46,10 @@ def free_energy(
     key: jax.Array,
     n_samples: int = 1,
     kl_scale: float = 1.0,
-) -> jax.Array:
-    """Variational free energy (eq. 5): KL(q||prior) + E_q[-log lik].
+) -> tuple[jax.Array, PyTree]:
+    """Variational free energy (eq. 5): KL(q||prior) + E_q[-log lik], and
+    the nll's aux summed over the MC samples (``()`` for an nll that
+    returns its value alone).
 
     ``kl_scale`` implements minibatch KL reweighting (1/num_batches in [10])
     so that one epoch of minibatch steps applies the KL once in expectation.
@@ -57,11 +61,14 @@ def free_energy(
         with jax.named_scope("sample"):
             theta = post.sample(k)
         with jax.named_scope("nll"):
-            return nll_fn(theta, batch)
+            out = nll_fn(theta, batch)
+        return out if isinstance(out, tuple) else (out, ())
 
     keys = jax.random.split(key, n_samples)
-    enll = jnp.mean(jax.vmap(one)(keys))
-    return kl_scale * kl + enll
+    values, aux = jax.vmap(one)(keys)
+    enll = jnp.mean(values)
+    return kl_scale * kl + enll, jax.tree.map(lambda a: jnp.sum(a, axis=0),
+                                              aux)
 
 
 def free_energy_and_grad(
@@ -72,8 +79,9 @@ def free_energy_and_grad(
     key: jax.Array,
     n_samples: int = 1,
     kl_scale: float = 1.0,
-) -> tuple[jax.Array, GaussianPosterior]:
-    return jax.value_and_grad(free_energy)(
+) -> tuple[tuple[jax.Array, PyTree], GaussianPosterior]:
+    """((free energy, aux), the free energy's gradient in ``post``)."""
+    return jax.value_and_grad(free_energy, has_aux=True)(
         post, prior, nll_fn, batch, key, n_samples, kl_scale
     )
 
@@ -90,12 +98,13 @@ def local_vi_steps(
     step0: jax.Array,
     n_samples: int = 1,
     kl_scale: float = 1.0,
-) -> tuple[GaussianPosterior, Any, jax.Array]:
+) -> tuple[GaussianPosterior, Any, jax.Array, PyTree]:
     """Run u local VI (Bayes-by-Backprop) steps — the paper's ``u`` local
     updates per communication round (supplementary Tables 1-3).
 
     ``batches``: pytree whose leaves carry a leading axis of length u (one
-    slice per local step).  Returns (new_post, new_opt_state, mean_loss).
+    slice per local step).  Returns (new_post, new_opt_state, mean_loss,
+    the nll's aux summed over the steps).
     """
     u = jax.tree.leaves(batches)[0].shape[0]
     keys = jax.random.split(key, u)
@@ -103,18 +112,19 @@ def local_vi_steps(
     def body(carry, xs):
         post, opt_state, step = carry
         batch, k = xs
-        loss, grads = free_energy_and_grad(
+        loss_aux, grads = free_energy_and_grad(
             post, prior, nll_fn, batch, k, n_samples, kl_scale
         )
         with jax.named_scope("optimizer"):
             updates, opt_state = opt.update(grads, opt_state, step, lr)
             post = apply_updates(post, updates)
-        return (post, opt_state, step + 1), loss
+        return (post, opt_state, step + 1), loss_aux
 
-    (post, opt_state, _), losses = jax.lax.scan(
+    (post, opt_state, _), (losses, aux) = jax.lax.scan(
         body, (post, opt_state, step0), (batches, keys)
     )
-    return post, opt_state, jnp.mean(losses)
+    return (post, opt_state, jnp.mean(losses),
+            jax.tree.map(lambda a: jnp.sum(a, axis=0), aux))
 
 
 def mc_predict(

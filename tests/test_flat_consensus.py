@@ -216,7 +216,7 @@ def test_flat_vi_round_and_dispatch():
     W = jnp.asarray(bidirectional_ring_w(n_agents), jnp.float32)
     losses = None
     for r in range(3):
-        state, losses = round_fn(state, batches, W, jax.random.key(r + 1))
+        state, losses, _ = round_fn(state, batches, W, jax.random.key(r + 1))
     assert isinstance(state.posterior, FlatPosterior)
     assert np.all(np.isfinite(np.asarray(losses)))
     assert int(state.round) == 3
