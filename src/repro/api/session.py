@@ -245,6 +245,8 @@ class Session:
         aux = getattr(self.engine, "last_aux", None) or {}
         if "expert_tokens" in aux:
             self._obs_expert_tokens(reg, aux["expert_tokens"])
+        if self.spec.inference.model == "lm":
+            self._obs_attention(reg)
         if "n_crashed" in rec:
             reg.counter(
                 "session.crashed_agent_windows", "agent-windows down"
@@ -272,6 +274,20 @@ class Session:
         load = counts.max(axis=1) / np.maximum(counts.mean(axis=1), 1e-30)
         reg.gauge("model.expert_load_max", "largest expert's tokens over "
                   "the mean, worst layer, last round").set(float(load.max()))
+
+    def _obs_attention(self, reg) -> None:
+        """The LM's latent-attention execution, as ``mla_block`` decides it
+        at trace time from the backend and the sequence length (the data's
+        ``dim``): counter ``model.attention_path{path=flash|chunked}`` once
+        a round, and gauge ``model.attention_block_fill``, the key blocks
+        it computes over all key blocks.  No device work."""
+        from repro.models.attention import mla_attention_path, mla_block_fill
+
+        s = self.data.dim
+        reg.counter("model.attention_path", "rounds by latent-attention "
+                    "execution").inc(path=mla_attention_path(s))
+        reg.gauge("model.attention_block_fill", "key blocks the attention "
+                  "computes over all key blocks").set(mla_block_fill(s))
 
     def run(
         self,

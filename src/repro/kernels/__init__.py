@@ -1,9 +1,14 @@
 # Pallas TPU kernels for the paper's compute hot-spots:
 #   consensus        fused precision-weighted posterior consensus (eq. 6)
 #   gauss_vi         fused Bayes-by-Backprop sample + KL (eq. 5)
-#   flash_attention  blocked causal/SWA attention (prefill/train hot path)
-# Each has a pure-jnp oracle in ref.py and a jit'd wrapper in ops.py;
-# validated in interpret=True mode on CPU, compiled via Mosaic on TPU.
+#   flash_attention  blocked causal/SWA attention, forward only (no caller
+#                    in the model)
+#   causal_flash     MLA's causal attention on the TPU: JAX's splash
+#                    kernels (forward, dq, dkv), tested against
+#                    models.attention.chunked_attention
+# The first three have pure-jnp oracles in ref.py (consensus and
+# gauss_vi jit'd wrappers in ops.py too); all are validated in
+# interpret=True mode on CPU and compiled via Mosaic on TPU.
 from repro.kernels import ops, ref
 from repro.kernels.consensus import (
     consensus_fused,

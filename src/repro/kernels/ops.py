@@ -27,7 +27,6 @@ from repro.kernels.consensus import (
     consensus_fused_network,
     consensus_fused_sparse,
 )
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gauss_vi import sample_and_kl_fused
 
 PyTree = Any
@@ -124,14 +123,3 @@ def sample_and_kl(post, prior, key: jax.Array, *, interpret: bool | None = None)
         mu_flat, rho_flat, eps, mu_p_flat, rho_p_flat, interpret=_auto(interpret)
     )
     return _unflatten(theta_flat, treedef, shapes, dtypes), kl
-
-
-def attention(
-    q, k, v, *, causal=True, window=0, block_q=512, block_k=512,
-    interpret: bool | None = None,
-):
-    """[B,H,S,hd] flash attention (Pallas on TPU, interpret elsewhere)."""
-    return flash_attention(
-        q, k, v, causal=causal, window=window, block_q=block_q, block_k=block_k,
-        interpret=_auto(interpret),
-    )

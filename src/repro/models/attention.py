@@ -3,9 +3,8 @@
 Training/prefill uses ``chunked_attention`` — the flash-attention algorithm
 (running max / running denominator over KV chunks) written in pure JAX so it
 (a) never materializes the [S, S] score matrix (required for prefill_32k),
-(b) lowers on any backend, and (c) shards under GSPMD.  On real TPU the
-Pallas kernel (repro.kernels.flash_attention) implements the same contract
-with explicit VMEM tiling; ``ops.attention`` dispatches between them.
+(b) lowers on any backend, and (c) shards under GSPMD.  Every attention
+here runs it, except ``mla_block``'s on the TPU (below).
 
 ``mla_block`` is DeepSeek-V2's multi-head latent attention (MLA, arXiv:
 2405.04434 §2.1) without query compression, for training and prefill only
@@ -18,6 +17,12 @@ with explicit VMEM tiling; ``ops.attention`` dispatches between them.
 * an optional low-rank adapter (LoRA) sits on each of the four
   projections: ``x W + (alpha / r) (x A) B``.
 
+``mla_attention_path`` picks its score/softmax/value product from what it
+can observe: on the TPU, when the sequence meets the kernels' tiling, the
+causal Pallas flash kernels of ``repro.kernels.causal_flash`` (score tiles
+kept in VMEM, key blocks above the diagonal skipped); ``chunked_attention``
+everywhere else.
+
 Decode uses a fixed-size KV cache: full-length for decode_32k, a ring buffer
 of ``window`` slots for sliding-window long-context decode (long_500k).
 """
@@ -28,6 +33,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import causal_flash
+from repro.kernels.dispatch import on_tpu
 from repro.models.modules import (
     rmsnorm,
     rope,
@@ -397,6 +404,26 @@ def mla_softmax_scale(cfg) -> float:
     return scale
 
 
+def mla_attention_path(s: int) -> str:
+    """The execution of ``mla_block``'s attention over ``s`` positions:
+    "flash" (``causal_flash``) on the TPU when ``s`` meets the kernels'
+    tiling, else "chunked" (``chunked_attention``).  Decided at trace time
+    from the backend and the shape; ``Session`` reads it for the registry's
+    ``model.attention_path``."""
+    if on_tpu() and causal_flash.block_sizes(s) is not None:
+        return "flash"
+    return "chunked"
+
+
+def mla_block_fill(s: int) -> float:
+    """Key blocks the attention's forward computes over all key blocks:
+    the causal kernel's live blocks on the flash path, every key block (1)
+    on the chunked one."""
+    if mla_attention_path(s) == "flash":
+        return causal_flash.block_fill(s, causal_flash.block_sizes(s))
+    return 1.0
+
+
 def _project(params, name: str, x, lora_scale: float):
     """x @ W, plus (alpha / r) (x @ A) @ B when the layer carries an
     adapter (``params["lora"][name]``, in float32)."""
@@ -415,7 +442,8 @@ def mla_block(params, x: jax.Array, cfg, *, positions: jax.Array,
     """MLA over x [B, S, D] (causal).  Per token: q = x W_q split into
     heads of (nope, rope); [c_kv, k_pe] = x W_kv_a with c_kv RMS-normed;
     [k_nope, v] = c_kv W_kv_b; YaRN RoPE on q_pe and the shared k_pe;
-    o = softmax(q k^T * scale) v; y = o W_o."""
+    o = softmax(q k^T * scale) v, run as ``mla_attention_path(S)`` says;
+    y = o W_o."""
     b, s, _ = x.shape
     h, nope, rd = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     with jax.named_scope("mla"):
@@ -436,8 +464,13 @@ def mla_block(params, x: jax.Array, cfg, *, positions: jax.Array,
         q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_pe, (b, s, h, rd))], axis=-1)
-        o = chunked_attention(q, k, v, causal=True, chunk_size=s,
-                              scale=mla_softmax_scale(cfg),
-                              q_chunk_size=chunk)
+        if mla_attention_path(s) == "flash":
+            o = causal_flash.causal_flash(
+                q, k, v, scale=mla_softmax_scale(cfg),
+                blocks=causal_flash.block_sizes(s))
+        else:
+            o = chunked_attention(q, k, v, causal=True, chunk_size=s,
+                                  scale=mla_softmax_scale(cfg),
+                                  q_chunk_size=chunk)
         return _project(params, "o", o.reshape(b, s, h * cfg.v_head_dim),
                         lora_scale)

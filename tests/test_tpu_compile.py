@@ -1,10 +1,11 @@
-"""Compile the consensus kernels for a described TPU v5e, without a chip.
+"""Compile the Pallas kernels for a described TPU v5e, without a chip.
 
 Interpret mode (every other Pallas test) cannot see what Mosaic refuses:
 primitives it has no lowering for, blocks that break the (8, 128) tiling
 rule, kernels that overrun the scoped VMEM.  These tests compile each
 kernel with ``interpret=False`` for one chip of a described ``v5e:2x2``
-topology at the paper MLP's width (P = 199,210 parameters per agent) and
+topology at the paper MLP's width (P = 199,210 parameters per agent), and
+the latent attention's causal flash kernels at the LM cell's shapes, and
 check that the program really holds the kernel (``tpu_custom_call``).
 
 The topology is described inside a fixture, never at import: only the
@@ -13,14 +14,18 @@ process that runs these tests loads the TPU compiler library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core.flat import QUARANTINE_BOUND, gather_tables
+from repro.kernels import causal_flash
 from repro.kernels import consensus as kc
+from repro.models.attention import mla_softmax_scale
 
 P_MLP = 199_210  # 784-200-200-10 MLP
 DEGREE = 5  # torus in-degree + self
@@ -115,6 +120,31 @@ def test_edge_list_gather_compiles_for_v5e_at_paper_width(one_chip, wire):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
     assert WS_N * WS_SLOTS <= kc.SPARSE_TABLE_ENTRIES
+
+
+def test_mla_flash_attention_compiles_for_v5e_at_cell_shapes(one_chip):
+    """``mla_block``'s attention on the chip at the LM cell's shapes (8
+    agents, S 4,096, 16 heads, q/k 192, v 128), forward and backward under
+    the layer's remat and the agents' vmap: the forward and the fused
+    backward kernels, and no float32 score buffer of a 256-query block
+    over all 4,096 keys (``chunked_attention``'s ``[.., 16, 256, 4096]``)."""
+    s, blocks = 4096, causal_flash.block_sizes(4096)
+    scale = mla_softmax_scale(get_config("deepseek-v2-lite"))
+    attn = jax.checkpoint(lambda q, k, v: causal_flash.causal_flash(
+        q, k, v, scale=scale, blocks=blocks, interpret=False))
+
+    def loss(q, k, v, g):
+        return jnp.sum(jax.vmap(attn)(q, k, v).astype(jnp.float32) * g)
+
+    shape = lambda d, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        (8, 1, s, 16, d), dt, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(192), shape(192), shape(128), shape(128, jnp.float32)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    for kernel in ("splash_mha_fwd", "splash_mha_dkv"):  # dkv fuses dq
+        assert kernel in text
+    assert not re.search(r"f32\[[\d,]*16,256,4096\]", text)
 
 
 def test_gather_tables_at_the_smem_bound_compile(one_chip):
