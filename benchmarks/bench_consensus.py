@@ -9,7 +9,8 @@ Sweeps (N_agents x P x topology) and times one eq.-(6) round through
     ``FlatPosterior`` buffers as ONE fused computation
     (``core.flat.consensus_flat``: Pallas network kernel on TPU, single
     fused XLA einsum elsewhere);
-  * ``flat_sparse``  — the CSR-neighbor-list variant on sparse topologies.
+  * ``flat_segments`` — the edge-list form (``core.flat
+    .consensus_flat_segments`` on the topology's edge arrays).
 
 Wall-clock (median of ``iters`` jitted calls, after warmup) is reported per
 path, together with the analytic roofline (``launch.costmodel
@@ -42,11 +43,10 @@ from repro.core.flat import (
     consensus_flat,
     consensus_flat_reference,
     consensus_flat_segments,
-    consensus_flat_sparse,
     flat_posterior_from_pytree,
-    neighbor_tables,
 )
 from repro.core.graphs import (
+    SparseGraph,
     bidirectional_ring_w,
     complete_w,
     star_w,
@@ -126,47 +126,50 @@ def bench_one(
     posts = _posts_for(jax.random.key(seed), n_agents, p_target, n_leaves)
     flat = flat_posterior_from_pytree(posts, leading_axes=1)
     W = jnp.asarray(_topology(topology, n_agents), jnp.float32)
-    nbr_np, wts_np = neighbor_tables(np.asarray(W))
-    nbr, wts = jnp.asarray(nbr_np), jnp.asarray(wts_np)
+    g = SparseGraph.from_dense(np.asarray(W))
+    edges = tuple(jnp.asarray(a) for a in g.edge_arrays())
     p = flat.layout.n_params
 
     leaf_fn = jax.jit(lambda po, w: consensus_all_agents(po, w).mean)
     flat_fn = jax.jit(lambda fp, w: consensus_flat(fp, w).mean)
-    sparse_fn = jax.jit(lambda fp, i, v: consensus_flat_sparse(fp, i, v).mean)
+    seg_fn = jax.jit(
+        lambda fp, d, s, v: consensus_flat_segments(fp, d, s, v).mean
+    )
 
     rec = {
         "n_agents": n_agents,
         "p": p,
         "n_leaves": n_leaves,
         "topology": topology,
-        "max_degree": int((np.asarray(W) > 0).sum(1).max()),
+        "max_degree": g.max_in_degree,
         "backend": jax.default_backend(),
         "us": {
             "leaf_loop": _time(leaf_fn, (posts, W), iters),
             "flat_fused": _time(flat_fn, (flat, W), iters),
-            "flat_sparse": _time(sparse_fn, (flat, nbr, wts), iters),
+            "flat_segments": _time(seg_fn, (flat, *edges), iters),
         },
         "roofline": consensus_roofline(
-            n_agents, p, n_leaves, max_degree=int((np.asarray(W) > 0).sum(1).max())
+            n_agents, p, n_leaves, max_degree=g.max_in_degree
         ),
     }
     rec["speedup_flat_vs_leaf_loop"] = rec["us"]["leaf_loop"] / rec["us"]["flat_fused"]
-    # the flat-fused path FOR a sparse topology is the sparse-neighborhood
-    # kernel (dense matmul form is the complete-graph case) — best-of both
+    # a sparse topology may run the edge-list form — best-of both
     rec["speedup_best_flat_vs_leaf_loop"] = rec["us"]["leaf_loop"] / min(
-        rec["us"]["flat_fused"], rec["us"]["flat_sparse"]
+        rec["us"]["flat_fused"], rec["us"]["flat_segments"]
     )
     if check_interpret:
         # correctness probe only: the Pallas interpreter is not timed
         ref = consensus_flat(flat, W, mode="xla")
         kern = consensus_flat(flat, W, mode="interpret", block=256)
-        sref = consensus_flat_sparse(flat, nbr, wts, mode="xla")
-        skern = consensus_flat_sparse(flat, nbr, wts, mode="interpret", block=256)
+        sref = consensus_flat_segments(flat, *edges, mode="xla")
+        skern = consensus_flat_segments(
+            flat, *edges, slots=g.max_in_degree, mode="interpret", block=256
+        )
         rec["interpret_max_err"] = {
             "dense_mean": float(jnp.max(jnp.abs(ref.mean - kern.mean))),
             "dense_rho": float(jnp.max(jnp.abs(ref.rho - kern.rho))),
-            "sparse_mean": float(jnp.max(jnp.abs(sref.mean - skern.mean))),
-            "sparse_rho": float(jnp.max(jnp.abs(sref.rho - skern.rho))),
+            "segments_mean": float(jnp.max(jnp.abs(sref.mean - skern.mean))),
+            "segments_rho": float(jnp.max(jnp.abs(sref.rho - skern.rho))),
         }
     return rec
 

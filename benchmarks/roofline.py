@@ -49,17 +49,17 @@ def run() -> None:
     p = 1 << 20
     n = 9
     ks = jax.random.split(jax.random.key(0), 3)
-    w = jax.nn.softmax(jax.random.normal(ks[0], (n,)))
+    W = jax.nn.softmax(jax.random.normal(ks[0], (n, n)), axis=1)
     mean = jax.random.normal(ks[1], (n, p))
     rho = jax.random.normal(ks[2], (n, p)) * 0.3
-    from repro.kernels.consensus import consensus_fused
+    from repro.kernels.consensus import consensus_fused_network
 
-    consensus_fused(w, mean, rho)  # compile
+    consensus_fused_network(W, mean, rho)  # compile
     t = Timer()
     reps = 3
     for _ in range(reps):
-        jax.block_until_ready(consensus_fused(w, mean, rho))
-    emit("kernel_consensus_1M_params", t.us(reps), f"n_neighbors={n};interpret=True")
+        jax.block_until_ready(consensus_fused_network(W, mean, rho))
+    emit("kernel_consensus_1M_params", t.us(reps), f"n_agents={n};interpret=True")
 
     from repro.kernels.gauss_vi import sample_and_kl_fused
 

@@ -20,8 +20,8 @@ Subcommands:
             kernel probe + tiny shapes; --full for the real sweep)
   run.py api-smoke                               headless exercise of the
             declarative repro.api surface: builds a tiny ExperimentSpec,
-            runs BOTH engines (simulated + launch), asserts their posteriors
-            agree, round-trips a self-describing session checkpoint
+            runs and evaluates it, round-trips a self-describing session
+            checkpoint
   run.py gossip-smoke [--json-out F]             event-driven gossip runtime
             smoke: all-edges-active window must equal the synchronous fused
             consensus bit-identically, tiny Poisson+link-failure run with
@@ -104,9 +104,8 @@ ALL = {
 
 def api_smoke() -> None:
     """Exercise the repro.api spec/session surface end-to-end on a tiny
-    experiment: eager validation, both engines, engine agreement, evaluate,
-    and the self-describing checkpoint round trip."""
-    import dataclasses
+    experiment: eager validation, a run, evaluate, and the self-describing
+    checkpoint round trip."""
     import os
     import tempfile
 
@@ -128,28 +127,19 @@ def api_smoke() -> None:
         inference=InferenceSpec(hidden=8, depth=1, lr=1e-2),
         run=RunSpec(n_rounds=3, seed=0),
     )
-    sessions = {}
-    for engine in ("simulated", "launch"):
-        s = build_session(
-            dataclasses.replace(spec, run=dataclasses.replace(spec.run, engine=engine))
-        )
-        s.run()
-        sessions[engine] = s
-        print(f"api-smoke,{engine},avg_acc={s.evaluate()['avg_acc']:.4f}")
-    p_sim = sessions["simulated"].posterior()
-    p_launch = sessions["launch"].posterior()
-    np.testing.assert_allclose(
-        np.asarray(p_sim.mean), np.asarray(p_launch.mean), atol=1e-5, rtol=1e-5
-    )
+    session = build_session(spec)
+    session.run()
+    print(f"api-smoke,simulated,avg_acc={session.evaluate()['avg_acc']:.4f}")
+    p_sim = session.posterior()
     with tempfile.TemporaryDirectory() as td:
         path = os.path.join(td, "session.ckpt")
-        sessions["simulated"].save(path)
+        session.save(path)
         resumed = Session.load(path)
         np.testing.assert_array_equal(
             np.asarray(resumed.posterior().mean), np.asarray(p_sim.mean)
         )
         assert resumed.round_idx == 3
-    print("api-smoke,ok,engines_agree=1;ckpt_roundtrip=1")
+    print("api-smoke,ok,ckpt_roundtrip=1")
 
 
 def main(argv=None) -> None:

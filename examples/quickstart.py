@@ -8,10 +8,9 @@ seen.
 
 The whole experiment is the ~15-line ``ExperimentSpec`` below —
 ``build_session`` validates it eagerly (connectivity, row-stochasticity,
-agent counts) and returns an engine-backed ``Session``; swap
-``RunSpec(engine="launch")`` to run the identical experiment on the
-production ``launch.steps`` path, or change ``TopologySpec`` to move the
-same run onto any other graph.
+agent counts) and returns an engine-backed ``Session``; change
+``TopologySpec`` to move the same run onto any other graph, or make it a
+gossip topology to run it on the event-driven ``GossipEngine``.
 
 To watch a run instead of just reading its result, attach the
 observability layer — ``ExperimentSpec(obs=ObsSpec(enabled=True))`` gives

@@ -24,8 +24,7 @@ it eagerly and returns an engine-backed ``Session``:
     session.run()
     print(session.evaluate())
 
-Engines: ``RunSpec.engine="simulated"`` (flat vmap runtime, default) or
-``"launch"`` (production ``launch.steps`` on the flat posterior); the
+Engines: ``RunSpec.engine="simulated"`` (flat vmap runtime, default); the
 conjugate linear-regression family of paper Example 1 is selected by
 ``InferenceSpec(method="conjugate_linreg")``; a
 ``TopologySpec(kind="gossip", clock=...)`` selects the event-driven
@@ -43,7 +42,6 @@ from repro.api.data import DataBundle, build_data
 from repro.api.engines import (
     ConjugateLinregEngine,
     Engine,
-    LaunchEngine,
     SimulatedEngine,
 )
 from repro.api.models import MODELS, ModelFns, build_model, mlp_init, mlp_logits, mlp_nll
@@ -67,7 +65,6 @@ __all__ = [
     "ExperimentSpec",
     "GossipEngine",
     "InferenceSpec",
-    "LaunchEngine",
     "MODELS",
     "ModelFns",
     "ObsSpec",

@@ -3,16 +3,12 @@
 An Engine owns the state layout and the per-round transition; the Session
 owns the loop, the data, and the RNG stream.  All engines run the SAME
 paper round (u local updates against the round prior, then eq.-(6)
-consensus) on the SAME key derivation, so their posteriors agree to
-numerical precision — enforced by the engine-equivalence test:
+consensus) on the SAME key derivation, so their posteriors agree (the
+synchronous engine and all-edges gossip bitwise, by test):
 
 * ``SimulatedEngine`` — the ``core.simulated`` flat runtime: one jitted
   ``round_fn`` (vmap over agents, scan over local steps), consensus as the
   single fused network-wide pass.  The default.
-* ``LaunchEngine`` — the production path: ``launch.steps.make_local_step`` /
-  ``make_consensus_step`` on a ``BayesTrainState`` whose posterior is a
-  ``FlatPosterior`` end-to-end (the ROADMAP "drive the flat runtime through
-  the launch path" item).  Same math, production step functions.
 * ``ConjugateLinregEngine`` — paper Example 1: exact conjugate
   full-covariance updates + eq.-(6) full-covariance consensus.
 * ``repro.gossip.engine.GossipEngine`` — the event-driven asynchronous
@@ -23,12 +19,10 @@ numerical precision — enforced by the engine-equivalence test:
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Any, Callable, Protocol
+from typing import Any, Protocol
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.api.data import DataBundle
 from repro.api.models import ModelFns
@@ -107,7 +101,6 @@ class SimulatedEngine:
             self.opt,
             init_sigma=self.init_sigma,
             shared_init=self.shared_init,
-            flat=True,
         )
 
     def run_round(self, state, batches, W, key):
@@ -117,98 +110,6 @@ class SimulatedEngine:
             state, with_shared(batches, self.model.shared), jnp.asarray(W),
             key)
         return state, losses
-
-    def posterior(self, state) -> FlatPosterior:
-        return state.posterior
-
-
-class LaunchEngine:
-    """Production ``launch.steps`` path behind the Engine protocol.
-
-    The hot loop is flat end-to-end: ``BayesTrainState.posterior`` is a
-    ``FlatPosterior``, the local VI step samples/updates the [A, P] buffers
-    (pytree only inside the model apply), and ``make_consensus_step``
-    dispatches to the fused network-wide consensus.  The key derivation
-    mirrors ``simulated.make_round_fn`` exactly (per-agent keys, then
-    per-local-step, then per-MC-sample), so both engines produce the same
-    posterior from the same Session stream.
-    """
-
-    name = "launch"
-
-    def __init__(self, spec: ExperimentSpec, model: ModelFns, n_agents: int):
-        from repro.launch.steps import make_consensus_step, make_local_step
-
-        inf = spec.inference
-        if inf.consensus == "mean_only":
-            raise ValueError(
-                "the launch engine implements gaussian/none consensus; "
-                "mean_only (the FedAvg baseline) runs on the simulated engine"
-            )
-        self.n_agents = n_agents
-        self.model = model
-        self.opt = build_optimizer(inf.optimizer)
-        self.init_sigma = inf.init_sigma
-        self.shared_init = inf.shared_init
-        self.consensus_mode = inf.consensus
-        self.u = spec.data.local_updates
-        base_sched = build_schedule(inf.lr, inf.lr_decay)
-        # the paper decays lr per communication ROUND; the launch step
-        # counter ticks per LOCAL step
-        u = self.u
-        local_step = make_local_step(
-            None,
-            self.opt,
-            lambda step: base_sched(step // u),
-            kl_scale=inf.kl_scale,
-            nll_fn=model.nll_fn,
-            n_mc_samples=inf.n_mc_samples,
-        )
-        wire_dtype = inf.wire_dtype
-        consensus = lambda post, W: make_consensus_step(
-            None, W, wire_dtype=wire_dtype
-        )(post)
-        if spec.run.jit:
-            local_step = jax.jit(local_step)
-            consensus = jax.jit(consensus)
-        self._local_step = local_step
-        self._consensus = consensus
-
-    def init(self, key: jax.Array):
-        from repro.launch.steps import BayesTrainState
-
-        ns = init_network(
-            key,
-            self.n_agents,
-            self.model.init_fn,
-            self.opt,
-            init_sigma=self.init_sigma,
-            shared_init=self.shared_init,
-            flat=True,
-        )
-        return BayesTrainState(
-            posterior=ns.posterior,
-            opt_state=ns.opt_state,
-            step=jnp.asarray(0, jnp.int32),
-        )
-
-    def run_round(self, state, batches, W, key):
-        u = jax.tree.leaves(batches)[0].shape[1]
-        # per-(agent, local-step) keys, exactly as simulated.make_round_fn:
-        # split over agents first, then over the u local steps
-        agent_keys = jax.random.split(key, self.n_agents)
-        step_keys = jax.vmap(lambda k: jax.random.split(k, u))(agent_keys)
-        prior = state.posterior  # q_i^{(n-1)}: consensus result of last round
-        losses = []
-        for t in range(u):
-            batch_t = jax.tree.map(lambda x: x[:, t], batches)
-            state, loss_t = self._local_step(state, prior, batch_t, step_keys[:, t])
-            losses.append(loss_t)
-        post = state.posterior
-        if self.consensus_mode == "gaussian":
-            post = self._consensus(post, jnp.asarray(W))
-        state = dataclasses.replace(state, posterior=post)
-        return state, jnp.mean(jnp.stack(losses), axis=0)
 
     def posterior(self, state) -> FlatPosterior:
         return state.posterior

@@ -18,10 +18,10 @@ engine, and returns a ``Session``:
     session.save("exp.ckpt")         # self-describing: spec embedded
     session = Session.load("exp.ckpt")   # rebuild + resume
 
-The engine behind the session (``RunSpec.engine``) is swappable without
-touching the loop: ``simulated`` (flat vmap runtime) or ``launch``
-(production step functions) — plus the conjugate linear-regression engine,
-selected automatically by ``InferenceSpec.method``.
+The engine behind the session is swappable without touching the loop:
+``simulated`` (the flat vmap runtime, ``RunSpec.engine``'s default),
+``gossip`` (selected by a gossip topology) and the conjugate
+linear-regression engine (selected by ``InferenceSpec.method``).
 """
 from __future__ import annotations
 
@@ -37,7 +37,6 @@ from repro.api.data import DataBundle, build_data
 from repro.api.engines import (
     ConjugateLinregEngine,
     Engine,
-    LaunchEngine,
     SimulatedEngine,
 )
 from repro.api.models import ModelFns, build_model
@@ -68,8 +67,6 @@ def build_session(spec: ExperimentSpec) -> "Session":
             # the edge-native form of the same thing — SparseWindow streams
             # executed through consensus_flat_segments.
             engine = GossipEngine(spec, model, n_agents)
-        elif spec.run.engine == "launch":
-            engine = LaunchEngine(spec, model, n_agents)
         else:
             engine = SimulatedEngine(spec, model, n_agents)
     if (spec.inference.wire_dtype == "f16"

@@ -689,12 +689,12 @@ class RunSpec:
 
     n_rounds: int = 20
     seed: int = 0
-    engine: str = "simulated"  # simulated | launch | gossip
+    engine: str = "simulated"  # simulated | gossip
     eval_every: int = 0
     jit: bool = True
 
     def validate(self) -> None:
-        if self.engine not in ("simulated", "launch", "gossip"):
+        if self.engine not in ("simulated", "gossip"):
             raise ValueError(f"unknown engine {self.engine!r}")
         if self.n_rounds < 0:
             raise ValueError("n_rounds must be nonnegative")
@@ -722,27 +722,15 @@ class ExperimentSpec:
             raise ValueError("conjugate_linreg inference requires dataset='linreg'")
         if self.data.dataset == "linreg" and self.inference.method != "conjugate_linreg":
             raise ValueError("dataset='linreg' requires method='conjugate_linreg'")
-        if self.inference.method == "conjugate_linreg" and self.run.engine == "launch":
-            raise ValueError("the launch engine backs Bayes-by-Backprop inference only")
         if (self.inference.model == "lm") != (self.data.dataset == "zipf_tokens"):
             raise ValueError("model='lm' trains on token data "
                              "(dataset='zipf_tokens'), and only it does")
-        if self.inference.model == "lm" and self.run.engine == "launch":
-            raise ValueError("the launch engine has no shared frozen trunk; "
-                             "model='lm' runs on the simulated or gossip "
-                             "engine")
         # "gossiping" = the GossipEngine drives the run: a dense gossip
         # topology, or a sparse topology with an edge-native clock attached
         gossiping = (self.topology.kind == "gossip"
                      or (self.topology.kind == "sparse"
                          and self.topology.clock is not None))
         if gossiping:
-            if self.run.engine == "launch":
-                raise ValueError(
-                    "a gossip topology runs on the GossipEngine (engine="
-                    "'gossip' or the 'simulated' default, auto-upgraded); "
-                    "the launch engine is synchronous"
-                )
             if self.inference.method == "conjugate_linreg":
                 raise ValueError(
                     "the gossip runtime backs Bayes-by-Backprop inference only"

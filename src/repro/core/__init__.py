@@ -19,11 +19,9 @@ from repro.core.flat import (
     FlatLayout,
     FlatPosterior,
     consensus_flat,
-    consensus_flat_sparse,
     flat_posterior_from_pytree,
     init_flat_posterior,
     make_flat_nll,
-    neighbor_tables,
 )
 from repro.core.simulated import (
     NetworkState,
@@ -37,11 +35,9 @@ __all__ = [
     "FlatLayout",
     "FlatPosterior",
     "consensus_flat",
-    "consensus_flat_sparse",
     "flat_posterior_from_pytree",
     "init_flat_posterior",
     "make_flat_nll",
-    "neighbor_tables",
     "FullCovGaussian",
     "GaussianPosterior",
     "consensus_all_agents",

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import graphs as _graphs
 from repro.core.numerics import (
     COMPUTE_DTYPE,
     EXCHANGE_PRECISION,
@@ -355,10 +354,11 @@ def consensus_flat_reference(
     more than the whole computation (measured on XLA:CPU; see
     BENCH_consensus.json).  Math is bitwise identical per block.
 
-    ``active`` (the gossip event-window form, see
-    ``consensus_flat_masked_reference``) selects per block between the
-    computed row (active agents) and the ORIGINAL (mean, rho) row
-    (inactive agents pass through bitwise); ``None`` adds no select at all.
+    ``active`` (the gossip event-window form, ``consensus_flat_masked``)
+    selects per block between the computed row (active agents) and the
+    ORIGINAL (mean, rho) row (inactive agents pass through bitwise);
+    ``None`` adds no select at all.  All-true ``active`` is bitwise the
+    unmasked output (the gossip/synchronous equivalence contract).
     ``wire_dtype`` rounds (prec, prec*mu) at the exchange boundary
     (``kernels.consensus`` module docstring); f32/None is bitwise the
     uncompressed path.
@@ -431,30 +431,6 @@ def consensus_flat(
     return FlatPosterior(mean=mean, rho=rho, layout=posts.layout)
 
 
-def consensus_flat_masked_reference(
-    mean: jax.Array,
-    rho: jax.Array,
-    W: jax.Array,
-    active: jax.Array,
-    block: int = XLA_BLOCK,
-    wire_dtype=None,
-) -> tuple[jax.Array, jax.Array]:
-    """Masked (event-window) eq. (6) on the flat buffers — reference
-    semantics for ``consensus_fused_masked`` and the fast non-TPU path.
-
-    The shared blocked loop of ``consensus_flat_reference`` with the
-    activity select: active agents get the computed row, inactive ones
-    their ORIGINAL (mean, rho) row.  With ``active`` all-true the select is
-    the identity on the computed values, so the output is bit-identical to
-    the unmasked reference (the gossip/synchronous equivalence contract,
-    which ``wire_dtype`` preserves: both paths round at the same exchange
-    boundary).
-    """
-    return consensus_flat_reference(
-        mean, rho, W, block=block, active=active, wire_dtype=wire_dtype
-    )
-
-
 def consensus_flat_masked(
     posts: FlatPosterior,
     W: jax.Array,
@@ -505,9 +481,9 @@ def consensus_flat_masked(
     if mode is None:
         mode = "pallas" if jax.default_backend() == "tpu" else "xla"
     if mode == "xla":
-        mean, rho = consensus_flat_masked_reference(
-            posts.mean, posts.rho, W, active,
-            block=(XLA_BLOCK if block is None else block),
+        mean, rho = consensus_flat_reference(
+            posts.mean, posts.rho, W,
+            block=(XLA_BLOCK if block is None else block), active=active,
             wire_dtype=wire_dtype,
         )
     elif mode in ("pallas", "interpret"):
@@ -762,80 +738,6 @@ def consensus_flat_segments(
     return FlatPosterior(mean=mean_out, rho=rho_out, layout=posts.layout)
 
 
-def consensus_flat_masked_sparse(
-    posts: FlatPosterior,
-    neighbors: jax.Array,
-    weights: jax.Array,
-    active: jax.Array,
-    *,
-    mode: str | None = None,
-    block: int | None = None,
-    wire_dtype=None,
-) -> FlatPosterior:
-    """Active-edge window consensus on CSR tables of the window's W-tilde
-    (``neighbor_tables(window.w_eff)``): active agents read only their
-    fired-neighbor rows, inactive agents copy their own row.  The "xla"
-    path rebuilds the tiny dense W-tilde (reference semantics); the
-    active-edge HBM saving exists on the Pallas path.  ``wire_dtype``
-    rounds the gathered (prec, prec*mu) at the exchange boundary."""
-    from repro.kernels.consensus import consensus_fused_masked_sparse
-
-    if mode is None:
-        mode = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if mode == "xla":
-        mean, rho = _sparse_reference(
-            posts.mean, posts.rho, neighbors, weights,
-            block=(XLA_BLOCK if block is None else block), active=active,
-            wire_dtype=wire_dtype,
-        )
-    elif mode in ("pallas", "interpret"):
-        mean, rho = consensus_fused_masked_sparse(
-            neighbors, weights, active, posts.mean, posts.rho,
-            block=block,
-            interpret=(True if mode == "interpret" else None),
-            wire_dtype=canonical_wire_dtype(wire_dtype),
-        )
-    else:
-        raise ValueError(f"unknown consensus_flat_masked_sparse mode {mode!r}")
-    return FlatPosterior(mean=mean, rho=rho, layout=posts.layout)
-
-
-def neighbor_tables(W: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """CSR-style padded neighbor tables for ``consensus_fused_sparse``.
-
-    Returns (neighbors [N, D] int32, weights [N, D] float32), D = max
-    in-degree.  Zero-weight entries of W are skipped; ragged rows are padded
-    with the agent's own id at weight 0.0 (reads a tile the agent already
-    touches, contributes nothing).  Host-side/static: call once per topology,
-    not per round.
-
-    Delegates to the one CSR construction
-    (``graphs.SparseGraph.from_dense(...).neighbor_tables()``) shared with
-    ``graphs.neighbor_lists`` / ``graphs.max_in_degree`` — sparse-native
-    callers skip the dense bridge and call the method on their
-    ``SparseGraph`` directly.
-    """
-    return _graphs.SparseGraph.from_dense(np.asarray(W)).neighbor_tables()
-
-
-def _sparse_reference(mean, rho, neighbors, weights, block: int = XLA_BLOCK,
-                      active=None, wire_dtype=None):
-    """Sparse reference path: rebuild the (tiny, [N, N]) dense W from the
-    neighbor tables and reuse the blocked dense path.  Bitwise-identical
-    semantics (zero-weight entries contribute nothing; self-padded slots
-    scatter-add 0.0 onto the diagonal), and far faster than row-gathers on
-    XLA:CPU, whose gather lowers to a scalar loop.  The true deg(i)-tile
-    HBM saving only exists on the Pallas path (mode="pallas" on TPU).
-    ``active`` is the gossip event-window mask (see
-    ``consensus_flat_reference``)."""
-    n = mean.shape[0]
-    rows = jnp.broadcast_to(jnp.arange(n, dtype=neighbors.dtype)[:, None], neighbors.shape)
-    W = jnp.zeros((n, n), COMPUTE_DTYPE).at[rows, neighbors].add(weights)
-    return consensus_flat_reference(
-        mean, rho, W, block=block, active=active, wire_dtype=wire_dtype
-    )
-
-
 # ---------------------------------------------------------------------------
 # Quarantine guard: fault-tolerant consensus (ROADMAP "Robustness")
 # ---------------------------------------------------------------------------
@@ -1003,63 +905,6 @@ def consensus_flat_masked_quarantined(
             posts_x, W_g, act_g,
             mode=mode, block=block, wire_dtype=wire_dtype,
         )
-    v_self = valid_self[:, None]
-    return (
-        FlatPosterior(
-            mean=jnp.where(v_self, out.mean, posts.mean),
-            rho=jnp.where(v_self, out.rho, posts.rho),
-            layout=posts.layout,
-        ),
-        valid_src,
-    )
-
-
-def consensus_flat_masked_sparse_quarantined(
-    posts: FlatPosterior,
-    neighbors: jax.Array,
-    weights: jax.Array,
-    active: jax.Array,
-    *,
-    mean_src: jax.Array | None = None,
-    rho_src: jax.Array | None = None,
-    mode: str | None = None,
-    block: int | None = None,
-    wire_dtype=None,
-    bound: float = QUARANTINE_BOUND,
-) -> tuple[FlatPosterior, jax.Array]:
-    """Quarantine-guarded ``consensus_flat_masked_sparse``: the CSR-table
-    form of the dense guard.  Table STRUCTURE stays static (same neighbor
-    ids — gathering a sanitized zero-weight row is harmless); only the
-    weights adjust in-graph: invalid non-self slots drop to 0.0 and each
-    row's dropped mass lands on its real self slot.  Zero faults is a
-    value-identity, as in the dense wrapper."""
-    mean_src = posts.mean if mean_src is None else mean_src
-    rho_src = posts.rho if rho_src is None else rho_src
-    valid_src = payload_validity(
-        mean_src, rho_src, wire_dtype=wire_dtype, bound=bound, mode=mode
-    )
-    valid_self = payload_validity(
-        posts.mean, posts.rho, wire_dtype=wire_dtype, bound=bound, mode=mode
-    )
-    mean_x, rho_x = _sanitized_sources(
-        posts, mean_src, rho_src, valid_src, valid_self
-    )
-    n = posts.mean.shape[0]
-    rows = jnp.arange(n, dtype=neighbors.dtype)[:, None]
-    self_mask = neighbors == rows
-    keep = valid_src[neighbors] | self_mask
-    wts_k = jnp.where(keep, weights, 0.0)
-    dropped = jnp.sum(weights - wts_k, axis=1)
-    # each row's REAL self entry (nonzero weight; pad slots are self at 0.0
-    # and must not receive mass) absorbs the dropped in-weights
-    self_slot = jnp.argmax(self_mask & (weights > 0.0), axis=1)
-    wts_g = wts_k.at[jnp.arange(n), self_slot].add(dropped)
-    act_g = (active > 0) & valid_self
-    out = consensus_flat_masked_sparse(
-        FlatPosterior(mean=mean_x, rho=rho_x, layout=posts.layout),
-        neighbors, wts_g, act_g,
-        mode=mode, block=block, wire_dtype=wire_dtype,
-    )
     v_self = valid_self[:, None]
     return (
         FlatPosterior(
@@ -1242,40 +1087,3 @@ def consensus_flat_segments_quarantined(
         ),
         valid_e,
     )
-
-
-def consensus_flat_sparse(
-    posts: FlatPosterior,
-    neighbors: jax.Array,
-    weights: jax.Array,
-    *,
-    mode: str | None = None,
-    block: int | None = None,
-    wire_dtype=None,
-) -> FlatPosterior:
-    """Sparse-neighborhood consensus: agents read only their deg(i) neighbor
-    rows (Pallas path).  Same mode/block/wire_dtype semantics as
-    ``consensus_flat``: the block default is per-mode (XLA cache block vs
-    kernel lane block); the "xla" path rebuilds the tiny dense W (reference
-    semantics — the deg(i) traffic saving exists only on the Pallas
-    path)."""
-    from repro.kernels.consensus import consensus_fused_sparse
-
-    if mode is None:
-        mode = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if mode == "xla":
-        mean, rho = _sparse_reference(
-            posts.mean, posts.rho, neighbors, weights,
-            block=(XLA_BLOCK if block is None else block),
-            wire_dtype=wire_dtype,
-        )
-    elif mode in ("pallas", "interpret"):
-        mean, rho = consensus_fused_sparse(
-            neighbors, weights, posts.mean, posts.rho,
-            block=block,
-            interpret=(True if mode == "interpret" else None),
-            wire_dtype=canonical_wire_dtype(wire_dtype),
-        )
-    else:
-        raise ValueError(f"unknown consensus_flat_sparse mode {mode!r}")
-    return FlatPosterior(mean=mean, rho=rho, layout=posts.layout)

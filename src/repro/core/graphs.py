@@ -122,9 +122,9 @@ class SparseGraph:
     Row i of the CSR (``indices[indptr[i]:indptr[i+1]]``) lists the source
     agents j that agent i listens to (W_ij > 0), self-loop included;
     ``weights`` holds the matching W_ij.  This is the native representation
-    for every O(E) code path: segment-sum consensus
-    (``core.flat.consensus_flat_segments``), padded neighbor tables for the
-    Pallas sparse kernels, and the E-parameterized rooflines.  ``to_dense``
+    for every O(E) code path: edge-native consensus
+    (``core.flat.consensus_flat_segments`` over ``edge_arrays()``) and the
+    E-parameterized rooflines.  ``to_dense``
     exists as an interop bridge only — the builders here never allocate
     ``[N, N]``.
     """
@@ -200,24 +200,6 @@ class SparseGraph:
             self.in_degrees.astype(np.int64),
         )
         return dst, self.indices.astype(np.int32), self.weights.astype(np.float32)
-
-    def neighbor_tables(self) -> tuple[np.ndarray, np.ndarray]:
-        """Padded [N, D] neighbor tables for ``consensus_fused_sparse``.
-
-        Identical contract (and bit pattern) to the historical dense-W
-        extraction: D = max in-degree, ragged rows padded with the agent's
-        own id at weight 0.0, weights cast to float32.  This is THE one CSR
-        construction behind ``core.flat.neighbor_tables``,
-        ``neighbor_lists`` and ``max_in_degree``.
-        """
-        n, d = self.n_agents, max(self.max_in_degree, 1)
-        neighbors = np.tile(np.arange(n, dtype=np.int32)[:, None], (1, d))
-        weights = np.zeros((n, d), np.float32)
-        for i in range(n):
-            lo, hi = int(self.indptr[i]), int(self.indptr[i + 1])
-            neighbors[i, : hi - lo] = self.indices[lo:hi]
-            weights[i, : hi - lo] = self.weights[lo:hi]
-        return neighbors, weights
 
     def strongly_connected(self) -> bool:
         return strongly_connected_csr(self.indptr, self.indices, self.n_agents)
@@ -665,8 +647,7 @@ def neighbor_lists(W: np.ndarray) -> list[list[int]]:
     """In-neighbors per agent (j such that W_ij > 0), including self.
 
     Routed through the one CSR construction (``SparseGraph.from_dense``) so
-    this, ``max_in_degree`` and ``core.flat.neighbor_tables`` can never
-    disagree on ordering or support."""
+    this and ``max_in_degree`` can never disagree on ordering or support."""
     g = SparseGraph.from_dense(W)
     return [[int(j) for j in g.row(i)[0]] for i in range(g.n_agents)]
 

@@ -14,7 +14,6 @@ One communication round at every agent i (Sec 2.1):
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any, Callable, Sequence
 
 import jax
@@ -77,7 +76,6 @@ def init_network(
     opt: Optimizer,
     init_sigma: float = 0.05,
     shared_init: bool = True,
-    flat: bool = True,
 ) -> NetworkState:
     """Paper Remark 7: agents use a SHARED initialization the first time the
     local models are trained (but never re-synchronize afterwards).  Set
@@ -87,22 +85,9 @@ def init_network(
     [N, P] buffers) — the canonical runtime format: consensus runs as ONE
     fused network-wide pass and the optimizer state collapses to flat
     buffers too.  ``make_round_fn`` picks the layout up from the state
-    automatically, so ``nll_fn`` keeps its pytree signature either way.
-
-    ``flat=False`` keeps the legacy pytree ``GaussianPosterior`` network
-    state (deprecated; the leaf-loop consensus reference stays reachable
-    through ``consensus_all_agents`` on pytree posteriors).
+    automatically, so ``nll_fn`` keeps its pytree signature.
     """
     from repro.core.posterior import init_posterior
-
-    if not flat:
-        warnings.warn(
-            "init_network(flat=False) builds the deprecated pytree network "
-            "state; the flat [N, P] posterior is the canonical runtime "
-            "format since PR 1 (pytrees remain the model-apply boundary).",
-            DeprecationWarning,
-            stacklevel=2,
-        )
 
     if shared_init:
         params = init_params_fn(key)
@@ -112,9 +97,9 @@ def init_network(
     else:
         keys = jax.random.split(key, n_agents)
         stack = jax.vmap(init_params_fn)(keys)
-    post = init_posterior(stack, init_sigma=init_sigma)
-    if flat:
-        post = flat_posterior_from_pytree(post, leading_axes=1)
+    post = flat_posterior_from_pytree(
+        init_posterior(stack, init_sigma=init_sigma), leading_axes=1
+    )
     opt_state = opt.init(post)
     return NetworkState(
         posterior=post,

@@ -663,7 +663,6 @@ class GossipEngine:
             self.opt,
             init_sigma=self.init_sigma,
             shared_init=self.shared_init,
-            flat=True,
         )
         hist_shape = (self.hist_slots,) + tuple(ns.posterior.mean.shape)
         return GossipState(

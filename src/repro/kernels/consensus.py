@@ -1,13 +1,12 @@
 """Pallas TPU kernels: fused precision-weighted posterior consensus (eq. 6).
 
-Three kernels, all computing
+Three consensus kernels, all computing
 
     prec_j   = softplus(rho_j)^-2
     prec_out = sum_j w_j prec_j
     mean_out = sum_j w_j prec_j mean_j / prec_out
     rho_out  = softplus^-1(prec_out^-1/2)
 
-* ``consensus_fused``          — one agent, stacked neighbor posteriors.
 * ``consensus_fused_network``  — ALL agents in one ``pallas_call`` over the
   flat network posterior (mean, rho: ``[N, P]``) with the full row-stochastic
   ``W [N, N]`` resident in VMEM.  Grid ``(P // BLOCK,)``: each program loads
@@ -15,26 +14,21 @@ Three kernels, all computing
   consensus rows for every agent via an MXU matmul ``W @ prec`` — a single
   HBM pass over the network posterior per round, vs (leaves x agents x ~6)
   elementwise round-trips for the unfused leaf-loop einsum.
-* ``consensus_fused_sparse``   — CSR-style neighbor-list variant for sparse
-  topologies (ring/grid/star): grid ``(N, P // BLOCK, D)`` with the neighbor
-  ids scalar-prefetched so each agent reads only its deg(i) <= D neighbor
-  tiles instead of all N rows.
 * ``consensus_fused_masked``   — the gossip event-window form (repro.gossip):
   the network kernel plus a per-agent activity mask.  ACTIVE rows run the
   identical MXU math as ``consensus_fused_network`` (bitwise: the all-active
   window reproduces the synchronous kernel exactly); INACTIVE rows pass
   their (mean, rho) through UNTOUCHED — no softplus/softplus^-1 round trip,
   so an idle agent's posterior is bit-stable across any number of windows.
-* ``consensus_fused_masked_sparse`` — CSR + activity mask: active agents
-  read only their deg(i) fired-neighbor tiles, inactive agents copy their
-  own row (the self-padded tables guarantee the last gathered tile IS the
-  agent's own row), giving HBM traffic proportional to the window's
-  active-edge fraction (``launch.costmodel.gossip_window_roofline``).
+* ``consensus_fused_masked_sparse`` — [N, D] neighbour tables + activity
+  mask: active agents read only their fired-neighbour rows, inactive agents
+  copy their own row (their table rows are all self, so the last gathered
+  row IS the agent's own).  ``_sparse_call`` runs it over the lane-dense
+  view, ``sparse_block`` picks its lane block.
 
-The padded neighbor tables of a static topology come from THE one CSR
-construction — ``core.graphs.SparseGraph.neighbor_tables()``
-(``core.flat.neighbor_tables`` is its dense-W bridge) — so the kernel view
-of a topology can never disagree with the graph layer's.
+and ``payload_validity_fused``, the quarantine guard's one-pass probe of
+every agent's exchanged (prec, prec*mu).  ``lane_block`` sizes the dense
+kernels' lane block.
 
 Edge-native gossip windows (``core.flat.consensus_flat_segments``, [E]
 dst/src/weight lists, no [N, N] anywhere) run one of two executions:
@@ -64,7 +58,7 @@ Flat-buffer layout contract (shared with ``core.flat.FlatPosterior``):
   * keep BLOCK a multiple of 128 (TPU lane width); the last dim rides the
     lane dim, agents/neighbors ride sublanes.  The dense kernels' default
     BLOCK shrinks with N (``lane_block``) so the [N, BLOCK] tiles and the
-    resident W fit the scoped VMEM; the row-gathering sparse kernels tile
+    resident W fit the scoped VMEM; the row-gathering sparse kernel tiles
     the lane-dense view [N, P/128, 128] (``_sparse_call``), a whole row a
     tile where it fits (``sparse_block``);
   * ``wire_dtype="f16"`` is refused by the compiled kernels: Mosaic cannot
@@ -85,8 +79,7 @@ network into one pass is the entire game (see launch.costmodel
 .consensus_roofline for the analytic pass counts the benchmark reports).
 
 ``interpret=None`` on every entry point means auto: Pallas-compiled on TPU,
-interpreter (CPU-correctness mode) elsewhere — callers on TPU no longer
-silently run the interpreter (satellite fix of ISSUE 1).
+interpreter (CPU-correctness mode) elsewhere.
 """
 from __future__ import annotations
 
@@ -106,7 +99,7 @@ from repro.core.numerics import (
 from repro.kernels.dispatch import auto_interpret as _auto_interpret
 
 DEFAULT_BLOCK = 2048
-LANES = 128  # TPU lane width: the sparse kernels view [N, P] as [N, P/128, 128]
+LANES = 128  # TPU lane width: the sparse kernel views [N, P] as [N, P/128, 128]
 
 # Default scoped-VMEM limit Mosaic gives one kernel on a TPU v5e, and the
 # number of fp32 [N, block] tiles one grid step of the dense kernels keeps
@@ -115,7 +108,7 @@ LANES = 128  # TPU lane width: the sparse kernels view [N, P] as [N, P/128, 128]
 # of scoped VMEM at block 2048, i.e. ~11 tiles beside the resident W.
 _VMEM_LIMIT = 16 * 2**20
 _LIVE_TILES = 12
-# The row-gathering sparse kernels' fp32 tiles per grid step: the gathered
+# The row-gathering sparse kernel's fp32 tiles per grid step: the gathered
 # (mean, rho) and the written (mean, rho), double-buffered, plus the two
 # accumulators.
 _SPARSE_LIVE_TILES = 10
@@ -160,74 +153,6 @@ def _pad_lanes(mean, rho, block):
         mean = jnp.pad(mean, widths)
         rho = jnp.pad(rho, widths, constant_values=1.0)
     return mean, rho, p + pad
-
-
-def _consensus_kernel(w_ref, mean_ref, rho_ref, mean_out_ref, rho_out_ref, *,
-                      wire_dtype):
-    w = w_ref[...]  # [N, 1]
-    mean = mean_ref[...]  # [N, BLOCK]
-    rho = rho_ref[...]  # [N, BLOCK]
-    sigma = jax.nn.softplus(rho)
-    prec = 1.0 / (sigma * sigma)
-    if wire_dtype == jnp.float32:
-        # pre-wire op order, verbatim — f32 stays bitwise identical
-        wp = w * prec  # [N, BLOCK]
-        prec_out = jnp.sum(wp, axis=0)  # [BLOCK]
-        mean_out = jnp.sum(wp * mean, axis=0) / prec_out
-    else:
-        # exchange boundary: round (prec, prec*mu), accumulate fp32
-        prec_w = wire_roundtrip(prec, wire_dtype)
-        pm_w = wire_roundtrip(prec * mean, wire_dtype)
-        prec_out = jnp.sum(w * prec_w, axis=0)
-        mean_out = jnp.sum(w * pm_w, axis=0) / prec_out
-    rho_out = softplus_inv(jax.lax.rsqrt(prec_out))
-    mean_out_ref[...] = mean_out[None, :]
-    rho_out_ref[...] = rho_out[None, :]
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret", "wire_dtype"))
-def consensus_fused(
-    w_row: jax.Array,  # [N]
-    mean_stack: jax.Array,  # [N, P]
-    rho_stack: jax.Array,  # [N, P]
-    *,
-    block: int | None = None,
-    interpret: bool | None = None,
-    wire_dtype=None,
-) -> tuple[jax.Array, jax.Array]:
-    """Fused consensus over a flat parameter block.  Returns (mean, rho) [P].
-
-    ``interpret=None`` auto-dispatches (compiled on TPU, interpreter
-    elsewhere); pass an explicit bool to force either mode.  ``wire_dtype``
-    rounds (prec, prec*mu) through the wire dtype at the exchange boundary
-    (module docstring); ``None``/f32 is the bitwise-identical uncompressed
-    path.
-    """
-    interpret = _auto_interpret(interpret)
-    wire_dtype = _wire_for(wire_dtype, interpret)
-    n, p = mean_stack.shape
-    block = lane_block(n) if block is None else block
-    mean_stack, rho_stack, pp = _pad_lanes(mean_stack, rho_stack, block)
-    grid = (pp // block,)
-    mean_out, rho_out = pl.pallas_call(
-        functools.partial(_consensus_kernel, wire_dtype=wire_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),  # w broadcast to all tiles
-            pl.BlockSpec((n, block), lambda i: (0, i)),
-            pl.BlockSpec((n, block), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, pp), mean_stack.dtype),
-            jax.ShapeDtypeStruct((1, pp), rho_stack.dtype),
-        ],
-        interpret=interpret,
-    )(w_row[:, None], mean_stack, rho_stack)
-    return mean_out[0, :p], rho_out[0, :p]
 
 
 def _consensus_network_kernel(w_ref, mean_ref, rho_ref, mean_out_ref,
@@ -372,84 +297,8 @@ def consensus_fused_masked(
     return mean_out[:, :p], rho_out[:, :p]
 
 
-def _consensus_sparse_kernel(
-    nbr_ref,  # scalar-prefetch [N * D] int32 neighbor ids (self-padded)
-    wts_ref,  # scalar-prefetch [N * D] fp32 neighbor weights (0-padded)
-    mean_ref,  # [BLOCK/128, 128] — row nbr[i, d], column tile j
-    rho_ref,  # [BLOCK/128, 128]
-    mean_out_ref,  # [BLOCK/128, 128] — row i, column tile j
-    rho_out_ref,  # [BLOCK/128, 128]
-    acc_prec,  # VMEM scratch [BLOCK/128, 128]
-    acc_pm,  # VMEM scratch [BLOCK/128, 128]
-    *,
-    wire_dtype,
-):
-    i = pl.program_id(0)
-    d = pl.program_id(2)
-    w = wts_ref[i * pl.num_programs(2) + d]
-
-    @pl.when(d == 0)
-    def _init():
-        acc_prec[...] = jnp.zeros_like(acc_prec)
-        acc_pm[...] = jnp.zeros_like(acc_pm)
-
-    sigma = jax.nn.softplus(rho_ref[...])
-    if wire_dtype == jnp.float32:
-        # pre-wire op order, verbatim (w/(sigma*sigma) fuses weight and
-        # precision) — f32 stays bitwise identical
-        wp = w / (sigma * sigma)  # zero-weight pad entries contribute nothing
-        acc_prec[...] += wp
-        acc_pm[...] += wp * mean_ref[...]
-    else:
-        # exchange boundary: the gathered neighbor tile's (prec, prec*mu)
-        # cross the wire rounded; the scratch accumulators stay fp32
-        prec = 1.0 / (sigma * sigma)
-        prec_x = wire_roundtrip(prec, wire_dtype)
-        pm_x = wire_roundtrip(prec * mean_ref[...], wire_dtype)
-        acc_prec[...] += w * prec_x
-        acc_pm[...] += w * pm_x
-
-    @pl.when(d == pl.num_programs(2) - 1)
-    def _finish():
-        prec_out = acc_prec[...]
-        mean_out_ref[...] = acc_pm[...] / prec_out
-        rho_out_ref[...] = softplus_inv(jax.lax.rsqrt(prec_out))
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret", "wire_dtype"))
-def consensus_fused_sparse(
-    neighbors: jax.Array,  # [N, D] int32: neighbor ids, padded with self id
-    weights: jax.Array,  # [N, D] fp32: W[i, neighbors[i]], padded with 0.0
-    mean: jax.Array,  # [N, P]
-    rho: jax.Array,  # [N, P]
-    *,
-    block: int | None = None,
-    interpret: bool | None = None,
-    wire_dtype=None,
-) -> tuple[jax.Array, jax.Array]:
-    """Sparse-neighborhood eq. (6): each agent reads only deg(i) <= D
-    neighbor tiles (D = max in-degree), not all N rows.
-
-    The (neighbors, weights) tables come from ``core.flat.neighbor_tables``
-    (rows of W with zero weight are skipped entirely; ragged degrees are
-    padded with the self id at weight 0, which reads a tile the agent already
-    needs but adds nothing to the sums).  HBM traffic: sum_i deg(i) tiles vs
-    N^2 for the dense kernel — the win for ring/grid/star topologies.
-    ``wire_dtype`` rounds each gathered tile's (prec, prec*mu) at the
-    exchange boundary (fp32 accumulators); f32/None is bitwise the
-    uncompressed kernel.
-    """
-    interpret = _auto_interpret(interpret)
-    wire_dtype = _wire_for(wire_dtype, interpret)
-    return _sparse_call(
-        functools.partial(_consensus_sparse_kernel, wire_dtype=wire_dtype),
-        (neighbors.astype(jnp.int32), weights.astype(jnp.float32)),
-        mean, rho, block, interpret,
-    )
-
-
 def sparse_block(p: int) -> int:
-    """Lane block of the row-gathering sparse kernels for rows of ``p``
+    """Lane block of the row-gathering sparse kernel for rows of ``p``
     lanes: the whole row, padded to a multiple of 128, when the
     ``_SPARSE_LIVE_TILES`` fp32 tiles of one grid step fit three quarters of
     the scoped VMEM (a 199,210-lane row does: 10 x 0.8 MB), else the fewest
@@ -635,8 +484,8 @@ def consensus_fused_masked_sparse(
     interpret: bool | None = None,
     wire_dtype=None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Active-edge eq. (6): CSR neighbor tables of the window's W-tilde
-    (``core.flat.neighbor_tables(w_eff)``) + per-agent activity mask.
+    """Active-edge eq. (6): [N, D] neighbour tables of the window's edges
+    (``core.flat.gather_tables``) + per-agent activity mask.
 
     Active agents accumulate only their deg(i) <= D fired-neighbor tiles;
     inactive agents copy their own (mean, rho) row bit-identically (their

@@ -5,18 +5,6 @@ import jax
 import jax.numpy as jnp
 
 
-def consensus_ref(w_row, mean_stack, rho_stack):
-    """Eq. (6) over a flat parameter block.  Shapes as consensus_fused."""
-    sigma = jax.nn.softplus(rho_stack)
-    prec = 1.0 / jnp.square(sigma)
-    wp = w_row[:, None] * prec
-    prec_out = jnp.sum(wp, axis=0)
-    mean_out = jnp.sum(wp * mean_stack, axis=0) / prec_out
-    sigma_out = 1.0 / jnp.sqrt(prec_out)
-    rho_out = sigma_out + jnp.log1p(-jnp.exp(-sigma_out))
-    return mean_out, rho_out
-
-
 def sample_and_kl_ref(mu, rho, eps, mu_p, rho_p):
     """Reparameterized sample + closed-form Gaussian KL (see gauss_vi)."""
     sq = jax.nn.softplus(rho)

@@ -188,26 +188,13 @@ def make_local_step(
     lr_schedule,
     kl_scale: float = 1e-4,
     remat: bool = True,
-    *,
-    nll_fn: Callable[[PyTree, Any], jax.Array] | None = None,
-    n_mc_samples: int = 1,
 ):
-    """One local VI step against an explicit prior (u>1 rounds in train.py).
+    """One local VI step against an explicit prior (u>1 rounds in train.py):
+    the LM objective — ``models.nll_loss`` on ``cfg``, per-token
+    normalized, averaged over agents.
 
-    Default (``nll_fn=None``): the LM objective — ``models.nll_loss`` on
-    ``cfg``, per-token normalized, averaged over agents.
-
-    ``nll_fn`` (the ``repro.api`` / ``LaunchEngine`` path): an arbitrary
-    per-agent pytree NLL.  The loss becomes the paper's un-normalized free
-    energy ``kl_scale * KL(q||prior) + E_q[nll]`` (eq. 5, estimated with
-    ``n_mc_samples`` MC samples exactly like ``vi.free_energy``), summed over
-    agents so each agent's gradient equals its OWN free-energy gradient; the
-    returned loss is the per-agent [A] vector.  ``key`` may then be a
-    pre-split [A] key array, giving bit-identical RNG to the simulated
-    runtime's per-agent key derivation.
-
-    Either way a ``FlatPosterior`` state runs flat end-to-end: sampling, KL,
-    the optimizer, and consensus all stay on the [A, P] buffers; the pytree
+    A ``FlatPosterior`` state runs flat end-to-end: sampling, KL, the
+    optimizer, and consensus all stay on the [A, P] buffers; the pytree
     appears only inside the model apply (``layout.unflatten``).
     """
 
@@ -216,51 +203,25 @@ def make_local_step(
         a = _n_agents(state.posterior)
         lr = lr_schedule(state.step)
         unflatten = _unflattener(state.posterior)
-        # a 1-D array of TYPED keys is a pre-split per-agent batch; anything
-        # else (typed scalar, legacy uint32 [2] key) is one key to split
-        is_key_batch = (
-            jnp.ndim(key) == 1
-            and jax.dtypes.issubdtype(key.dtype, jax.dtypes.prng_key)
-        )
-        keys = key if is_key_batch else jax.random.split(key, a)
+        keys = jax.random.split(key, a)
 
         def loss_fn(post):
             def per_agent(post_a, prior_a, batch_a, key_a):
-                if nll_fn is not None:
-                    from repro.vi.bayes_by_backprop import free_energy
-
-                    value, _ = free_energy(
-                        post_a,
-                        prior_a,
-                        lambda theta, b: nll_fn(unflatten(theta), b),
-                        batch_a,
-                        key_a,
-                        n_samples=n_mc_samples,
-                        kl_scale=kl_scale,
-                    )
-                    return value
                 theta = post_a.sample(key_a)
                 kl = kl_gaussian(post_a, prior_a)
                 nll, aux = nll_loss(unflatten(theta), cfg, batch_a, remat=remat)
                 ntok = jnp.asarray(batch_a["targets"].size, jnp.float32)
                 return (nll + cfg.router_aux_weight * aux * ntok) / ntok + kl_scale * kl / ntok
 
-            losses = jax.vmap(per_agent)(
+            return jnp.mean(jax.vmap(per_agent)(
                 post, jax.lax.stop_gradient(prior), batch, keys
-            )
-            # sum: d(sum)/d(post_a) = each agent's own gradient (the agents
-            # are independent); mean would scale every lr by 1/A
-            agg = jnp.sum(losses) if nll_fn is not None else jnp.mean(losses)
-            return agg, losses
+            ))
 
-        (_, losses), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.posterior
-        )
+        loss, grads = jax.value_and_grad(loss_fn)(state.posterior)
         with jax.named_scope("optimizer"):
             updates, opt_state = opt.update(grads, state.opt_state,
                                             state.step, lr)
             new_post = apply_updates(state.posterior, updates)
-        loss = losses if nll_fn is not None else jnp.mean(losses)
         return (
             BayesTrainState(posterior=new_post, opt_state=opt_state, step=state.step + 1),
             loss,

@@ -1,5 +1,5 @@
-"""The declarative API (repro.api): spec validation, engine equivalence,
-session checkpoint round-trip with the embedded ExperimentSpec, first-class
+"""The declarative API (repro.api): spec validation, the session
+checkpoint round-trip with the embedded ExperimentSpec, first-class
 topology schedules, and the flat-default satellite flips."""
 import dataclasses
 import os
@@ -21,9 +21,9 @@ from repro.api import (
 from repro.core.flat import FlatPosterior
 
 
-def _tiny_spec(engine="simulated", n_rounds=3, seed=0):
+def _tiny_spec(n_rounds=3, seed=0):
     """3-agent star, 8-dim 3-class synthetic task, 2 local steps of batch 4 —
-    small enough that an engine-equivalence round trip runs in seconds."""
+    small enough that a session round trip runs in seconds."""
     return ExperimentSpec(
         topology=TopologySpec.star(n_edge=2, a=0.5),
         data=DataSpec(
@@ -34,53 +34,8 @@ def _tiny_spec(engine="simulated", n_rounds=3, seed=0):
             local_updates=2,
         ),
         inference=InferenceSpec(hidden=8, depth=1, lr=1e-2),
-        run=RunSpec(n_rounds=n_rounds, seed=seed, engine=engine),
+        run=RunSpec(n_rounds=n_rounds, seed=seed),
     )
-
-
-# ---------------------------------------------------------------------------
-# engine equivalence: the acceptance gate for the launch-path rewiring
-# ---------------------------------------------------------------------------
-
-
-def test_simulated_and_launch_engines_agree():
-    """SimulatedEngine (core.simulated flat runtime) and LaunchEngine
-    (launch.steps make_local_step/make_consensus_step on FlatPosterior)
-    produce allclose posteriors over 3 rounds on a tiny star network — the
-    production hot loop runs the same math as the reference runtime, flat
-    end-to-end."""
-    from repro.launch.steps import BayesTrainState
-
-    s_sim = build_session(_tiny_spec(engine="simulated"))
-    s_launch = build_session(_tiny_spec(engine="launch"))
-    h_sim = s_sim.run()
-    h_launch = s_launch.run()
-    del h_sim, h_launch
-
-    assert isinstance(s_launch.state, BayesTrainState)
-    p_sim, p_launch = s_sim.posterior(), s_launch.posterior()
-    # no pytree posterior in the launch hot loop
-    assert isinstance(p_launch, FlatPosterior)
-    assert isinstance(p_sim, FlatPosterior)
-    np.testing.assert_allclose(
-        np.asarray(p_sim.mean), np.asarray(p_launch.mean), atol=1e-5, rtol=1e-5
-    )
-    np.testing.assert_allclose(
-        np.asarray(p_sim.rho), np.asarray(p_launch.rho), atol=1e-5, rtol=1e-5
-    )
-    # and the training actually moved the posterior
-    fresh = build_session(_tiny_spec(engine="simulated")).posterior()
-    assert float(jnp.max(jnp.abs(p_sim.mean - fresh.mean))) > 1e-4
-
-
-def test_launch_engine_evaluate_matches_simulated():
-    s_sim = build_session(_tiny_spec(engine="simulated"))
-    s_launch = build_session(_tiny_spec(engine="launch"))
-    s_sim.run()
-    s_launch.run()
-    ev_sim = s_sim.evaluate()
-    ev_launch = s_launch.evaluate()
-    np.testing.assert_allclose(ev_sim["acc"], ev_launch["acc"], atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -124,8 +79,9 @@ def test_schedule_union_connectivity_enforced():
 
 
 def test_unknown_enum_fields_rejected():
-    with pytest.raises(ValueError, match="engine"):
-        _tiny_spec().run.__class__(engine="warp").validate()
+    for engine in ("warp", "launch"):
+        with pytest.raises(ValueError, match="unknown engine"):
+            _tiny_spec().run.__class__(engine=engine).validate()
     with pytest.raises(ValueError, match="consensus"):
         InferenceSpec(consensus="median").validate()
     with pytest.raises(ValueError, match="dataset"):
@@ -289,16 +245,11 @@ def test_init_network_flat_default_and_deprecation():
 
     opt = adam()
     state = init_network(jax.random.key(0), 3, init_params, opt)
-    assert isinstance(state.posterior, FlatPosterior)  # flat IS the default
-    with pytest.warns(DeprecationWarning, match="flat"):
-        legacy = init_network(jax.random.key(0), 3, init_params, opt, flat=False)
-    assert not isinstance(legacy.posterior, FlatPosterior)
-    # both hold the same values
-    np.testing.assert_allclose(
-        np.asarray(state.posterior.mean),
-        np.asarray(legacy.posterior.mean["w"].reshape(3, -1)),
-        atol=1e-6,
-    )
+    assert isinstance(state.posterior, FlatPosterior)  # flat IS the format
+    # the shared initialization, flattened: every agent holds the same row
+    w = np.asarray(init_params(jax.random.key(0))["w"]).reshape(-1)
+    np.testing.assert_array_equal(np.asarray(state.posterior.mean),
+                                  np.broadcast_to(w, (3, w.size)))
 
 
 def test_launch_init_train_state_flat_default():

@@ -423,9 +423,6 @@ def test_lm_spec_validation_and_refusals():
         InferenceSpec(arch=ARCH).validate()
     with pytest.raises(ValueError, match="zipf_tokens"):
         dataclasses.replace(_spec(SYNC), data=DataSpec()).validate()
-    with pytest.raises(ValueError, match="launch"):
-        dataclasses.replace(_spec(SYNC), run=RunSpec(engine="launch")
-                            ).validate()
     session = build_session(_spec(SYNC))
     with pytest.raises(NotImplementedError, match="held-out token NLL"):
         session.evaluate()

@@ -11,7 +11,7 @@ The ladder this file pins (ROADMAP "Robustness"):
 * under ``fault_policy="quarantine"`` injected NaN/Inf/huge payloads NEVER
   reach a healthy resident posterior, on every consensus execution;
 * with ZERO faults the quarantined path is BITWISE identical to strict on
-  every execution (dense masked, sparse masked, delayed; the sharded
+  every execution (dense masked, edge-list, delayed; the sharded
   ppermute rung runs under the ``multidevice`` marker);
 * the Pallas validity kernel agrees with the XLA reference exactly.
 """
@@ -28,13 +28,12 @@ from repro.core.flat import (
     FlatPosterior,
     consensus_flat_masked,
     consensus_flat_masked_quarantined,
-    consensus_flat_masked_sparse,
-    consensus_flat_masked_sparse_quarantined,
-    neighbor_tables,
+    consensus_flat_segments,
+    consensus_flat_segments_quarantined,
     payload_validity,
     quarantine_w,
 )
-from repro.core.graphs import bidirectional_ring_w
+from repro.core.graphs import SparseGraph, bidirectional_ring_w
 from repro.core.posterior import softplus
 from repro.gossip.clocks import PoissonClock, build_clock
 from repro.gossip.faults import FaultModel, FaultSpec
@@ -279,10 +278,22 @@ def test_quarantined_window_hand_computed_three_agents():
     assert np.isfinite(np.asarray(out.mean)).all()
 
 
+def _fired_edges(w_eff):
+    """A window's W-tilde as the edge-native window carries it: the fired
+    non-self edges (dst, src, w), the per-agent self weights, and the row
+    bound (max in-degree, self included)."""
+    g = SparseGraph.from_dense(np.asarray(w_eff))
+    dst, src, w = g.edge_arrays()
+    fired = dst != src
+    self_w = np.diagonal(np.asarray(w_eff)).astype(np.float32)
+    return (jnp.asarray(dst[fired]), jnp.asarray(src[fired]),
+            jnp.asarray(w[fired]), jnp.asarray(self_w), g.max_in_degree)
+
+
 def test_zero_fault_quarantine_bitwise_dense_and_sparse_kernels():
     """Kernel rung of the ladder: with every payload valid the quarantined
-    wrappers are BITWISE the plain masked kernels (dense and sparse, xla
-    and interpreted-Pallas modes)."""
+    wrappers are BITWISE the plain kernels (dense masked and edge-list,
+    xla and interpreted-Pallas modes)."""
     n, p = 6, 256
     posts = _posts(n, p, seed=9)
     win = PoissonClock(bidirectional_ring_w(n), rate=0.7, seed=2).window(0)
@@ -298,15 +309,17 @@ def test_zero_fault_quarantine_bitwise_dense_and_sparse_kernels():
                                       np.asarray(ref.mean))
         np.testing.assert_array_equal(np.asarray(got.rho),
                                       np.asarray(ref.rho))
-    neighbors, weights = neighbor_tables(np.asarray(win.w_eff))
+    dst, src, w, self_w, slots = _fired_edges(win.w_eff)
+    ar = jnp.arange(n, dtype=dst.dtype)
     for mode in ("xla", "interpret"):
-        ref = consensus_flat_masked_sparse(
-            posts, jnp.asarray(neighbors), jnp.asarray(weights, jnp.float32),
-            active, mode=mode, block=128,
+        ref = consensus_flat_segments(
+            posts, jnp.concatenate([dst, ar]), jnp.concatenate([src, ar]),
+            jnp.concatenate([w, self_w]), active=active, slots=slots,
+            mode=mode, block=128,
         )
-        got, valid = consensus_flat_masked_sparse_quarantined(
-            posts, jnp.asarray(neighbors), jnp.asarray(weights, jnp.float32),
-            active, mode=mode, block=128,
+        got, valid = consensus_flat_segments_quarantined(
+            posts, dst, src, w, self_w, active=active, slots=slots,
+            mode=mode, block=128,
         )
         assert bool(jnp.all(valid))
         np.testing.assert_array_equal(np.asarray(got.mean),
@@ -322,15 +335,18 @@ def test_sparse_quarantine_drops_invalid_neighbor_mass_to_self():
     W = jnp.asarray(win.w_eff, jnp.float32)
     active = jnp.asarray(win.active)
     mean_src = posts.mean.at[1].set(jnp.inf)
-    neighbors, weights = neighbor_tables(np.asarray(win.w_eff))
-    got_s, valid_s = consensus_flat_masked_sparse_quarantined(
-        posts, jnp.asarray(neighbors), jnp.asarray(weights, jnp.float32),
-        active, mean_src=mean_src, rho_src=posts.rho,
+    dst, src, w, self_w, _ = _fired_edges(win.w_eff)
+    got_s, valid_s = consensus_flat_segments_quarantined(
+        posts, dst, src, w, self_w, active=active,
+        mean_src=mean_src, rho_src=posts.rho,
     )
     got_d, valid_d = consensus_flat_masked_quarantined(
         posts, W, active, mean_src=mean_src, rho_src=posts.rho,
     )
-    np.testing.assert_array_equal(np.asarray(valid_s), np.asarray(valid_d))
+    # the edge-list guard flags each fired edge by its source's payload
+    np.testing.assert_array_equal(np.asarray(valid_s),
+                                  np.asarray(valid_d)[np.asarray(src)])
+    assert not bool(jnp.all(valid_s))  # agent 1's fired edges are dropped
     np.testing.assert_allclose(np.asarray(got_s.mean),
                                np.asarray(got_d.mean), rtol=1e-6, atol=1e-6)
     assert np.isfinite(np.asarray(got_s.mean)).all()

@@ -1,5 +1,5 @@
 """Property tests for the flat-buffer posterior + fused network consensus:
-flat-fused (XLA and Pallas-interpret, dense and sparse) must agree with the
+flat-fused (XLA and Pallas-interpret, dense and edge-list) must agree with the
 ``consensus_all_agents`` leaf-loop einsum reference to <= 1e-6 on ragged
 mixed-shape pytrees, sparse W rows, and non-divisible P % BLOCK padding."""
 import os
@@ -14,20 +14,24 @@ from repro.core.flat import (
     FlatLayout,
     FlatPosterior,
     consensus_flat,
-    consensus_flat_sparse,
+    consensus_flat_segments,
     flat_posterior_from_pytree,
     init_flat_posterior,
     make_flat_nll,
-    neighbor_tables,
 )
-from repro.core.graphs import bidirectional_ring_w, complete_w, star_w
+from repro.core.graphs import (
+    SparseGraph,
+    bidirectional_ring_w,
+    complete_w,
+    star_w,
+)
 from repro.core.numerics import softplus, softplus_inv
 from repro.core.posterior import (
     GaussianPosterior,
     consensus_all_agents,
     init_posterior,
 )
-from repro.kernels.consensus import consensus_fused, consensus_fused_network
+from repro.kernels.consensus import consensus_fused_network
 
 
 def _ragged_posts(n, seed=0, dtypes=None):
@@ -95,34 +99,22 @@ def test_flat_consensus_matches_leaf_loop_reference(topology, mode):
 
 @pytest.mark.parametrize("mode", ["xla", "interpret"])
 def test_flat_sparse_consensus_skips_zero_rows(mode):
-    """CSR neighbor-table path == dense reference on sparse W (zero-weight
-    entries contribute exactly nothing)."""
+    """Edge-list path (XLA segment sum, or the row-gather kernel with
+    ``slots`` entries a row) == the leaf-loop reference on a sparse W:
+    zero-weight entries are no edges and contribute exactly nothing."""
     n = 8
     W = jnp.asarray(bidirectional_ring_w(n), jnp.float32)
     posts = _ragged_posts(n, seed=5)
     flat = flat_posterior_from_pytree(posts, leading_axes=1)
-    nbr, wts = neighbor_tables(np.asarray(W))
-    assert nbr.shape[1] == 3  # ring: self + 2 neighbors, NOT n
+    g = SparseGraph.from_dense(np.asarray(W))
+    assert g.max_in_degree == 3  # ring: self + 2 neighbors, NOT n
+    dst, src, w = (jnp.asarray(a) for a in g.edge_arrays())
     ref = consensus_all_agents(posts, W)
-    out = consensus_flat_sparse(
-        flat, jnp.asarray(nbr), jnp.asarray(wts), mode=mode, block=128
+    out = consensus_flat_segments(
+        flat, dst, src, w, slots=g.max_in_degree, mode=mode, block=128
     ).to_pytree()
     _assert_tree_close(out.mean, ref.mean)
     _assert_tree_close(out.rho, ref.rho)
-
-
-def test_network_kernel_rows_match_per_agent_kernel():
-    """consensus_fused_network row i == consensus_fused with w_row = W[i]."""
-    n, p = 5, 300
-    ks = jax.random.split(jax.random.key(3), 3)
-    mean = jax.random.normal(ks[0], (n, p))
-    rho = jax.random.normal(ks[1], (n, p)) * 0.4 - 1.0
-    W = jax.nn.softmax(jax.random.normal(ks[2], (n, n)), axis=1)
-    mo, ro = consensus_fused_network(W, mean, rho, block=128, interpret=True)
-    for i in range(n):
-        mi, ri = consensus_fused(W[i], mean, rho, block=128, interpret=True)
-        np.testing.assert_allclose(mo[i], mi, atol=1e-6, rtol=1e-5)
-        np.testing.assert_allclose(ro[i], ri, atol=1e-6, rtol=1e-5)
 
 
 def test_consensus_identity_and_fixed_point_flat():
@@ -180,7 +172,7 @@ def test_softplus_inv_matches_float64_over_sigma_range():
 
 
 def test_flat_vi_round_and_dispatch():
-    """End-to-end flat runtime: init_network(flat=True) + param_layout round
+    """End-to-end flat runtime: init_network + param_layout round
     steps under vmap, consensus_all_agents auto-dispatches on FlatPosterior."""
     from repro.core.simulated import init_network, make_round_fn
     from repro.optim import adam
@@ -202,7 +194,7 @@ def test_flat_vi_round_and_dispatch():
         return jnp.sum(logz - gold)
 
     opt = adam()
-    state = init_network(jax.random.key(0), n_agents, init_params, opt, flat=True)
+    state = init_network(jax.random.key(0), n_agents, init_params, opt)
     assert isinstance(state.posterior, FlatPosterior)
     layout = state.posterior.layout
     round_fn = jax.jit(

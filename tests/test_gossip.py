@@ -6,7 +6,6 @@ staleness telemetry), the time_varying_star re-expression, the
 delivery-latency runtime (DelayedClock + [K, N, P] history ring), the
 sharded window consensus (consensus_ppermute_window equivalence ladder,
 8-virtual-device subprocess), and the gossip-window roofline satellite."""
-import dataclasses
 import os
 import textwrap
 
@@ -27,10 +26,10 @@ from repro.api import (
 from repro.core.flat import (
     consensus_flat,
     consensus_flat_masked,
-    consensus_flat_masked_sparse,
-    neighbor_tables,
+    consensus_flat_segments,
 )
 from repro.core.graphs import (
+    SparseGraph,
     bidirectional_ring_w,
     complete_w,
     time_varying_star_schedule,
@@ -185,12 +184,8 @@ def test_gossip_topology_validates_union_connectivity():
 
 def test_gossip_engine_field_cross_validation():
     topo = TopologySpec.gossip("bidirectional_ring", {"n": 4})
-    spec = _gossip_spec(topo, 4)
-    # gossip topology + launch engine is contradictory
-    with pytest.raises(ValueError, match="GossipEngine"):
-        dataclasses.replace(
-            spec, run=dataclasses.replace(spec.run, engine="launch")
-        ).validate()
+    # the "simulated" default on a gossip topology runs the GossipEngine
+    _gossip_spec(topo, 4).validate()
     # engine="gossip" without a gossip topology is contradictory
     with pytest.raises(ValueError, match="kind='gossip'"):
         ExperimentSpec(
@@ -250,7 +245,7 @@ def test_all_active_window_equals_network_kernel_bitwise(mode):
 def test_partial_window_passthrough_and_active_rows(mode):
     """Inactive agents pass through BITWISE (no softplus round trip); active
     rows match the dense reference on the window's W-tilde.  Dense-masked
-    and CSR-masked paths agree."""
+    and edge-list paths agree."""
     from repro.core.flat import FlatPosterior, FlatLayout
 
     n, p = 6, 260
@@ -277,9 +272,11 @@ def test_partial_window_passthrough_and_active_rows(mode):
         atol=1e-6, rtol=1e-5,
     )
 
-    nbr, wts = neighbor_tables(win.w_eff)
-    sp = consensus_flat_masked_sparse(
-        posts, jnp.asarray(nbr), jnp.asarray(wts), act, mode=mode, block=128
+    g = SparseGraph.from_dense(win.w_eff)
+    dst, src, w = (jnp.asarray(a) for a in g.edge_arrays())
+    sp = consensus_flat_segments(
+        posts, dst, src, w, active=act, slots=g.max_in_degree, mode=mode,
+        block=128,
     )
     np.testing.assert_allclose(
         np.asarray(sp.mean), np.asarray(out.mean), atol=1e-6, rtol=1e-5

@@ -1,40 +1,63 @@
-"""Per-kernel allclose sweeps (shapes x dtypes) against the ref.py oracles,
-interpret=True on CPU."""
+"""Per-kernel allclose sweeps (shapes x dtypes) against the XLA references
+(``core.flat``) and the ref.py oracles, interpret=True on CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.posterior import GaussianPosterior, init_posterior
+from repro.core.flat import consensus_flat_reference
+from repro.core.posterior import init_posterior
 from repro.kernels import ref
-from repro.kernels.consensus import consensus_fused
+from repro.kernels.consensus import (
+    consensus_fused_masked,
+    consensus_fused_network,
+)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gauss_vi import sample_and_kl_fused
-from repro.kernels.ops import consensus_posterior, sample_and_kl
+from repro.kernels.ops import sample_and_kl
 
 
+@pytest.mark.parametrize("kernel", ["network", "masked"])
 @pytest.mark.parametrize("n", [1, 3, 9, 16])
 @pytest.mark.parametrize("p", [17, 2048, 5000])
-def test_consensus_kernel_shapes(n, p):
+def test_consensus_kernel_shapes(n, p, kernel):
+    """The dense network kernel, and the masked window kernel under a mixed
+    activity mask, against the XLA reference at ragged shapes."""
     ks = jax.random.split(jax.random.key(p * 31 + n), 3)
-    w = jax.nn.softmax(jax.random.normal(ks[0], (n,)))
+    W = jax.nn.softmax(jax.random.normal(ks[0], (n, n)), axis=1)
     mean = jax.random.normal(ks[1], (n, p))
     rho = jax.random.normal(ks[2], (n, p)) * 0.5 - 1.0
-    mo, ro = consensus_fused(w, mean, rho, block=1024)
-    mr, rr = ref.consensus_ref(w, mean, rho)
+    if kernel == "network":
+        active = None
+        mo, ro = consensus_fused_network(W, mean, rho, block=1024)
+    else:
+        active = jnp.arange(n) % 3 != 1  # agents 1, 4, 7, ... sit out
+        mo, ro = consensus_fused_masked(W, active, mean, rho, block=1024)
+    mr, rr = consensus_flat_reference(mean, rho, W, active=active)
     np.testing.assert_allclose(mo, mr, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(ro, rr, rtol=1e-5, atol=1e-5)
+    if active is not None:  # idle rows pass through bitwise
+        idle = ~np.asarray(active)
+        np.testing.assert_array_equal(np.asarray(mo)[idle],
+                                      np.asarray(mean)[idle])
+        np.testing.assert_array_equal(np.asarray(ro)[idle],
+                                      np.asarray(rho)[idle])
 
 
 def test_consensus_kernel_sparse_weights():
-    """Zero-weight neighbors (sparse topologies) contribute nothing."""
+    """Zero-weight neighbors (sparse topologies) contribute nothing: the
+    network kernel on a W whose last two columns are zero equals the
+    reference over the first two agents alone."""
     n, p = 4, 300
     ks = jax.random.split(jax.random.key(0), 2)
     mean = jax.random.normal(ks[0], (n, p))
     rho = jax.random.normal(ks[1], (n, p)) * 0.3
-    w = jnp.asarray([0.5, 0.5, 0.0, 0.0])
-    mo, ro = consensus_fused(w, mean, rho)
-    mr, rr = ref.consensus_ref(w[:2], mean[:2], rho[:2])
+    W = jnp.asarray([[0.5, 0.5, 0.0, 0.0],
+                     [0.3, 0.7, 0.0, 0.0],
+                     [0.5, 0.5, 0.0, 0.0],
+                     [0.2, 0.8, 0.0, 0.0]])
+    mo, ro = consensus_fused_network(W, mean, rho)
+    mr, rr = consensus_flat_reference(mean[:2], rho[:2], W[:, :2])
     np.testing.assert_allclose(mo, mr, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(ro, rr, rtol=1e-5, atol=1e-5)
 
@@ -93,26 +116,6 @@ def test_flash_attention_matches_chunked_model_path():
         causal=True, chunk_size=64,
     ).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(o_pallas, o_chunked, atol=1e-5, rtol=1e-5)
-
-
-def test_ops_consensus_posterior_pytree():
-    """ops.consensus_posterior == core consensus on a full pytree."""
-    from repro.core.posterior import consensus_mean_field
-
-    n = 4
-    params = {"a": jnp.zeros((3, 5)), "b": jnp.zeros((7,))}
-    stacked = jax.tree.map(lambda p: jnp.zeros((n,) + p.shape), params)
-    rng = np.random.default_rng(0)
-    posts = GaussianPosterior(
-        mean=jax.tree.map(lambda p: jnp.asarray(rng.normal(size=p.shape), jnp.float32), stacked),
-        rho=jax.tree.map(lambda p: jnp.asarray(rng.normal(size=p.shape) * 0.3, jnp.float32), stacked),
-    )
-    w = jnp.asarray([0.4, 0.3, 0.2, 0.1])
-    out_k = consensus_posterior(posts, w, interpret=True)
-    out_r = consensus_mean_field(posts, w)
-    for ka in ("a", "b"):
-        np.testing.assert_allclose(out_k.mean[ka], out_r.mean[ka], rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(out_k.rho[ka], out_r.rho[ka], rtol=1e-4, atol=1e-4)
 
 
 def test_ops_sample_and_kl_pytree():

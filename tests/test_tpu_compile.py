@@ -71,9 +71,6 @@ def _kernel_call(name, n, wire):
         return (lambda m, r: kc.payload_validity_fused(
                     m, r, bound=QUARANTINE_BOUND, **kw),
                 [(buf, jnp.float32), (buf, jnp.float32)])
-    if name == "sparse":
-        return (lambda nb, w, m, r: kc.consensus_fused_sparse(nb, w, m, r, **kw),
-                [*tables, (buf, jnp.float32), (buf, jnp.float32)])
     assert name == "masked_sparse"
     return (lambda nb, w, a, m, r: kc.consensus_fused_masked_sparse(
                 nb, w, a, m, r, **kw),
@@ -81,7 +78,7 @@ def _kernel_call(name, n, wire):
              (buf, jnp.float32)])
 
 
-KERNELS = ["network", "masked", "validity", "sparse", "masked_sparse"]
+KERNELS = ["network", "masked", "validity", "masked_sparse"]
 
 
 @pytest.mark.parametrize("wire", ["f32", "bf16"])

@@ -53,11 +53,9 @@ from repro.core.flat import (
     consensus_flat,
     consensus_flat_delayed,
     consensus_flat_masked,
-    consensus_flat_masked_sparse,
-    consensus_flat_sparse,
-    neighbor_tables,
+    consensus_flat_segments,
 )
-from repro.core.graphs import bidirectional_ring_w, complete_w
+from repro.core.graphs import SparseGraph, bidirectional_ring_w, complete_w
 from repro.core.numerics import (
     canonical_wire_dtype,
     softplus,
@@ -157,8 +155,10 @@ def _paths(posts, win):
     W_ring = jnp.asarray(bidirectional_ring_w(N), jnp.float32)
     W_eff = jnp.asarray(win.w_eff, jnp.float32)
     act = jnp.asarray(win.active)
-    nbr, wts = neighbor_tables(np.asarray(bidirectional_ring_w(N)))
-    nbr_w, wts_w = neighbor_tables(win.w_eff)
+    ring = SparseGraph.from_dense(bidirectional_ring_w(N))
+    edges = [jnp.asarray(a) for a in ring.edge_arrays()]
+    edges_w = [jnp.asarray(a)
+               for a in SparseGraph.from_dense(win.w_eff).edge_arrays()]
     mesh1 = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("agents",))
     return [
         ("dense_xla",
@@ -168,13 +168,12 @@ def _paths(posts, win):
          lambda wd: consensus_flat(posts, W_ring, mode="interpret",
                                    block=128, wire_dtype=wd),
          W_ring, None),
-        ("sparse",
-         lambda wd: consensus_flat_sparse(
-             posts, jnp.asarray(nbr), jnp.asarray(wts), wire_dtype=wd),
+        ("segments",
+         lambda wd: consensus_flat_segments(posts, *edges, wire_dtype=wd),
          W_ring, None),
-        ("sparse_interpret",
-         lambda wd: consensus_flat_sparse(
-             posts, jnp.asarray(nbr), jnp.asarray(wts), mode="interpret",
+        ("segments_interpret",
+         lambda wd: consensus_flat_segments(
+             posts, *edges, slots=ring.max_in_degree, mode="interpret",
              block=128, wire_dtype=wd),
          W_ring, None),
         ("masked",
@@ -184,9 +183,9 @@ def _paths(posts, win):
          lambda wd: consensus_flat_masked(posts, W_eff, act, mode="interpret",
                                           block=128, wire_dtype=wd),
          W_eff, win.active),
-        ("masked_sparse",
-         lambda wd: consensus_flat_masked_sparse(
-             posts, jnp.asarray(nbr_w), jnp.asarray(wts_w), act, wire_dtype=wd),
+        ("masked_segments",
+         lambda wd: consensus_flat_segments(
+             posts, *edges_w, active=act, wire_dtype=wd),
          W_eff, win.active),
         ("ppermute_window",
          lambda wd: consensus_ppermute_window(
