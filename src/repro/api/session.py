@@ -261,7 +261,8 @@ class Session:
         round's aux ``expert_tokens``, [N, n_moe, E]), summed over agents:
         one ``model.expert_tokens{layer}`` observation per expert, and the
         gauge ``model.expert_load_max``: the largest expert's count over
-        the mean, the worst layer."""
+        the mean, the worst layer.  Then the routed experts' execution
+        (``_obs_experts``)."""
         counts = np.asarray(tokens).sum(axis=0)  # [n_moe, E]
         hist = reg.histogram("model.expert_tokens",
                              "tokens per routed expert per round")
@@ -271,6 +272,34 @@ class Session:
         load = counts.max(axis=1) / np.maximum(counts.mean(axis=1), 1e-30)
         reg.gauge("model.expert_load_max", "largest expert's tokens over "
                   "the mean, worst layer, last round").set(float(load.max()))
+        self._obs_experts(reg, counts)
+
+    def _obs_experts(self, reg, counts) -> None:
+        """The routed experts' grouped products, as ``_grouped_swiglu``
+        decides them at trace time from the backend and the product's
+        shape (every agent's and MC sample's (token, expert) pairs of one
+        step, folded into one product): counter
+        ``model.expert_matmul_path{path=pallas_gmm|ragged_dot}`` once a
+        round, and gauge ``model.expert_tile_fill``, real rows over the
+        rows the kernel computes, the worst layer, with the round's
+        per-expert ``counts`` [n_moe, E] spread evenly over its steps and
+        MC samples.  No device work."""
+        from repro.api.models import lm_config
+        from repro.models.moe import expert_matmul_path, expert_tile_fill
+
+        inf, data = self.spec.inference, self.spec.data
+        cfg = lm_config(inf.arch, inf.n_layers)
+        m = (self.data.n_agents * inf.n_mc_samples * data.batch_size
+             * self.data.dim * cfg.top_k)
+        d, f = cfg.d_model, cfg.expert_d_ff
+        reg.counter("model.expert_matmul_path", "rounds by routed-expert "
+                    "grouped-matmul execution").inc(
+                        path=expert_matmul_path(m, d, f))
+        products = data.local_updates * inf.n_mc_samples
+        fill = min(expert_tile_fill(np.round(row / products).astype(np.int64),
+                                    m, d, f) for row in counts)
+        reg.gauge("model.expert_tile_fill", "real rows over the rows the "
+                  "grouped-matmul kernel computes, worst layer").set(fill)
 
     def _obs_attention(self, reg) -> None:
         """The LM's latent-attention execution, as ``mla_block`` decides it

@@ -6,6 +6,9 @@
 #   causal_flash     MLA's causal attention on the TPU: JAX's splash
 #                    kernels (forward, dq, dkv), tested against
 #                    models.attention.chunked_attention
+#   grouped_matmul   the routed experts' grouped products on the TPU:
+#                    JAX's megablox kernel, tested against
+#                    jax.lax.ragged_dot
 # The consensus kernels are checked against core.flat's XLA references,
 # gauss_vi and flash_attention against the pure-jnp oracles in ref.py
 # (gauss_vi's pytree wrapper is ops.sample_and_kl); all are validated in

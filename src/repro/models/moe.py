@@ -19,12 +19,17 @@ router's logits (float32), takes the greedy top-k, optionally renormalizes
 the k weights, scales them by ``routed_scaling_factor`` and adds the
 shared experts (one SwiGLU of ``n_shared_experts`` times the expert
 width).  Dispatch is dropless: the (token, expert) pairs are sorted by
-expert and each expert's SwiGLU runs as a grouped matmul
-(``jax.lax.ragged_dot``) over all of its tokens.  Under ``vmap`` (the
-agents of the simulated runtime, which share the frozen experts) the
-grouped product sees the tokens of every batch element at once: one
-product per layer, not one per agent (``_flat_over_vmap``).  No auxiliary
-loss: the router is frozen wherever this path trains.
+expert and each expert's SwiGLU runs as grouped matmuls over all of its
+tokens: megablox's Pallas kernel (``kernels.grouped_matmul``) on the TPU
+where the shapes meet its tiling, ``jax.lax.ragged_dot`` elsewhere
+(``expert_matmul_path``).  The expert weights are frozen, so the backward
+pass runs the activation-gradient products alone, written out in
+``_grouped_swiglu_vjp``: nine grouped products a layer and step with the
+recomputed forward.  Under ``vmap`` (the agents of the simulated runtime,
+which share the frozen experts) the grouped product sees the tokens of
+every batch element at once: one product per layer, not one per agent
+(``_flat_over_vmap``).  No auxiliary loss: the router is frozen wherever
+this path trains.
 """
 from __future__ import annotations
 
@@ -32,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
+from repro.kernels import grouped_matmul
+from repro.kernels.dispatch import on_tpu
 from repro.models.modules import swiglu, swiglu_init, truncated_normal_init
 
 
@@ -148,26 +155,78 @@ def route_greedy(router_logits: jax.Array, cfg):
     return weights * cfg.routed_scaling_factor, idx
 
 
+def expert_matmul_path(m: int, k: int, n: int) -> str:
+    """The execution of the routed experts' grouped products over ``m``
+    (token, expert) rows, model width ``k`` and expert width ``n``:
+    "pallas_gmm" (``kernels.grouped_matmul``) on the TPU when every one of
+    the four products (gate/up, down and their transposes) meets the
+    kernel's tiling, else "ragged_dot" (``jax.lax.ragged_dot``).  Decided
+    at trace time from the backend and the shapes; ``Session`` reads it
+    for the registry's ``model.expert_matmul_path``."""
+    if on_tpu() and all(grouped_matmul.tiling_for(m, a, b, t) is not None
+                        for a, b in ((k, n), (n, k)) for t in (False, True)):
+        return "pallas_gmm"
+    return "ragged_dot"
+
+
+def expert_tile_fill(group_sizes, m: int, k: int, n: int) -> float:
+    """Real rows over the rows the gate/up product computes, on the path
+    ``expert_matmul_path`` picks (1 on the ``ragged_dot`` one)."""
+    if expert_matmul_path(m, k, n) == "ragged_dot":
+        return 1.0
+    tm = grouped_matmul.tiling_for(m, k, n)[0]
+    return grouped_matmul.tile_fill(group_sizes, tm)
+
+
+def _grouped_product(path: str):
+    """(lhs [m, k], rhs [G, k, n] or, transposed, [G, n, k], group sizes)
+    -> [m, n] in lhs's dtype, on ``path``."""
+    if path == "pallas_gmm":
+        return grouped_matmul.grouped_matmul
+
+    def ragged(lhs, rhs, sizes, *, transpose_rhs=False):
+        return jax.lax.ragged_dot(
+            lhs, jnp.swapaxes(rhs, 1, 2) if transpose_rhs else rhs, sizes)
+
+    return ragged
+
+
+def _sort_pairs(idx, n_experts: int):
+    """The T*k (token, expert) pair slots sorted by expert (stable) and
+    each expert's pair count."""
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)  # pair slots grouped by expert
+    return order, jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+
+
+def _swiglu_act(g, u):
+    return (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+            ).astype(g.dtype)
+
+
+def _combine(y, order, weights):
+    """Expert-sorted outputs y [T*k, D] back in pair order, each token's k
+    outputs summed with its weights: [T, D] in y's dtype."""
+    t, k = weights.shape
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    pairs = y[inverse].reshape(t, k, -1).astype(jnp.float32)
+    return jnp.einsum("tkd,tk->td", pairs, weights.astype(jnp.float32)
+                      ).astype(y.dtype)
+
+
 def _grouped_swiglu(x, weights, idx, w_gate, w_up, w_down):
     """Dropless routed experts over tokens x [T, D]: sort the T*k (token,
     expert) pairs by expert, gather their rows, run gate/up/down as grouped
-    matmuls over the experts' contiguous row groups, and combine each
-    token's k outputs with its weights.  Returns y [T, D] (x's dtype)."""
-    t, d = x.shape
-    k, e = idx.shape[-1], w_gate.shape[0]
-    flat = idx.reshape(-1)
-    order = jnp.argsort(flat, stable=True)  # pair slots grouped by expert
-    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    matmuls over the experts' contiguous row groups (on
+    ``expert_matmul_path``'s path), and combine each token's k outputs
+    with its weights.  Returns y [T, D] (x's dtype)."""
+    k = idx.shape[-1]
+    order, sizes = _sort_pairs(idx, w_gate.shape[0])
     rows = x[order // k]
-    g = jax.lax.ragged_dot(rows, w_gate, sizes)
-    u = jax.lax.ragged_dot(rows, w_up, sizes)
-    h = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32))
-    y = jax.lax.ragged_dot(h.astype(x.dtype), w_down, sizes)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    y = y[inverse].reshape(t, k, d).astype(jnp.float32)
-    return jnp.einsum("tkd,tk->td", y, weights.astype(jnp.float32)
-                      ).astype(x.dtype)
+    mm = _grouped_product(expert_matmul_path(rows.shape[0], *w_gate.shape[1:]))
+    h = _swiglu_act(mm(rows, w_gate, sizes), mm(rows, w_up, sizes))
+    return _combine(mm(h, w_down, sizes), order, weights).astype(x.dtype)
 
 
 def _flat_over_vmap(fn, n_token_args: int):
@@ -202,10 +261,25 @@ _experts_fwd = _flat_over_vmap(_grouped_swiglu, 3)
 
 
 def _grouped_swiglu_vjp(x, weights, idx, w_gate, w_up, w_down, g):
-    _, vjp = jax.vjp(
-        lambda x, w: _grouped_swiglu(x, w, idx, w_gate, w_up, w_down),
-        x, weights)
-    return vjp(g)
+    """(dx, dweights) of ``_grouped_swiglu`` at cotangent g [T, D], the
+    expert weights frozen: the forward's three products recomputed, then
+    dh = dy w_down^T and dx_rows = dg w_gate^T + du w_up^T as grouped
+    products (six in all); the sort, gathers and combine differentiated
+    by JAX."""
+    k = idx.shape[-1]
+    order, sizes = _sort_pairs(idx, w_gate.shape[0])
+    rows, rows_vjp = jax.vjp(lambda x: x[order // k], x)
+    mm = _grouped_product(expert_matmul_path(rows.shape[0], *w_gate.shape[1:]))
+    h, act_vjp = jax.vjp(_swiglu_act, mm(rows, w_gate, sizes),
+                         mm(rows, w_up, sizes))
+    _, combine_vjp = jax.vjp(lambda y, w: _combine(y, order, w),
+                             mm(h, w_down, sizes), weights)
+    dy, dweights = combine_vjp(g)
+    dg, du = act_vjp(mm(dy, w_down, sizes, transpose_rhs=True))
+    dx_rows = (mm(dg, w_gate, sizes, transpose_rhs=True)
+               + mm(du, w_up, sizes, transpose_rhs=True))
+    (dx,) = rows_vjp(dx_rows.astype(x.dtype))
+    return dx, dweights
 
 
 def _experts_bwd_rule(x, weights, idx, g, w_gate, w_up, w_down):

@@ -5,8 +5,9 @@ primitives it has no lowering for, blocks that break the (8, 128) tiling
 rule, kernels that overrun the scoped VMEM.  These tests compile each
 kernel with ``interpret=False`` for one chip of a described ``v5e:2x2``
 topology at the paper MLP's width (P = 199,210 parameters per agent), and
-the latent attention's causal flash kernels at the LM cell's shapes, and
-check that the program really holds the kernel (``tpu_custom_call``).
+the latent attention's causal flash kernels and the routed experts'
+grouped-matmul kernels at the LM cell's shapes, and check that the program
+really holds the kernel (``tpu_custom_call``).
 
 The topology is described inside a fixture, never at import: only the
 process that runs these tests loads the TPU compiler library.
@@ -25,6 +26,9 @@ from repro.configs import get_config
 from repro.core.flat import QUARANTINE_BOUND, gather_tables
 from repro.kernels import causal_flash
 from repro.kernels import consensus as kc
+from repro.kernels import dispatch
+from repro.kernels import grouped_matmul as gm
+from repro.models import moe
 from repro.models.attention import mla_softmax_scale
 
 P_MLP = 199_210  # 784-200-200-10 MLP
@@ -142,6 +146,54 @@ def test_mla_flash_attention_compiles_for_v5e_at_cell_shapes(one_chip):
     for kernel in ("splash_mha_fwd", "splash_mha_dkv"):  # dkv fuses dq
         assert kernel in text
     assert not re.search(r"f32\[[\d,]*16,256,4096\]", text)
+
+
+@pytest.mark.parametrize("key", sorted(gm.TILINGS))
+def test_grouped_matmul_compiles_for_v5e_at_cell_shapes(one_chip, key):
+    """Each of the routed experts' four grouped products at the LM cell's
+    196,608 rows (8 agents x 4,096 tokens x top-6) over 64 experts, at the
+    table's tiles: the kernel's blocks fit the scoped VMEM."""
+    k, n, transpose_rhs = key
+    rhs = (64, n, k) if transpose_rhs else (64, k, n)
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    fn = lambda x, w, g: gm.grouped_matmul(
+        x, w, g, transpose_rhs=transpose_rhs, interpret=False)
+    text = jax.jit(fn).lower(shape((196_608, k)), shape(rhs),
+                             shape((64,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_routed_experts_run_the_kernel_under_experts_on_v5e(one_chip,
+                                                            monkeypatch):
+    """``routed_experts`` forward and backward at the cell's widths (2
+    agents x 256 tokens, top-6 of 64 experts, d 2,048, expert width 1,408)
+    on the TPU path: nine kernel calls (three forward, three recomputed,
+    three activation-gradient), each with ``experts`` on its scope path,
+    which the benchmark's scope reader needs, and no ``ragged_dot`` left."""
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    a, t, d, f, e, k = 2, 256, 2048, 1408, 64, 6
+
+    def loss(x, w, idx, w_gate, w_up, w_down):
+        with jax.named_scope("experts"):
+            y = jax.vmap(lambda x, w, i: moe.routed_experts(
+                x, w, i, w_gate, w_up, w_down))(x, w, idx)
+        return jnp.sum(y.astype(jnp.float32)), y
+
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True)).lower(
+        shape((a, t, d)), shape((a, t, k), jnp.float32),
+        shape((a, t, k), jnp.int32), shape((e, d, f)), shape((e, d, f)),
+        shape((e, f, d))).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 9
+    for line in calls:
+        scope = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert re.search(r"(^|[/(])experts\)*/", scope), scope
+    assert "%ragged-dot" not in text  # XLA's ragged_dot custom calls
 
 
 def test_gather_tables_at_the_smem_bound_compile(one_chip):
